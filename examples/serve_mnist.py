@@ -91,6 +91,7 @@ def main():
     parser.add_argument("--max-queue", type=int, default=512)
     args = parser.parse_args()
     mx.util.pin_platform(args.device)
+    mx.compile.enable_jax_cache()
     logging.basicConfig(level=logging.INFO)
 
     X, y = synthetic_digits(args.num_examples)

@@ -27,7 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
-def build_net(network, num_classes):
+def build_net(network, num_classes, **net_kwargs):
     from mxnet_tpu.gluon.model_zoo import vision
 
     factory = {
@@ -37,30 +37,30 @@ def build_net(network, num_classes):
         "inception-v3": vision.inception_v3,
         "mobilenet": vision.mobilenet1_0,
     }[network]
-    net = factory(classes=num_classes)
+    net = factory(classes=num_classes, **net_kwargs)
     net.initialize()
     return net
 
 
 def build_train_step(network="resnet50", num_classes=1000, dtype=None,
-                     device=None, lr=0.1, momentum=0.9, wd=1e-4):
-    """The compiled training step bench.py measures."""
-    import jax
-
+                     devices=None, lr=0.1, momentum=0.9, wd=1e-4,
+                     **net_kwargs):
+    """The compiled training step bench.py and chip_smoke.py drive:
+    data-parallel over `devices` (default: every device JAX reports,
+    so a four-chip host trains on four chips, not on the first)."""
     from mxnet_tpu import gluon
     from mxnet_tpu.parallel import TrainStep, make_mesh
 
-    net = build_net(network, num_classes)
-    device = device if device is not None else jax.devices()[0]
+    net = build_net(network, num_classes, **net_kwargs)
     return TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                      optimizer="sgd",
                      optimizer_params={"learning_rate": lr,
                                        "momentum": momentum, "wd": wd},
-                     mesh=make_mesh({"dp": 1}, devices=[device]),
+                     mesh=make_mesh({"dp": -1}, devices=devices),
                      dtype=dtype)
 
 
-def benchmark_rate(network="resnet50", batch=32, dtype=None, device=None,
+def benchmark_rate(network="resnet50", batch=32, dtype=None, devices=None,
                    image_shape=(3, 224, 224), iters=10, windows=5,
                    warmup=3, num_classes=1000, lr=0.1, momentum=0.9,
                    wd=1e-4):
@@ -69,7 +69,7 @@ def benchmark_rate(network="resnet50", batch=32, dtype=None, device=None,
     import jax
     import jax.numpy as jnp
 
-    step = build_train_step(network, num_classes, dtype, device,
+    step = build_train_step(network, num_classes, dtype, devices,
                             lr=lr, momentum=momentum, wd=wd)
     rng = np.random.RandomState(0)
     x = rng.rand(batch, *image_shape).astype(np.float32)
@@ -97,7 +97,8 @@ def main():
     parser.add_argument("--network", default="resnet50")
     parser.add_argument("--device", default=os.environ.get(
         "MXNET_DEVICE", "auto"), choices=["auto", "cpu", "tpu"],
-        help="'cpu' pins the cpu backend in-process")
+        help="pins the backend in-process; 'tpu' fails where there "
+             "is no TPU")
     parser.add_argument("--num-classes", type=int, default=1000)
     parser.add_argument("--batch-size", type=int, default=32)
     parser.add_argument("--image-shape", default="3,224,224")
@@ -116,9 +117,11 @@ def main():
     parser.add_argument("--data-train", default=None,
                         help=".rec file for real training data")
     args = parser.parse_args()
+    from mxnet_tpu.compile import enable_jax_cache
     from mxnet_tpu.util import pin_platform
 
     pin_platform(args.device)
+    enable_jax_cache()
     logging.basicConfig(level=logging.INFO)
     shape = tuple(int(v) for v in args.image_shape.split(","))
     dtype = None if args.dtype == "float32" else args.dtype

@@ -98,8 +98,8 @@ def main():
                         choices=["mlp", "lenet"])
     parser.add_argument("--device", default=os.environ.get(
         "MXNET_DEVICE", "auto"), choices=["auto", "cpu", "tpu"],
-        help="'cpu' pins the cpu backend in-process (reliable even "
-        "where the TPU plugin overrides JAX_PLATFORMS)")
+        help="pins the backend in-process; 'tpu' fails where there "
+        "is no TPU")
     parser.add_argument("--batch-size", type=int, default=64)
     parser.add_argument("--lr", type=float, default=0.05)
     parser.add_argument("--num-epochs", type=int, default=5)

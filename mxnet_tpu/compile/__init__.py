@@ -36,6 +36,12 @@ Enable with ``MXNET_COMPILE_CACHE=<dir>`` (optionally
 ``MXNET_COMPILE_CACHE_MB`` for LRU retention) or programmatically via
 :func:`configure`. Disabled (the default) every seam compiles exactly
 as before.
+
+This executable store is NOT JAX's own persistent compilation cache.
+That one is keyed by XLA on the compile request, serves every
+``jax.jit`` in the process and is what the entry points turn on with
+:func:`enable_jax_cache`; the store above serves three seams, ships
+entries across a pod and stays opt-in.
 """
 from __future__ import annotations
 
@@ -54,7 +60,7 @@ __all__ = ["CachedFunction", "CompileCacheStore", "cached_compile",
            "maybe_cached_jit", "configure", "reset", "enabled",
            "active_store", "attach_kvstore", "set_distributor",
            "shared_filesystem", "backend_fingerprint", "make_key",
-           "entry_name", "ENTRY_FORMAT"]
+           "entry_name", "ENTRY_FORMAT", "enable_jax_cache"]
 
 _hits_total = _tm.REGISTRY.counter(
     "mx_compile_cache_hits_total",
@@ -78,6 +84,29 @@ _load_seconds = _tm.REGISTRY.histogram(
     "pays instead of mx_compile_seconds)", labels=("site",))
 
 _logger = _log.get_logger("mxnet_tpu.compile")
+
+# -- JAX's own persistent compilation cache ------------------------------------
+
+_JAX_CACHE_DEFAULT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_jax_cache():
+    """Place JAX's persistent compilation cache for this process; entry
+    points call it before their first compile. ``JAX_COMPILATION_CACHE_DIR``
+    wins when set (JAX reads it itself, nothing is set here); otherwise
+    the cache lives at ``<checkout>/.jax_cache``. The directory is part
+    of the cache key, so it is a fixed path, never one named after a
+    temporary directory, a pid or the time. Returns the directory."""
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        import jax
+
+        directory = _JAX_CACHE_DEFAULT
+        jax.config.update("jax_compilation_cache_dir", directory)
+    return directory
+
 
 # -- process-wide configuration ------------------------------------------------
 
@@ -400,8 +429,8 @@ class CachedFunction:
         try:
             payload = _serialize(compiled)
         except Exception as exc:
-            # Backend cannot serialize (older plugin, exotic topology):
-            # the executable still runs, the cache just stays cold.
+            # Backend cannot serialize this executable: it still runs,
+            # the cache just stays cold.
             _errors_total.labels(site=self.site,
                                  kind="serialize_unsupported").inc()
             _log.warn_rate_limited(
@@ -465,19 +494,32 @@ def _fingerprint_text(lowered):
 
 
 def _serialize(compiled):
-    """Executable -> bytes (pickled payload + in/out trees)."""
+    """Executable -> bytes (pickled payload + in/out trees + the ids of
+    the devices it was compiled for, in assignment order)."""
+    import jax
     from jax.experimental import serialize_executable as _sx
 
     payload, in_tree, out_tree = _sx.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree), protocol=4)
+    sharding = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings))[0]
+    device_ids = [d.id for d in sharding._device_assignment]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
+                        protocol=4)
 
 
 def _deserialize(blob):
-    """Bytes -> loaded executable ready to call."""
+    """Bytes -> loaded executable ready to call, on the devices it was
+    compiled for: left to its default, the loader assigns EVERY device
+    of the backend, and a one-device executable then dies at its first
+    call on any host with more than one."""
+    import jax
     from jax.experimental import serialize_executable as _sx
 
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return _sx.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _sx.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 # -- the seam API --------------------------------------------------------------

@@ -40,7 +40,7 @@ import zlib
 
 __all__ = ["CompileCacheStore", "make_key", "entry_name", "ENTRY_FORMAT"]
 
-ENTRY_FORMAT = "mxnet_tpu.compile_cache/1"
+ENTRY_FORMAT = "mxnet_tpu.compile_cache/2"
 _PREFIX = "cc."
 _SUFFIX = ".bin"
 

@@ -73,7 +73,18 @@ class Context:
         devs = _devices_by_type()[self.device_type]
         if not devs:
             raise RuntimeError("no %s device available" % self.device_type)
-        return devs[self.device_id % len(devs)]
+        if self.device_type == "cpu":
+            # Host ids wrap, as in the reference, where every cpu(i)
+            # names the one host: scripts and tests written for N
+            # devices still run where fewer host devices exist.
+            return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            # A chip id past the end is an error, never chip 0: a run
+            # that asked for four chips must not pile onto the first.
+            raise RuntimeError(
+                "%r: this process has %d tpu device(s)"
+                % (self, len(devs)))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return (

@@ -499,9 +499,20 @@ class FusedApplier:
         # optimizers document). Pad lanes are zeros and every supported
         # body maps zeros to zeros, so they never drift or NaN.
         pad = (-total) % 64
-        padded = total + pad
-        repeats = np.asarray(sizes + ([pad] if pad else []))
         body = spec.body
+
+        def per_element(hyp):
+            # One hyperparameter per parameter -> one per element, as a
+            # concatenation of broadcasts. (jnp.repeat builds its gather
+            # indices from a cumsum over the whole flat length; the
+            # sizes are constants, so XLA folds that at compile time,
+            # through its slow evaluator: minutes for a ResNet.)
+            parts = [jnp.broadcast_to(hyp[i], (sizes[i],))
+                     for i in range(n)]
+            if pad:
+                parts.append(jnp.zeros((pad,), hyp.dtype))
+            return parts[0] if len(parts) == 1 else \
+                jnp.concatenate(parts)
 
         # rescale_grad is BAKED, exactly like the loop path bakes it in
         # the op's attrs key (a changed batch size recompiles once per
@@ -524,20 +535,12 @@ class FusedApplier:
                 # clips — unclipped executables are byte-identical to
                 # the pre-clip ones.
                 g = g * scale[0].astype(g.dtype)
-            hyp = (lrs, wds)
-            if pad:
-                z = jnp.zeros((1,), lrs.dtype)
-                hyp = (jnp.concatenate([lrs, z]),
-                       jnp.concatenate([wds, z]))
-            lr_el = jnp.repeat(hyp[0], repeats,
-                               total_repeat_length=padded)
-            wd_el = jnp.repeat(hyp[1], repeats,
-                               total_repeat_length=padded)
             # The barrier materializes the expanded hyperparameters as
-            # plain buffers: a repeat (gather) fused INTO the update
-            # loop perturbs XLA:CPU codegen the same ulp-level way the
+            # plain buffers: an expansion fused INTO the update loop
+            # perturbs XLA:CPU codegen the same ulp-level way the
             # epilogue does. Found by end-to-end cross-check.
-            lr_el, wd_el = jax.lax.optimization_barrier((lr_el, wd_el))
+            lr_el, wd_el = jax.lax.optimization_barrier(
+                (per_element(lrs), per_element(wds)))
             new_w, new_s = body(flat_w, g, tuple(flat_s), lr_el, wd_el,
                                 rescale)
             outs = tuple(

@@ -88,13 +88,12 @@ def _worker_initializer(dataset, is_child_process):
         import os
 
         os.environ["JAX_PLATFORMS"] = "cpu"
-        # The env var alone is provably insufficient: the TPU PJRT
-        # plugin re-registers at import time and overrides it, so a
-        # worker that touches jax would still dial (and possibly hang
-        # on) the chip. Pin through the config API too — it wins as
-        # long as no backend has initialized in this child, which fork
-        # start methods guarantee only if the parent's client handle is
-        # unusable here anyway (the reason for this contract).
+        # A chip belongs to one process, the trainer: a worker that
+        # touched jax on the default platform would fail or hang on it.
+        # jax reads JAX_PLATFORMS when it is imported, and a forked
+        # worker inherits the parent's already-imported jax, so there
+        # only the config API takes effect; the variable covers a
+        # spawned worker and whatever the worker itself starts.
         try:
             import jax
 

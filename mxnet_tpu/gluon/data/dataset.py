@@ -6,7 +6,7 @@ _LazyTransformDataset).
 
 TPU rebuild: datasets are host-side (numpy / python objects); device
 transfer happens once per batch at the DataLoader boundary, keeping the
-PCIe/tunnel traffic to one contiguous copy per stream.
+host-to-device traffic to one contiguous copy per stream.
 """
 from __future__ import annotations
 

@@ -35,6 +35,47 @@ __all__ = ["flash_attention"]
 _NEG = -1e30
 
 
+# Row statistics (running max, denominator, logsumexp, delta) live as
+# (block_q, 1) columns inside the kernels, where they broadcast against
+# the (block_q, block_k) score tile, and as lane-dense (bh, 1, tq) rows
+# in HBM: the TPU lowering only takes blocks whose last two dimensions
+# are (8, 128)-divisible or the whole array, so a (1, block_q) block of
+# a (bh, tq) array is refused, and a (bh, tq, 1) column would be padded
+# to 128 lanes. The two helpers below move one block between the forms
+# through a full (·, 128) tile, the transpose the hardware has.
+_LANES = 128
+
+
+def _col_to_row(col):
+    """(n, 1) -> (1, n)."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _row_to_col(row):
+    """(1, n) -> (n, 1)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _causal_mask(i, j, block_q, block_k, transposed=False):
+    """q_pos >= k_pos for block (i, j); (block_k, block_q) if transposed."""
+    shape = (block_k, block_q) if transposed else (block_q, block_k)
+    q_pos = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1 if transposed else 0)
+    k_pos = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0 if transposed else 1)
+    return q_pos >= k_pos
+
+
+def _dot(a, b, contract):
+    """MXU matmul on the operands' own dtype, fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))    # a @ b.T
+_NN = ((1,), (0,))    # a @ b
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
             scale, causal, block_q, block_k):
     import jax.experimental.pallas as pl
@@ -44,37 +85,29 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     i = pl.program_id(1)
 
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)        # (bq, d)
-        k = k_ref[0].astype(jnp.float32)        # (bk, d)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = None
+        q = q_ref[0]                            # (bq, d)
+        k = k_ref[0]                            # (bk, d)
+        v = v_ref[0]
+        s = _dot(q, k, _NT) * scale             # (bq, bk) fp32
         if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = q_pos >= k_pos
+            mask = _causal_mask(i, j, block_q, block_k)
             s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                     # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         if causal:
-            p = p * mask
+            p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * corr + p.sum(axis=-1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(v.dtype), v, _NN)
 
     if causal:
         # k-blocks wholly above the diagonal (first key after this
@@ -86,11 +119,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
         # Per-row logsumexp: the single residual the backward needs to
         # regenerate any probability block (FlashAttention-2 eq. 5).
-        lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+        lse_ref[0] = _col_to_row(m_ref[...] + jnp.log(l))
 
 
 def _block_sizes(tq, tk, block_q, block_k):
@@ -101,6 +134,15 @@ def _block_sizes(tq, tk, block_q, block_k):
             "sequence lengths (%d, %d) must divide by blocks (%d, %d)"
             % (tq, tk, block_q, block_k))
     return block_q, block_k
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    # The innermost grid axis carries the accumulators; the other two
+    # are independent.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
@@ -120,7 +162,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         functools.partial(_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         out_shape=(jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, tq), jnp.float32)),
+                   jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
@@ -129,42 +171,17 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         ],
         out_specs=(pl.BlockSpec((1, block_q, d),
                                 lambda b_, i, j: (b_, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b_, i, j: (b_, i))),
+                   pl.BlockSpec((1, 1, block_q),
+                                lambda b_, i, j: (b_, 0, i))),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_compiler_params(),
         interpret=interpret,
     )(q3, k3, v3)
     return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
-
-
-def _regen(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, i, j, *,
-           scale, causal, block_q, block_k):
-    """Shared backward recompute: regenerate this (i, j) block's exact
-    probabilities from q/k + saved logsumexp, and form dS (FA2 eqs).
-    Returns (p, ds, q, k, do) in fp32. One copy of the mask convention
-    for both backward passes."""
-    q = q_ref[0].astype(jnp.float32)          # (bq, d)
-    k = k_ref[0].astype(jnp.float32)          # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)        # (bq, d)
-    lse = lse_ref[0]                          # (bq,)
-    delta = dlt_ref[0]                        # (bq,) rowsum(dO*O)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if causal:
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG)
-    p = jnp.exp(s - lse[:, None])             # exact probabilities
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # (bq,bk)
-    ds = p * (dp - delta[:, None]) * scale
-    return p, ds, q, k, do
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
@@ -173,7 +190,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     """dK/dV pass: grid (bh, k-blocks, q-blocks); the q dimension
     iterates innermost, accumulating this k-block's gradients in VMEM.
     Probabilities are REGENERATED from q/k + the saved logsumexp — no
-    O(T²) residual ever exists (the whole point of a flash backward)."""
+    O(T²) residual ever exists (the whole point of a flash backward).
+    The block is formed TRANSPOSED, (block_k, block_q): the saved rows
+    then broadcast as they are stored and both accumulations are plain
+    matmuls (FA2 eqs on S^T)."""
     import jax.experimental.pallas as pl
 
     j = pl.program_id(1)                      # k block (outer)
@@ -182,19 +202,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
     @pl.when(i == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _accumulate():
-        p, ds, q, _, do = _regen(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # p^T do (bk, d)
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # ds^T q (bk, d)
+        q = q_ref[0]                          # (bq, d)
+        k = k_ref[0]                          # (bk, d)
+        v = v_ref[0]
+        do = do_ref[0]                        # (bq, d)
+        st = _dot(k, q, _NT) * scale          # (bk, bq) = S^T
+        if causal:
+            st = jnp.where(_causal_mask(i, j, block_q, block_k,
+                                        transposed=True), st, _NEG)
+        pt = jnp.exp(st - lse_ref[0])         # exact probabilities, P^T
+        dpt = _dot(v, do, _NT)                # (bk, bq) = dP^T
+        dst = pt * (dpt - dlt_ref[0]) * scale
+        dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d)
+        dk_acc[...] += _dot(dst.astype(q.dtype), q, _NN)      # (bk, d)
 
     if causal:
         # q-blocks entirely above the diagonal see zero probability
@@ -205,12 +229,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
     @pl.when(i == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                   dq_ref, dq_acc, *, scale, causal, block_q, block_k):
+                   dq_ref, dq_acc, lse_col, dlt_col, *, scale, causal,
+                   block_q, block_k):
     """dQ pass: grid (bh, q-blocks, k-blocks), k innermost."""
     import jax.experimental.pallas as pl
 
@@ -220,15 +245,23 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
     @pl.when(j == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        # The saved rows as columns, once per q-block.
+        lse_col[...] = _row_to_col(lse_ref[0])
+        dlt_col[...] = _row_to_col(dlt_ref[0])
 
     def _accumulate():
-        _, ds, _, k, _ = _regen(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, d)
+        q = q_ref[0]                          # (bq, d)
+        k = k_ref[0]                          # (bk, d)
+        v = v_ref[0]
+        do = do_ref[0]                        # (bq, d)
+        s = _dot(q, k, _NT) * scale           # (bq, bk)
+        if causal:
+            s = jnp.where(_causal_mask(i, j, block_q, block_k), s, _NEG)
+        p = jnp.exp(s - lse_col[...])         # exact probabilities
+        dp = _dot(do, v, _NT)                 # (bq, bk)
+        ds = p * (dp - dlt_col[...]) * scale
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)       # (bq, d)
 
     if causal:
         pl.when(j * block_k <= (i + 1) * block_q - 1)(_accumulate)
@@ -237,59 +270,75 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
     @pl.when(j == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _flash_dkv(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
+               block_k, interpret):
+    """The dK/dV pallas_call on (bh, t, d) operands and (bh, 1, tq)
+    row statistics."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, tq, d = q3.shape
+    tk = k3.shape[1]
+    qspec = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
+    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, i))
+    return pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        out_shape=(jax.ShapeDtypeStruct((bh, tk, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, d), v3.dtype)),
+        grid=(bh, tk // block_k, tq // block_q),
+        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+        out_specs=(kspec, kspec),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(q3, k3, v3, do3, lse3, delta)
+
+
+def _flash_dq(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
+              block_k, interpret):
+    """The dQ pallas_call, same operands as :func:`_flash_dkv`."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, tq, d = q3.shape
+    tk = k3.shape[1]
+    qspec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0))
+    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i))
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
+        grid=(bh, tq // block_q, tk // block_k),
+        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(q3, k3, v3, do3, lse3, delta)
 
 
 def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
                     block_k, interpret):
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, tq, d = q.shape
-    tk = k.shape[2]
-    block_q, block_k = _block_sizes(tq, tk, block_q, block_k)
+    block_q, block_k = _block_sizes(tq, k.shape[2], block_q, block_k)
     bh = b * h
-    q3, k3, v3 = (a.reshape(bh, -1, d) for a in (q, k, v))
-    do3 = g.reshape(bh, tq, d)
-    lse2 = lse.reshape(bh, tq)
     # delta_i = rowsum(dO_i * O_i) — O(T·d), fused by XLA.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(bh, tq)
-
-    qspec = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    rowq = pl.BlockSpec((1, block_q), lambda b_, j, i: (b_, i))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        out_shape=(jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v.dtype)),
-        grid=(bh, tk // block_k, tq // block_q),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
-        out_specs=(pl.BlockSpec((1, block_k, d),
-                                lambda b_, j, i: (b_, j, 0)),
-                   pl.BlockSpec((1, block_k, d),
-                                lambda b_, j, i: (b_, j, 0))),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(q3, k3, v3, do3, lse2, delta)
-
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0))
-    rowq2 = pl.BlockSpec((1, block_q), lambda b_, i, j: (b_, i))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        grid=(bh, tq // block_q, tk // block_k),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda b_, i, j: (b_, i, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q3, k3, v3, do3, lse2, delta)
-
+                    axis=-1).reshape(bh, 1, tq)
+    operands = tuple(a.reshape(bh, -1, d) for a in (q, k, v, g)) + (
+        lse.reshape(bh, 1, tq), delta)
+    static = (scale, causal, block_q, block_k, interpret)
+    dk, dv = _flash_dkv(*operands, *static)
+    dq = _flash_dq(*operands, *static)
     return (dq.reshape(q.shape), dk.reshape(k.shape),
             dv.reshape(v.shape))
 

@@ -105,20 +105,6 @@ def initialize(coordinator_address=None, num_processes=None,
             "DMLC_PS_ROOT_URI/PORT + DMLC_WORKER_ID (tools/launch.py -s 0 "
             "exports them) or pass them explicitly")
 
-    # Cross-process computations on the CPU backend need a collectives
-    # implementation (the DCN stand-in on a dev box): without gloo the
-    # runtime rejects any multi-process executable outright. Must be
-    # configured before the backend initializes — which is exactly
-    # where we are. Only the "none" default is replaced: an operator
-    # who pinned mpi (env or config) keeps their choice.
-    try:
-        if getattr(jax.config, "jax_cpu_collectives_implementation",
-                   None) in (None, "none"):
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-    except Exception:
-        pass        # older jax: flag absent; TPU/GPU paths unaffected
-
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

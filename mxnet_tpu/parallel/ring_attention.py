@@ -123,10 +123,7 @@ def ring_self_attention(mesh, q, k, v, causal=False, scale=None,
     `ring_attention`, returns the assembled global result."""
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map          # jax >= 0.4.35 stable path
-    except ImportError:                    # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     dp = dp_axis if dp_axis in mesh.axis_names else None
     spec = P(dp, None, sp_axis, None)           # (B, H, T, D)

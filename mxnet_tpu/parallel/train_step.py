@@ -465,12 +465,6 @@ class TrainStep:
                 raise ValueError(
                     "deterministic_reduction supports dp-only meshes; "
                     "got axis %r of size %d" % (ax, mesh.shape[ax]))
-        try:
-            shard_map = jax.shard_map
-            no_check = {"check_vma": False}
-        except AttributeError:  # older jax spelling (and kwarg name)
-            from jax.experimental.shard_map import shard_map
-            no_check = {"check_rep": False}
         ndp = mesh.shape["dp"]
 
         def ordered_mean(gathered):
@@ -495,11 +489,11 @@ class TrainStep:
             # check_vma=False: outputs ARE replicated (all_gather +
             # identical per-device arithmetic) but the static checker
             # cannot infer it through the gathered-and-resummed chain.
-            loss, new_aux, grads = shard_map(
+            loss, new_aux, grads = jax.shard_map(
                 per_shard, mesh=mesh,
                 in_specs=(rep, rep, data_spec, data_spec, rep),
                 out_specs=(rep, rep, rep),
-                **no_check)(pvals, aux_vals, x, y, key)
+                check_vma=False)(pvals, aux_vals, x, y, key)
             return (loss, new_aux), grads
 
         return grad_of

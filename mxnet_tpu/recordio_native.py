@@ -2,14 +2,18 @@
 
 The C++ scanner/reader is the data pipeline's high-throughput path: a
 whole-file index scan and random-access record reads with no Python
-per-frame overhead. Built on demand with g++ (cached as a .so next to
-the source); every entry point degrades to the pure-python
+per-frame overhead. Built on demand with g++ (cached next to the source
+as a .so named by the source's content hash, so a library is only ever
+loaded if it was built from the source as it stands — file times mean
+nothing after a checkout or a copy of the tree); every entry point
+degrades to the pure-python
 implementation in `mxnet_tpu.recordio` when the toolchain or the build
 is unavailable — the wire format is identical.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +22,6 @@ __all__ = ["available", "native_index", "native_read_at"]
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src", "recordio_core.cc")
-_SO = os.path.splitext(_SRC)[0] + ".so"
 _lock = threading.Lock()
 _lib = None
 _tried = False
@@ -34,23 +37,25 @@ def _load():
             return _lib
         _tried = True
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            with open(_SRC, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+            so = "%s.%s.so" % (os.path.splitext(_SRC)[0], digest)
+            if not os.path.exists(so):
                 # build to a private temp path, then atomically rename:
                 # concurrent processes (DataLoader workers, parallel
                 # pytest) must never dlopen a half-written .so — the
                 # per-process lock cannot serialize across processes
-                tmp = "%s.build.%d" % (_SO, os.getpid())
+                tmp = "%s.build.%d" % (so, os.getpid())
                 try:
                     subprocess.run(
                         ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
                          _SRC, "-o", tmp],
                         check=True, capture_output=True, timeout=120)
-                    os.replace(tmp, _SO)
+                    os.replace(tmp, so)
                 finally:
                     if os.path.exists(tmp):
                         os.unlink(tmp)
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             # binding stays inside the try: a stale .so missing a
             # symbol must degrade to the python fallback, not raise
             lib.rio_index.restype = ctypes.c_longlong

@@ -117,7 +117,7 @@ The goodput ledger (ISSUE 20) folds all of the above into the run-level
 answer — "how much of the wall-clock was useful work":
 
 * :mod:`.goodput` — :class:`GoodputLedger`: a mutually-exclusive,
-  collectively-exhaustive goodput/badput taxonomy (device_compute vs.
+  collectively-exhaustive goodput/badput category set (device_compute vs.
   compile / input_stall / h2d / exposed_comm / checkpoint /
   restart_replay / hang_recovery / idle / other) whose categories sum
   to wall-clock within a closure tolerance; durable per-rank

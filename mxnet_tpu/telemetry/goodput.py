@@ -8,7 +8,7 @@ many seconds were useful training/serving work, and which subsystem ate
 the rest?" — the number preemptible-TPU spend is budgeted against.
 :class:`GoodputLedger` closes that gap by folding the telemetry the
 system already emits into a mutually-exclusive, collectively-exhaustive
-category taxonomy whose members are REQUIRED to sum to wall-clock:
+category set whose members are REQUIRED to sum to wall-clock:
 
 ==================  ==========================================================
 ``device_compute``  goodput — the device chewing the fused step
@@ -79,8 +79,8 @@ __all__ = ["GoodputLedger", "CATEGORIES", "GOODPUT_CATEGORIES",
            "ledger_name", "install", "uninstall", "active_ledger",
            "serving_snapshot", "fleet_snapshot", "load_ledger"]
 
-# The MECE taxonomy. Order is the report/render order: goodput first,
-# then badput by "how directly fixable", idle/other last.
+# The MECE category set. Order is the report/render order: goodput
+# first, then badput by "how directly fixable", idle/other last.
 CATEGORIES = ("device_compute", "compile", "input_stall", "h2d",
               "exposed_comm", "checkpoint", "restart_replay",
               "hang_recovery", "idle", "other")

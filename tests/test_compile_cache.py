@@ -186,6 +186,34 @@ def test_cached_function_hit_miss_counters(tmp_path):
     assert cf2.num_hits == 1
 
 
+@pytest.mark.parametrize("first,count", [(3, 1), (2, 4)])
+def test_warm_load_runs_on_the_devices_it_was_compiled_for(tmp_path, first,
+                                                           count):
+    """A one-device (or sub-mesh) executable reloaded on the 8-device
+    backend executes on ITS devices: the installed loader's default
+    assigns every device of the backend and the first call then dies
+    with a shard-count mismatch."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    cc.configure(str(tmp_path))
+    jnp = _jnp()
+    devices = jax.devices()[first:first + count]
+    sharding = NamedSharding(Mesh(np.asarray(devices), ("dp",)), P("dp"))
+    x = jax.device_put(jnp.arange(8.0), sharding)
+
+    def f(a):
+        return a * 2 + 1
+
+    kwargs = dict(in_shardings=(sharding,), out_shardings=sharding)
+    want = np.asarray(cc.cached_compile(f, "t_dev", **kwargs)(x))
+    warm = cc.cached_compile(f, "t_dev", **kwargs)
+    out = warm(x)
+    assert warm.num_compiles == 0 and warm.num_hits == 1
+    assert {s.device for s in out.addressable_shards} == set(devices)
+    assert np.array_equal(np.asarray(out), want)
+
+
 def test_truncated_entry_is_counted_miss_and_recompiles(tmp_path,
                                                         fault_fs):
     """fault_fs truncate-on-close: the entry commits TORN; the next

@@ -35,7 +35,7 @@ def _ledger(tmp_path=None, **kw):
         rank=kw.pop("rank", 0), **kw)
 
 
-# -- taxonomy + closure -------------------------------------------------------
+# -- categories + closure -----------------------------------------------------
 
 def test_direct_mode_books_steps_and_derives_idle():
     clock = _FakeClock()

@@ -1,0 +1,69 @@
+"""The chip's compiler, asked without the chip: the three flash-attention
+kernels at real widths, compiled for a described TPU v5e (2x2). What
+interpret mode cannot refuse — a block the lowering does not tile, more
+VMEM than a kernel may use — is refused here, at no chip time.
+
+One file, on purpose: only one process may hold the TPU's library, the
+worker that is given this file loads it on the first test, and a second
+file could land on another worker. The topology is described inside the
+fixture, never at import, and the compile runs in the test's own process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import pallas_attention as pa
+
+_B, _H, _T = 4, 16, 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep it out.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _launch(kernel, d, scale):
+    """(function, argument shapes) of one kernel's pallas_call at
+    (4, 16, 2048, d) bf16, causal, blocks 128/128, interpret=False."""
+    bh = _B * _H
+    qkv = ((bh, _T, d), jnp.bfloat16)
+    row = ((bh, 1, _T), jnp.float32)
+    static = (scale, True, 128, 128, False)
+    if kernel == "fwd":
+        return (lambda q, k, v: pa._flash_forward(q, k, v, *static),
+                [((_B, _H, _T, d), jnp.bfloat16)] * 3)
+    fn = {"dkv": pa._flash_dkv, "dq": pa._flash_dq}[kernel]
+    return (lambda *a: fn(*a, *static), [qkv] * 4 + [row] * 2)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+def test_flash_kernel_compiles_for_v5e(one_chip, kernel, d):
+    fn, shapes = _launch(kernel, d, d ** -0.5)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    # Flash: the (b*h, T, T) score tensor never exists, nor a fraction.
+    scores = _B * _H * _T * _T * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores // 4

@@ -44,7 +44,7 @@ def _categories(snap):
     from mxnet_tpu.telemetry import goodput
 
     cats = snap.get("categories") or {}
-    # Taxonomy order first, then anything a newer format added.
+    # Declared category order first, then anything a newer format added.
     ordered = [c for c in goodput.CATEGORIES if c in cats]
     ordered += sorted(c for c in cats if c not in goodput.CATEGORIES)
     return [(c, float(cats[c])) for c in ordered]

@@ -9,9 +9,13 @@ this host with the DMLC_* env contract.
 
 TPU deployment note: on real pods each worker process owns that host's
 TPU chips while servers/schedulers pin to CPU (kvstore_server.py sets
-JAX_PLATFORMS=cpu for those roles); on a dev machine workers share the
-chip. Cluster launchers (gke/mpi) are out of scope here — `local` covers
-the reference's own test matrix; ssh raises with guidance.
+JAX_PLATFORMS=cpu for those roles). A chip belongs to one process at a
+time, so the N workers `local` starts on one host cannot share it: the
+launcher itself never touches JAX, but give at most one worker the chip
+and pin the rest to the CPU through `worker_envs` (JAX_PLATFORMS=cpu), as
+the tests do for all of them. Cluster launchers (gke/mpi) are out of
+scope here — `local` covers the reference's own test matrix; ssh raises
+with guidance.
 
 Usage::
 
