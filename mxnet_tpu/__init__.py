@@ -29,6 +29,9 @@ from . import util
 from . import ndarray
 from . import ndarray as nd
 from . import autograd
+# Not lazy: importing it registers the compile log's listeners, so that
+# every program built after `import mxnet_tpu` is in compile.build_log().
+from . import compile
 from .ndarray import NDArray
 
 # Subsystems are imported lazily via __getattr__ to keep import fast and
@@ -42,7 +45,6 @@ _LAZY = {
     "lr_scheduler": ".lr_scheduler",
     "callback": ".callback",
     "checkpoint": ".checkpoint",
-    "compile": ".compile",
     "data": ".data",
     "kvstore": ".kvstore",
     "kv": ".kvstore",

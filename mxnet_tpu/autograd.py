@@ -23,6 +23,7 @@ import threading
 import numpy as np
 
 from .ops import registry as _reg
+from .telemetry import trace as _trace
 
 __all__ = [
     "record", "pause", "train_mode", "predict_mode", "is_recording",
@@ -188,6 +189,7 @@ def _vjp_runner(op, attrs_key, attrs, named=()):
             _, pullback = jax.vjp(f, *inputs)
             return pullback(tuple(cotangents))
 
+        run.__name__ = op.vjp_name           # the executable's name
         fn = jax.jit(run)
         _VJP_CACHE[key] = fn
     return fn
@@ -236,6 +238,11 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
     """Run backward from `heads`, writing into each marked variable's
     `.grad` per its grad_req — or, with `variables`, returning their
     gradients (reference: Imperative::Backward imperative.cc:270)."""
+    with _trace.span("autograd::backward"):
+        return _backward(heads, head_grads, variables)
+
+
+def _backward(heads, head_grads, variables):
     from .ndarray.ndarray import NDArray
 
     if head_grads is None:
@@ -279,14 +286,16 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
                for c, a in zip(cts, node.out_avals)]
         if getattr(node, "custom_backward", None) is not None:
             ct_nds = [NDArray(c) for c in cts]
-            res = node.custom_backward.backward(*ct_nds)
+            with _trace.span("autograd::vjp", op=node.op.name):
+                res = node.custom_backward.backward(*ct_nds)
             if not isinstance(res, (tuple, list)):
                 res = (res,)
             in_grads = [r._data if isinstance(r, NDArray) else r for r in res]
         else:
             runner = _vjp_runner(node.op, node.attrs_key, node.attrs,
                                  node.named)
-            in_grads = runner(tuple(node.inputs), tuple(cts))
+            with _trace.span("autograd::vjp", op=node.op.name):
+                in_grads = runner(tuple(node.inputs), tuple(cts))
         for parent, g in zip(node.parents, in_grads):
             if parent is None or g is None:
                 continue
@@ -311,13 +320,14 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
             out.append(NDArray(g, ctx=v.context))
         return out
 
-    for nd, g in leaf_grads.values():
-        if nd._grad_req == "null" or nd._grad is None:
-            continue
-        if nd._grad_req == "add":
-            nd._grad._set_data(nd._grad._data + g)
-        else:
-            nd._grad._set_data(g.astype(nd._grad.dtype))
+    with _trace.span("autograd::commit", leaves=len(leaf_grads)):
+        for nd, g in leaf_grads.values():
+            if nd._grad_req == "null" or nd._grad is None:
+                continue
+            if nd._grad_req == "add":
+                nd._grad._set_data(nd._grad._data + g)
+            else:
+                nd._grad._set_data(g.astype(nd._grad.dtype))
     return None
 
 

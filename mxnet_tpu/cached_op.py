@@ -22,13 +22,10 @@ so one executable serves every call with fresh masks.
 """
 from __future__ import annotations
 
-import time
-
 from . import autograd
 from . import random as _random
 from .ops.registry import Operator, _freeze
 from .ndarray.ndarray import NDArray, _wrap_outputs
-from .telemetry import memstats as _ms
 from .telemetry import metrics as _tm
 from .telemetry import trace as _trace
 
@@ -108,18 +105,18 @@ class CachedOp:
             return out._data if isinstance(out, NDArray) else out
 
         self._op = Operator(name, pure, needs_rng=True, train_aware=True)
+        self._op.fwd_name = "mx_cached_fwd"
+        self._op.vjp_name = "mx_cached_vjp"
         # Persistent compilation cache (mxnet_tpu.compile): when enabled,
         # this op's per-signature executables build through the cached
         # seam — a warm restart (or an elastic peer with a warm pod
-        # cache) traces but does NOT compile, and the wrapper does the
-        # compile accounting (only real XLA compiles count). The
-        # attrs/named key is restart-stable; the per-process op counter
-        # in `name` deliberately is NOT part of the cache key — the HLO
+        # cache) traces but does NOT compile. The attrs/named key is
+        # restart-stable; the per-process op counter in `name`
+        # deliberately is NOT part of the cache key — the HLO
         # fingerprint identifies the graph.
         from . import compile as _cc
 
-        self._cc_active = _cc.enabled()
-        if self._cc_active:
+        if _cc.enabled():
             self._op.jit_wrapper = lambda fn, key: _cc.cached_compile(
                 fn, "cached_op", key_parts=("cached_op", key))
         # Off-ladder shape canonicalization (recompile elimination):
@@ -136,8 +133,6 @@ class CachedOp:
 
         from .ops import registry as _reg
 
-        traces_before = self.num_traces
-        t0 = time.perf_counter()
         with _trace.span("cached_op::execute", op=self._op.name):
             if autograd.is_recording():
                 raw = autograd._record_op(self._op, list(args), arrays,
@@ -147,14 +142,6 @@ class CachedOp:
             else:
                 raw = _reg.invoke_raw(self._op, arrays, attrs)
                 result = _wrap_outputs(raw, ctx, out=out)
-        if self.num_traces != traces_before and not self._cc_active:
-            # This call filled the executable cache (new shape
-            # signature): its wall time is trace + XLA compile — the
-            # compile-accounting seam (mx_compile_seconds). Under the
-            # persistent cache the wrapper accounts real compiles
-            # itself — a trace satisfied from the cache is NOT a
-            # compile and must not pollute the warm-restart contract.
-            _ms.observe_compile("cached_op", time.perf_counter() - t0)
         return result
 
     def pad_to_buckets(self, policy):
@@ -235,12 +222,8 @@ class CachedOp:
         if canon is not None:
             bucket, rows = canon
             arrays = self._pad_inputs(arrays, bucket, rows)
-        traces_before = self.num_traces
-        t0 = time.perf_counter()
         with _trace.span("cached_op::inference", op=self._op.name):
             raw = _reg.invoke_raw(self._op, arrays, {"training": False})
-        if self.num_traces != traces_before and not self._cc_active:
-            _ms.observe_compile("cached_op", time.perf_counter() - t0)
         if canon is not None:
             # Slice the padded rows back out (batch-dim outputs only —
             # a scalar/aggregate output is returned as computed).

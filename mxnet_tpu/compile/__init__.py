@@ -51,7 +51,7 @@ import threading
 import time
 
 from .store import CompileCacheStore, make_key, entry_name, ENTRY_FORMAT
-from ..telemetry import memstats as _ms
+from .buildlog import build_log, step_done, install as _install_log
 from ..telemetry import metrics as _tm
 from ..telemetry import trace as _trace
 from .. import log as _log
@@ -60,7 +60,13 @@ __all__ = ["CachedFunction", "CompileCacheStore", "cached_compile",
            "maybe_cached_jit", "configure", "reset", "enabled",
            "active_store", "attach_kvstore", "set_distributor",
            "shared_filesystem", "backend_fingerprint", "make_key",
-           "entry_name", "ENTRY_FORMAT", "enable_jax_cache"]
+           "entry_name", "ENTRY_FORMAT", "enable_jax_cache", "build_log",
+           "step_done"]
+
+# The compile log (buildlog.py) listens to JAX's compile events from
+# here on: every program traced, lowered or built after this import is
+# in compile.build_log().
+_install_log()
 
 _hits_total = _tm.REGISTRY.counter(
     "mx_compile_cache_hits_total",
@@ -98,13 +104,24 @@ def enable_jax_cache():
     wins when set (JAX reads it itself, nothing is set here); otherwise
     the cache lives at ``<checkout>/.jax_cache``. The directory is part
     of the cache key, so it is a fixed path, never one named after a
-    temporary directory, a pid or the time. Returns the directory."""
+    temporary directory, a pid or the time. Returns the directory.
+
+    Every program is stored, however quickly it compiled, unless the
+    operator set ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``. JAX's
+    default keeps what took a second or more, and the framework's
+    set-up is mostly programs under it (``compile.build_log()``:
+    parameter initialisers, eager ops, the input pool), each compiled
+    again by every process and stored only by the one run in which it
+    happened to take longer, so that a warm start depended on how many
+    runs the directory had seen."""
+    import jax
+
     directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not directory:
-        import jax
-
         directory = _JAX_CACHE_DEFAULT
         jax.config.update("jax_compilation_cache_dir", directory)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return directory
 
 
@@ -284,7 +301,7 @@ class CachedFunction:
     the compile time saved.
     """
 
-    def __init__(self, fn, site, key_parts=(), store=None, observe=True,
+    def __init__(self, fn, site, key_parts=(), store=None,
                  publish=None, **jit_kwargs):
         import jax
 
@@ -292,7 +309,6 @@ class CachedFunction:
         self.site = site
         self.key_parts = tuple(key_parts)
         self._store = store
-        self._observe = observe
         # publish: None = ask the distributor (rank 0 publishes);
         # True/False force.
         self._publish = publish
@@ -352,14 +368,14 @@ class CachedFunction:
                 return compiled
         # Miss: pay the real XLA compile (the one cost this subsystem
         # exists to delete on every later start).
+        # (mx_compile_seconds is observed by the compile log, from the
+        # build event this compile raises.)
         _misses_total.labels(site=self.site).inc()
         t0 = time.perf_counter()
         with _trace.span("compile_cache::compile", site=self.site):
             compiled = lowered.compile()
         dt = time.perf_counter() - t0
         self.num_compiles += 1
-        if self._observe:
-            _ms.observe_compile(self.site, dt)
         _record_cost(self.site, key, compiled)
         if store is not None:
             self._commit(store, key, compiled, dt)
@@ -524,21 +540,19 @@ def _deserialize(blob):
 
 # -- the seam API --------------------------------------------------------------
 
-def cached_compile(fn, site, key_parts=(), observe=True, **jit_kwargs):
+def cached_compile(fn, site, key_parts=(), **jit_kwargs):
     """Wrap ``fn`` in a :class:`CachedFunction` against the active
     store (the store may be attached later; a disabled cache just means
     every signature compiles, exactly like ``jax.jit``)."""
-    return CachedFunction(fn, site, key_parts=key_parts, observe=observe,
-                          **jit_kwargs)
+    return CachedFunction(fn, site, key_parts=key_parts, **jit_kwargs)
 
 
-def maybe_cached_jit(fn, site, key_parts=(), observe=True, **jit_kwargs):
+def maybe_cached_jit(fn, site, key_parts=(), **jit_kwargs):
     """The three compile seams' entry point: a :class:`CachedFunction`
     when the cache is enabled, else a plain ``jax.jit`` — zero behavior
     (and zero overhead) change while disabled."""
     if enabled():
-        return cached_compile(fn, site, key_parts=key_parts,
-                              observe=observe, **jit_kwargs)
+        return cached_compile(fn, site, key_parts=key_parts, **jit_kwargs)
     import jax
 
     return jax.jit(fn, **jit_kwargs)

@@ -73,8 +73,6 @@ storm — `telemetry.StepMonitor.attach_fused` watches it through the
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from . import env as _env
@@ -82,7 +80,6 @@ from .ndarray.ndarray import NDArray
 from .ndarray import sparse as _sp
 from .ops import registry as _reg
 from .ops import optimizer_ops as _oo
-from .telemetry import memstats as _ms
 from .telemetry import metrics as _tm
 from .telemetry import trace as _trace
 
@@ -366,7 +363,7 @@ class _ApplyChunk:
 
     __slots__ = ("exec_fn", "flatten_fn", "shapes", "sizes", "offsets",
                  "n", "k", "flat_w", "flat_s", "weights", "wver",
-                 "views", "state_objs", "stale", "compiled", "cc",
+                 "views", "state_objs", "stale",
                  "mp", "base_k", "with_scale")
 
     def __init__(self, exec_fn, flatten_fn, shapes, sizes, offsets, k):
@@ -387,8 +384,6 @@ class _ApplyChunk:
         self.views = []
         self.state_objs = []
         self.stale = True
-        self.compiled = False      # first exec dispatch pays XLA compile
-        self.cc = False            # exec_fn rides the persistent cache
 
 
 class FusedApplier:
@@ -548,19 +543,22 @@ class FusedApplier:
                 for i in range(n))
             return outs, new_w, tuple(new_s)
 
-        def flat_cat(*xs):
+        def mx_flatten_chunk(*xs):
             parts = [x.ravel() for x in xs]
             if pad:
                 parts.append(jnp.zeros((pad,), xs[0].dtype))
             return parts[0] if len(parts) == 1 else \
                 jnp.concatenate(parts)
 
+        # The executables' names (no counter: they enter the cache key).
+        # The flatten is not `mx_fused_*`: the compile log files that
+        # prefix under site fused_apply, where it never counted.
+        chunk_fn.__name__ = "mx_fused_" + spec.name
+
         # Persistent compilation cache (mxnet_tpu.compile): the chunk
         # executable is THE fused_apply compile site — under the cache a
-        # warm restart deserializes it instead of recompiling, and the
-        # wrapper does the compile accounting (ch.compiled timing below
-        # stays for the uncached path). The flatten executable rides the
-        # same seam uncounted (it was never part of mx_compile_seconds).
+        # warm restart deserializes it instead of recompiling. The
+        # flatten executable rides the same seam.
         from . import compile as _cc
 
         # Donation (TPU/GPU): the flat weight and state inputs alias
@@ -577,14 +575,12 @@ class FusedApplier:
         ch = _ApplyChunk(
             _cc.maybe_cached_jit(chunk_fn, "fused_apply", key_parts=key,
                                  **jit_kwargs),
-            _cc.maybe_cached_jit(flat_cat, "fused_flatten",
-                                 key_parts=("fused_flatten", repr(sig)),
-                                 observe=False),
+            _cc.maybe_cached_jit(mx_flatten_chunk, "fused_flatten",
+                                 key_parts=("fused_flatten", repr(sig))),
             tuple(shapes), sizes, offsets, k)
         ch.mp = spec.mp
         ch.base_k = spec.base_k
         ch.with_scale = with_scale
-        ch.cc = isinstance(ch.exec_fn, _cc.CachedFunction)
         self._chunks[sig] = ch
         self.num_compiles += 1
         _apply_compiles.labels(optimizer=spec.name).inc()
@@ -674,23 +670,11 @@ class FusedApplier:
         if ch.with_scale:
             scale_args = (jnp.asarray(
                 np.float32(1.0 if grad_scale is None else grad_scale)),)
-        # Under the persistent cache the CachedFunction accounts real
-        # compiles itself (a warm restart's first dispatch is a load,
-        # not a compile — it must not count).
-        t_compile = None if (ch.compiled or ch.cc) else time.perf_counter()
         outs, new_w, new_s = _dispatch(
             "trainer::fused_apply", ch.exec_fn,
             tuple(e[2]._data for e in group), ch.flat_w,
             tuple(ch.flat_s), lrs, wds, *scale_args,
             optimizer=spec.name, params=len(group))
-        if t_compile is not None:
-            # jit compiles synchronously inside the first dispatch (the
-            # execution itself stays async), so this wall time is the
-            # executable-cache fill a persistent compile cache would
-            # delete (mx_compile_seconds{site="fused_apply"}).
-            ch.compiled = True
-            _ms.observe_compile("fused_apply",
-                                time.perf_counter() - t_compile)
         # Inlined _set_data: this commit loop runs once per parameter
         # per step and the engine-mode check hoists out of it.
         naive = _engine.is_naive()
@@ -904,8 +888,10 @@ class _Bucket:
             import jax
             import jax.numpy as jnp
 
-            self._sumsq = jax.jit(
-                lambda f: jnp.sum(jnp.square(f.astype(jnp.float32))))
+            def mx_bucket_sumsq(f):      # the executable's name
+                return jnp.sum(jnp.square(f.astype(jnp.float32)))
+
+            self._sumsq = jax.jit(mx_bucket_sumsq)
         return _dispatch("trainer::bucket_sumsq", self._sumsq,
                          flat._data, bucket=self.id)
 
@@ -915,8 +901,10 @@ class _Bucket:
             import jax
             import jax.numpy as jnp
 
-            self._flatten = jax.jit(lambda *gs: jnp.concatenate(
-                [g.ravel() for g in gs]))
+            def mx_bucket_flatten(*gs):  # the executable's name
+                return jnp.concatenate([g.ravel() for g in gs])
+
+            self._flatten = jax.jit(mx_bucket_flatten)
         flat = _dispatch("trainer::bucket_flatten", self._flatten,
                          *[a._data for a in arrays],
                          bucket=self.id, params=len(self.keys))
@@ -931,12 +919,12 @@ class _Bucket:
             offs = np.cumsum([0] + self.sizes)
             shapes = self.shapes
 
-            def split(f):
+            def mx_bucket_unflatten(f):  # the executable's name
                 return tuple(
                     f[offs[i]:offs[i + 1]].reshape(shapes[i])
                     for i in range(len(shapes)))
 
-            self._unflatten = jax.jit(split)
+            self._unflatten = jax.jit(mx_bucket_unflatten)
         return _dispatch("trainer::bucket_unflatten", self._unflatten,
                          flat._data, bucket=self.id,
                          params=len(self.keys))

@@ -28,6 +28,7 @@ import queue
 import threading
 import time
 
+from .. import compile as _cc
 from .. import env as _env
 from .. import optimizer as opt
 from .. import ndarray as nd
@@ -293,8 +294,10 @@ class Trainer:
         # and kvstore wire message below then carries the step's trace.
         ctx = _xtrace.current()
         with _xtrace.activate(ctx if ctx is not None
-                              else _xtrace.new_root()):
+                              else _xtrace.new_root()), \
+                _trace.span("trainer::step"):
             self._step_traced(batch_size, ignore_stale_grad)
+        _cc.step_done()
 
     def _step_traced(self, batch_size, ignore_stale_grad=False):
         self._optimizer.rescale_grad = self._scale / batch_size

@@ -27,12 +27,29 @@ __all__ = ["Operator", "register", "get", "list_all_ops", "invoke", "OP_REGISTRY
 
 OP_REGISTRY: dict[str, "Operator"] = {}
 
-# Executable launches since import — every imperative jitted dispatch
-# (invoke_raw's non-inlined path) plus the fused-update path's coalesced
-# launches (fused_update._dispatch) bump this. Traced-inline calls do
-# NOT count: they fuse into an enclosing executable instead of
-# launching one. Read through test_utils.count_dispatches().
+# Executable launches since import through two seams: invoke_raw's
+# non-inlined path (an op called while nothing records) and the
+# fused-update path's coalesced launches (fused_update._dispatch).
+# NOT counted: a forward launched while recording
+# (autograd._record_op), the vjp launches of backward(), raw jnp calls
+# (backward's per-leaf astype among them), and traced-inline calls,
+# which fuse into an enclosing executable instead of launching one.
+# Read through test_utils.count_dispatches().
 DISPATCHES = [0]
+
+
+def named_fn(fn, name):
+    """`fn` behind a function called `name`: what is handed to
+    ``jax.jit`` at a framework seam, so that the executable is
+    ``jit_<name>`` in the device trace, in JAX's compile events and in
+    the compile log, and not the name of an inner closure. The name
+    enters the module text and so the persistent-cache key: it must be
+    the same in every process (no counter, no id)."""
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
 
 
 def _freeze(value):
@@ -74,6 +91,11 @@ class Operator:
         # jax.jit — generic small ops never pay the wrapper's per-call
         # signature hash; only whole-graph CachedOps opt in.
         self.jit_wrapper = None
+        # Names of this op's forward and vjp executables (named_fn); a
+        # CachedOp, whose `name` holds a per-process counter, overrides
+        # both.
+        self.fwd_name = "mx_op_" + name
+        self.vjp_name = "mx_vjp_" + name
         self._jit_cache: dict = {}
         # attrs_key -> True when the trace under those attrs consumed no
         # randomness (set by CachedOp.pure). Such calls reuse one cached
@@ -102,12 +124,13 @@ class Operator:
         key = (attrs_key, named)
         hit = self._jit_cache.get(key)
         if hit is None:
+            fn = named_fn(self.bound_fn(attrs, named), self.fwd_name)
             if self.jit_wrapper is not None:
-                hit = self.jit_wrapper(self.bound_fn(attrs, named), key)
+                hit = self.jit_wrapper(fn, key)
             else:
                 import jax
 
-                hit = jax.jit(self.bound_fn(attrs, named))
+                hit = jax.jit(fn)
             self._jit_cache[key] = hit
         return hit
 

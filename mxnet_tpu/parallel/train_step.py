@@ -24,10 +24,10 @@ import jax
 import jax.numpy as jnp
 
 from .. import autograd
+from .. import compile as _cc
 from .. import random as _random
 from ..telemetry import attribution as _attr
 from ..telemetry import healthplane as _hp
-from ..telemetry import memstats as _ms
 from ..telemetry import metrics as _tm
 from ..telemetry import trace as _trace
 from ..telemetry import watchdog as _watchdog
@@ -128,7 +128,6 @@ class TrainStep:
         self._jitted = None
         self._materialized = False
         self._multiproc = False
-        self._compile_pending = False
         # Readiness slot for /readyz: claimed lazily on the FIRST
         # __call__ (a TrainStep built but never stepped — eval-only, a
         # discarded retune — must not leave a permanently not-ready
@@ -565,6 +564,8 @@ class TrainStep:
                         p, g, opt_state[name], lr, t)
             return new_p, new_s, new_aux, loss
 
+        step.__name__ = "mx_train_step"      # the executable's name
+
         shardings = self._shardings
         k = self._opt_n_states
         state_shardings = {n: tuple(shardings[n] for _ in range(k))
@@ -580,8 +581,6 @@ class TrainStep:
         # under the cache a warm restart deserializes it. key_parts are
         # the restart-stable configuration; param shapes/dtypes and the
         # step graph itself are covered by the HLO fingerprint.
-        from .. import compile as _cc
-
         self._jitted = _cc.maybe_cached_jit(
             step, "train_step",
             key_parts=("train_step", self.optimizer,
@@ -589,10 +588,6 @@ class TrainStep:
                        repr(self._dtype), self.deterministic_reduction),
             in_shardings=in_shardings, out_shardings=out_shardings,
             donate_argnums=(0, 1, 2))
-        # Under the cache the wrapper accounts real compiles itself; a
-        # cache-hit first call must not count as a compile.
-        self._compile_pending = not isinstance(self._jitted,
-                                               _cc.CachedFunction)
 
     # -- public API -----------------------------------------------------------
 
@@ -664,11 +659,7 @@ class TrainStep:
             _trace.complete("train_step::step", t_start, t_end, step=t)
             _step_seconds.observe(t_end - t_start)
             _steps_total.inc()
-            if self._compile_pending:
-                # First call after a build pays whole-step trace + XLA
-                # compile — the compile-accounting seam.
-                self._compile_pending = False
-                _ms.observe_compile("train_step", t_end - t_start)
+            _cc.step_done()
             if not self._hp_ready:  # warmup compile done: ready
                 self._hp_ready = True
                 _hp.set_ready(self._hp_component)

@@ -12,10 +12,16 @@ chrome-trace spans + aggregate tables, grown to production scope):
 2. **Structured tracing** (:mod:`.trace`) — thread-aware span recording
    (``with trace.span("step", step=i):``) into bounded per-thread
    rings, flushed to chrome://tracing JSON (``trace.dump()``) loadable
-   in Perfetto alongside jax.profiler's XPlane capture. Spans are
-   emitted at every layer seam: CachedOp trace/execute, TrainStep
+   in Perfetto. A dump is on ``time.perf_counter()``'s clock, a
+   jax.profiler capture on the profiler's own: to see the program's
+   names against the device lines, load the capture, which carries
+   every ``span()`` opened while it ran as a ``TraceAnnotation`` of
+   the same name on its ``/host:CPU`` plane. Spans are emitted at
+   every layer seam: CachedOp trace/execute, autograd
+   backward/vjp/commit, Trainer step/allreduce/update, TrainStep
    step/dispatch, serving enqueue→device→reply, checkpoint
-   snapshot/write/commit.
+   snapshot/write/commit, and XLA trace/lower/build from the compile
+   log (``mxnet_tpu.compile.build_log()``).
 3. **Step-health monitor** (:mod:`.health`) — rolling step-time EWMA
    with slow-step outlier detection, recompile detection via the
    ``CachedOp.on_trace`` hook, and checkpoint-writer backlog watching,
@@ -32,7 +38,7 @@ Quick start::
     for i in range(num_steps):
         with monitor.step(i):
             loss = train_step(x, y)
-    trace.dump("chrome_trace.json")           # load in Perfetto
+    trace.dump("chrome_trace.json")           # perf_counter's clock
     print(telemetry.render_prometheus())
 
 ``telemetry.set_enabled(False)`` pauses both metric recording and span
@@ -75,7 +81,8 @@ Failure forensics (ISSUE 7) turns detection into evidence:
   step/batch-id provenance, optionally halting the job.
 * :mod:`.memstats` — ``mx_device_live_bytes``/``_buffers``/peak gauges
   sampled from the backend, and ``mx_compile_seconds{site}`` fed by the
-  CachedOp / fused-apply / TrainStep executable-cache-fill seams.
+  compile log from JAX's own compile events (one observation per XLA
+  compile, none for a persistent-cache hit).
 
 The fleet health plane (ISSUE 8) makes the pod operable from outside:
 

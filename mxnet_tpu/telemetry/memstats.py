@@ -13,16 +13,16 @@ ROADMAP direction 2's persistent compile cache) are judged against:
   :class:`DeviceMemoryMonitor` for a background cadence, or let a
   flight-recorder bundle capture one at the moment of failure.
 
-* **Compile time.** ``mx_compile_seconds{site}`` histogram, fed by the
-  framework's three executable-cache-fill seams (``site`` is the seam,
-  not the op — bounded cardinality): ``cached_op`` (CachedOp
-  trace+compile, detected via the ``num_traces``/``on_trace`` counter
-  the recompile detector already watches), ``fused_apply``
-  (FusedApplier's first dispatch of a freshly built chunk executable)
-  and ``train_step`` (TrainStep's first call after a build). Each
-  observation is the wall time of the call that paid the cache fill —
-  trace + XLA compile + first execute, compile-dominated — which is
-  exactly the cold-start cost a persistent compile cache would delete.
+* **Compile time.** ``mx_compile_seconds{site}`` histogram, observed by
+  the compile log (``mxnet_tpu.compile.buildlog``) from JAX's own
+  backend-compile events: one observation per XLA compile, with XLA's
+  seconds alone (tracing and lowering are in ``compile.build_log()``),
+  none for a program loaded from JAX's persistent cache or from the
+  repo's executable store. ``site`` is read off the executable's name —
+  ``cached_op`` (``mx_cached_fwd``/``mx_cached_vjp``), ``fused_apply``
+  (``mx_fused_<optimizer>``), ``train_step`` (``mx_train_step``), else
+  ``other`` — so its cardinality is bounded. This is the cold-start
+  cost a persistent compile cache deletes.
 """
 from __future__ import annotations
 
@@ -49,8 +49,8 @@ _peak_bytes = _metrics.REGISTRY.gauge(
     labels=("device",))
 _compile_seconds = _metrics.REGISTRY.histogram(
     "mx_compile_seconds",
-    "Executable-cache fill wall time (trace + XLA compile + first "
-    "execute) per compile site", labels=("site",))
+    "XLA compile seconds per compile site (from JAX's compile events; "
+    "persistent-cache hits are not counted)", labels=("site",))
 
 # Host-side peak watermark per device (backends without a native peak
 # counter): survives across samples, reset via reset_peak().
@@ -59,10 +59,9 @@ _peaks_lock = threading.Lock()
 
 
 def observe_compile(site, seconds):
-    """Record one executable-cache fill into
-    ``mx_compile_seconds{site=...}``. Called from the CachedOp /
-    FusedApplier / TrainStep compile seams; available for custom jit
-    seams too."""
+    """Record one compile into ``mx_compile_seconds{site=...}``.
+    Called by the compile log for every XLA compile JAX reports;
+    available for compiles made outside JAX too."""
     _compile_seconds.labels(site=site).observe(float(seconds))
 
 

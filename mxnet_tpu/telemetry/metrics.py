@@ -431,6 +431,15 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families = {}
+        self._collect_hooks = []
+
+    def on_collect(self, hook):
+        """Call ``hook()`` at the start of every :meth:`collect` (scrape,
+        aggregate snapshot): a count kept in a cheaper form on a hot
+        path (trace.py's ring drop cells) is folded into its family
+        when the registry is read, not when it moves."""
+        with self._lock:
+            self._collect_hooks.append(hook)
 
     def _get_or_create(self, cls, name, help, labels, **kwargs):
         if not _NAME_RE.match(name):
@@ -474,6 +483,8 @@ class Registry:
             self._families.pop(name, None)
 
     def collect(self):
+        for hook in list(self._collect_hooks):
+            hook()
         with self._lock:
             return list(self._families.values())
 

@@ -4,9 +4,23 @@ chrome://tracing JSON.
 The reference profiler wrote chrome-trace JSON spans straight from the
 engine (src/profiler/profiler.h:87); here the device truth lives in
 jax.profiler's XPlane output, and THIS module records the *framework*
-seams — CachedOp trace/execute, TrainStep step/dispatch, serving
-enqueue→device→reply, checkpoint snapshot/write/commit — so one
-Perfetto load shows queue wait next to device time.
+seams — CachedOp trace/execute, autograd backward/vjp/commit, Trainer
+step/allreduce/update, TrainStep step/dispatch, serving
+enqueue→device→reply, checkpoint snapshot/write/commit, XLA
+trace/lower/build (``compile.buildlog``) — so one Perfetto load shows
+queue wait next to device time.
+
+Two clocks, one of them shared. The rings (and so ``chrome_trace()``,
+``dump()`` and the streaming segments) are on ``time.perf_counter()``'s
+clock in microseconds; a ``jax.profiler`` capture is on the profiler's
+own. They are NOT the same timeline, so a dump is not what to lay beside
+a capture. Instead, while a capture is running every ``span()`` is also
+opened and closed as a ``jax.profiler.TraceAnnotation`` of the same name
+and args, so the capture's ``/host:CPU`` plane carries the program's
+names itself, against the device lines, with no shift. ``complete()``
+and ``instant()`` are not mirrored (a retroactive event cannot be opened
+in the past); while no capture runs the mirror costs one
+``TraceAnnotation.is_enabled()`` check per span.
 
 Design:
 
@@ -25,7 +39,7 @@ Design:
   request's queue-wait once it knows when dispatch started).
 * **Flush or stream.** ``chrome_trace()`` merges the rings into a
   ``{"traceEvents": [...]}`` dict; ``dump(path)`` writes it as JSON
-  loadable in Perfetto / chrome://tracing alongside the XPlane capture
+  loadable in Perfetto / chrome://tracing, on ``perf_counter``'s clock
   (atomically — tmp+fsync+rename, so a crash mid-dump leaves the
   previous file, never a truncated unloadable one). For multi-hour jobs
   ``drain()`` detaches the buffered events instead, feeding
@@ -44,6 +58,7 @@ import threading
 import time
 from collections import deque
 
+from . import metrics as _metrics
 from . import xtrace as _xtrace
 
 __all__ = ["span", "instant", "complete", "chrome_trace", "dump",
@@ -61,11 +76,15 @@ _MAX_DEAD_RINGS = 32
 _state = {"enabled": True, "capacity": _DEFAULT_CAPACITY,
           "span_ids": False}
 _registry_lock = threading.Lock()
-_rings = []            # [(thread, deque, drops-cell), ...]
+# [(thread, deque, drops-cell), ...]; a drops-cell is
+# [dropped, taken by take_dropped(), published to the counter].
+_rings = []
 _tls = threading.local()
-# mx_trace_dropped_spans_total{thread} — created lazily on the first
-# drop (trace<->metrics import late-binds through the package).
-_dropped_fam = None
+_dropped_fam = _metrics.REGISTRY.counter(
+    "mx_trace_dropped_spans_total",
+    "spans dropped by per-thread ring overflow", labels=("thread",))
+# jax.profiler.TraceAnnotation, bound at the first recorded span.
+_annotation = None
 # Process-unique span ids (itertools.count.__next__ is atomic under the
 # GIL, so no lock on the span hot path).
 _span_counter = itertools.count(1)
@@ -130,7 +149,7 @@ def _ring():
     if ring is None:
         thread = threading.current_thread()
         ring = deque(maxlen=_state["capacity"])
-        drops = [0]
+        drops = [0, 0, 0]
         with _registry_lock:
             _prune_locked()
             _rings.append((thread, ring, drops))
@@ -141,23 +160,30 @@ def _ring():
 
 def _append(record):
     """Ring append with overflow accounting: a full bounded deque drops
-    its oldest on append — count that (per-ring cell for the streaming
-    segment headers, ``mx_trace_dropped_spans_total{thread}`` for the
-    scrape) instead of losing spans silently."""
+    its oldest on append — count that in the ring's own cell and nothing
+    else, so a span on a full ring costs what one on an empty ring
+    costs. The segment headers (:func:`take_dropped`) and
+    ``mx_trace_dropped_spans_total{thread}`` (every registry collect)
+    are brought up to date from the cells when they are read."""
     ring = _ring()
     if len(ring) == ring.maxlen:
         _tls.drops[0] += 1
-        global _dropped_fam
-        if _dropped_fam is None:
-            from . import metrics as _metrics
-
-            _dropped_fam = _metrics.REGISTRY.counter(
-                "mx_trace_dropped_spans_total",
-                "spans dropped by per-thread ring overflow",
-                labels=("thread",))
-        _dropped_fam.labels(
-            thread=threading.current_thread().name).inc()
     ring.append(record)
+
+
+def _publish_dropped():
+    """Bring ``mx_trace_dropped_spans_total{thread}`` up to the rings'
+    drop cells (the registry calls this before every collect)."""
+    with _registry_lock:
+        entries = list(_rings)
+    for thread, _, drops in entries:
+        n = drops[0] - drops[2]
+        if n:
+            drops[2] += n
+            _dropped_fam.labels(thread=thread.name).inc(n)
+
+
+_metrics.REGISTRY.on_collect(_publish_dropped)
 
 
 def take_dropped():
@@ -165,16 +191,28 @@ def take_dropped():
     streaming exporter stamps this into each segment header as
     ``dropped`` so trace_merge can annotate the gap). Best-effort
     under concurrency: a drop racing the harvest lands in the next
-    harvest."""
+    harvest. Also brings the scrape's counter up to date."""
+    _publish_dropped()
     with _registry_lock:
         entries = list(_rings)
     total = 0
     for _, _, drops in entries:
-        n = drops[0]
+        n = drops[0] - drops[1]
         if n:
-            drops[0] -= n
+            drops[1] += n
             total += n
     return total
+
+
+def _capture_running():
+    """Whether a ``jax.profiler`` capture is recording host annotations
+    right now (one static call into the profiler's recorder)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation.is_enabled()
 
 
 class _Span:
@@ -183,10 +221,11 @@ class _Span:
     sampled :mod:`xtrace` context the span allocates an id, records
     ``trace_id``/``parent_span_id`` linkage, and installs itself as the
     parent of anything the block opens (including across process seams
-    via ``xtrace.inject``)."""
+    via ``xtrace.inject``). While a ``jax.profiler`` capture runs the
+    span is mirrored into it as a ``TraceAnnotation``."""
 
     __slots__ = ("_name", "_args", "_t0", "_id", "_link", "_token",
-                 "_pushed")
+                 "_pushed", "_mirror")
 
     def __init__(self, name, args):
         self._name = name
@@ -197,6 +236,7 @@ class _Span:
         self._link = None
         self._token = None
         self._pushed = False
+        self._mirror = None
         if _state["enabled"]:
             ctx = _xtrace.current()
             traced = ctx is not None and ctx.sampled
@@ -212,6 +252,10 @@ class _Span:
                 if traced:
                     self._link = (ctx.trace_id, ctx.span_id)
                     self._token = _xtrace._push_child(ctx, sid)
+            if _capture_running():
+                self._mirror = _annotation(self._name,
+                                           **(self._args or {}))
+                self._mirror.__enter__()
             self._t0 = time.perf_counter()
         else:
             self._t0 = None
@@ -219,6 +263,8 @@ class _Span:
 
     def __exit__(self, *exc):
         t0 = self._t0
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
         if self._token is not None:
             _xtrace._pop(self._token)
         if self._pushed:
@@ -357,7 +403,7 @@ def drain(prune_dead=True):
         with _registry_lock:
             _rings[:] = [entry for entry in _rings
                          if entry[0].is_alive() or len(entry[1])
-                         or entry[2][0]]
+                         or entry[2][0] != entry[2][1]]
     return out
 
 

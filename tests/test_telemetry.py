@@ -237,7 +237,7 @@ def test_trace_dead_thread_rings_pruned():
     t.start()
     t.join()
     with trace._registry_lock:
-        dead = sum(1 for th, _ in trace._rings if not th.is_alive())
+        dead = sum(1 for th, _, _ in trace._rings if not th.is_alive())
     assert dead <= trace._MAX_DEAD_RINGS + 1
     # recent dead threads' events are still flushable
     assert any(e["name"] == "churn::mark"
