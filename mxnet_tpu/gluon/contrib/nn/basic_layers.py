@@ -81,11 +81,13 @@ class SyncBatchNorm(BatchNorm):
 
     TPU-native: under SPMD (`mxnet_tpu.parallel.TrainStep` /
     `pjit`-traced steps) the batch axis is sharded over the mesh, and
-    XLA lowers the batch-mean/variance reductions to global collectives
-    over ICI automatically — the statistics are already synchronized
-    across devices with no extra machinery, which is the entire point of
-    the reference's hand-written key-synchronized implementation.
-    `num_devices` is accepted for API parity and unused.
+    BatchNorm's training branch takes its statistics as two plain sums
+    over the batch, `sum(x)` and `sum(x*x)` forward, `sum(dy)` and
+    `sum(dy*xhat)` backward: XLA lowers each pair to one all-reduce over
+    ICI, so the statistics are the global batch's with no extra
+    machinery, which is the entire point of the reference's hand-written
+    key-synchronized implementation. `num_devices` is accepted for API
+    parity and unused.
     """
 
     def __init__(self, in_channels=0, num_devices=None, momentum=0.9,

@@ -22,10 +22,13 @@ TPU rebuild notes:
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from .registry import register
 from .. import random as _random
+from ..telemetry import metrics as _tm
 
 
 def _jnp():
@@ -200,6 +203,77 @@ def _pooling(data, kernel=(), pool_type="max", global_pool=False, stride=(),
 # normalization
 # ---------------------------------------------------------------------------
 
+_batchnorm_train_traced = _tm.REGISTRY.counter(
+    "mx_batchnorm_train_traced_total",
+    "BatchNorm training branches traced (one per call of the operator "
+    "in train mode with batch statistics: 53 for one build of a "
+    "ResNet-50 step program)")
+
+
+@functools.lru_cache(maxsize=None)
+def _batch_norm_train_impl(axis, eps):
+    """The training branch's core, `(x, g, beta) -> (out, mean, var)`
+    for the channel axis `axis` (not negative), `g` and `beta` already
+    in the accumulator type `promote_types(x.dtype, float32)`, in which
+    `mean` and the biased `var` come back.
+
+    Two passes over the activation each way. Forward: `sum(x)` and
+    `sum(x*x)` over one read of `x` (plain `jnp` reductions: under a
+    mesh that shards the batch they become collectives, which is all
+    SyncBatchNorm is), `var = max(E[x^2] - E[x]^2, 0)`, then one pass
+    that writes `x * scale + shift` with the per-channel constants
+    folded. Backward: `sum(dy)` and `sum(dy * xhat)` over one read of
+    `dy` and `x`, then one pass that writes `dx`. The residuals are `x`
+    as it came in and three per-channel vectors: no centred, normalised
+    or widened copy of the activation is kept. The statistics are
+    outputs for the moving averages only; their cotangents are ignored,
+    as the operator stops their gradient.
+    """
+    import jax
+
+    jnp = _jnp()
+
+    def layout(x):
+        red = tuple(i for i in range(x.ndim) if i != axis)
+        shape = tuple(x.shape[i] if i == axis else 1 for i in range(x.ndim))
+        n = float(np.prod([x.shape[i] for i in red]))
+        return red, shape, n
+
+    def fwd(x, g, beta):
+        with jax.named_scope("batchnorm_train_fwd"):
+            red, shape, n = layout(x)
+            xf = x.astype(g.dtype)
+            mean = jnp.sum(xf, axis=red) / n
+            var = jnp.maximum(jnp.sum(xf * xf, axis=red) / n - mean * mean,
+                              0.0)
+            inv = jax.lax.rsqrt(var + eps)
+            scale = g * inv
+            shift = beta - mean * scale
+            out = xf * scale.reshape(shape) + shift.reshape(shape)
+            return (out.astype(x.dtype), mean, var), (x, mean, inv, g)
+
+    @jax.custom_vjp
+    def f(x, g, beta):
+        return fwd(x, g, beta)[0]
+
+    def bwd(res, cts):
+        with jax.named_scope("batchnorm_train_bwd"):
+            x, mean, inv, g = res
+            red, shape, n = layout(x)
+            dy = cts[0].astype(g.dtype)
+            xhat = (x.astype(g.dtype) - mean.reshape(shape)) \
+                * inv.reshape(shape)
+            dbeta = jnp.sum(dy, axis=red)
+            dgamma = jnp.sum(dy * xhat, axis=red)
+            dx = (g * inv).reshape(shape) * (
+                dy - (dbeta / n).reshape(shape)
+                - xhat * (dgamma / n).reshape(shape))
+            return dx.astype(x.dtype), dgamma, dbeta
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 @register("BatchNorm", aliases=("batch_norm",), train_aware=True)
 def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
                 momentum=0.9, fix_gamma=True, use_global_stats=False,
@@ -209,26 +283,38 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     Reference semantics (batch_norm-inl.h): train mode normalizes with
     batch stats and updates moving stats; eval mode uses moving stats.
     Functional form — caller commits the updated stats.
+
+    Train mode takes the batch mean and the biased variance in
+    `promote_types(data.dtype, float32)` (the reference's AccReal) and
+    keeps them there: the normalisation and the moving averages both
+    read the fp32 statistics, `out` has `data`'s type, and the backward
+    is written by hand (`_batch_norm_train_impl`).
     """
     import jax
 
     jnp = _jnp()
     g = jnp.ones_like(gamma) if fix_gamma else gamma
+    if training and not use_global_stats:
+        _batchnorm_train_traced.inc()
+        acc = jnp.promote_types(data.dtype, jnp.float32)
+        fn = _batch_norm_train_impl(axis % data.ndim, float(eps))
+        out, mean, var = fn(data, g.astype(acc), beta.astype(acc))
+        # The moving averages keep the type they had: that of the stored
+        # statistics, or the activation's where that is wider.
+        stat = jnp.promote_types(moving_mean.dtype, data.dtype)
+        new_mm = (moving_mean * momentum
+                  + jax.lax.stop_gradient(mean) * (1 - momentum)).astype(stat)
+        new_mv = (moving_var * momentum
+                  + jax.lax.stop_gradient(var) * (1 - momentum)).astype(stat)
+        return out, new_mm, new_mv
     shape = [1] * data.ndim
     shape[axis] = data.shape[axis]
     shape = tuple(shape)
-    red_axes = tuple(i for i in range(data.ndim) if i != axis)
-    if training and not use_global_stats:
-        mean = jnp.mean(data, axis=red_axes)
-        var = jnp.var(data, axis=red_axes)
-        new_mm = moving_mean * momentum + jax.lax.stop_gradient(mean) * (1 - momentum)
-        new_mv = moving_var * momentum + jax.lax.stop_gradient(var) * (1 - momentum)
-    else:
-        mean, var = moving_mean, moving_var
-        new_mm, new_mv = moving_mean, moving_var
-    inv = jax.lax.rsqrt(var.reshape(shape) + np.asarray(eps, data.dtype))
-    out = (data - mean.reshape(shape)) * inv * g.reshape(shape) + beta.reshape(shape)
-    return out, new_mm, new_mv
+    inv = jax.lax.rsqrt(moving_var.reshape(shape)
+                        + np.asarray(eps, data.dtype))
+    out = (data - moving_mean.reshape(shape)) * inv * g.reshape(shape) \
+        + beta.reshape(shape)
+    return out, moving_mean, moving_var
 
 
 @register("LayerNorm", aliases=("layer_norm",))

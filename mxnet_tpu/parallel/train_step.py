@@ -515,9 +515,10 @@ class TrainStep:
                  else a)
             mapping = {p: NDArray(cast(pvals[p.name])) for p in train_params}
             # Aux (BN running stats) stay fp32: in train mode they sit
-            # only on the EMA-update path, so the moments accumulate in
-            # fp32 (the reference's AccReal contract) while activations
-            # stay in the compute dtype.
+            # only on the EMA-update path, and BatchNorm hands that path
+            # the batch moments as it summed them, in fp32 (the
+            # reference's AccReal contract), while activations stay in
+            # the compute dtype.
             mapping.update({p: NDArray(aux_vals[p.name])
                             for p in aux_params})
             ov = override(mapping)
