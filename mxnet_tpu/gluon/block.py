@@ -58,6 +58,15 @@ class _BlockScope:
         return prefix, params
 
 
+class _HookHandle:
+    def __init__(self, hooks, hook):
+        self._hooks, self._hook = hooks, hook
+
+    def detach(self):
+        if self._hook in self._hooks:
+            self._hooks.remove(self._hook)
+
+
 class Block:
     """Base building block (reference: gluon/block.py:Block)."""
 
@@ -105,10 +114,16 @@ class Block:
         self._children[name or str(len(self._children))] = block
 
     def register_forward_hook(self, hook):
+        """`hook(block, args, out)` after every forward; returns a handle
+        whose `detach()` takes it off (reference: utils.py:HookHandle)."""
         self._forward_hooks.append(hook)
+        return _HookHandle(self._forward_hooks, hook)
 
     def register_forward_pre_hook(self, hook):
+        """`hook(block, args)` before every forward; see
+        `register_forward_hook`."""
         self._forward_pre_hooks.append(hook)
+        return _HookHandle(self._forward_pre_hooks, hook)
 
     def collect_params(self, select=None):
         """All parameters of self + descendants (reference: block.py:

@@ -1,6 +1,7 @@
 """Model zoo (reference: python/mxnet/gluon/model_zoo/ — vision models +
 pinned pretrained weights via model_store.py)."""
 from . import vision
+from . import deepseek_v3
 from .vision import get_model
 
-__all__ = ["vision", "get_model"]
+__all__ = ["vision", "deepseek_v3", "get_model"]

@@ -80,6 +80,7 @@ class Parameter:
         self._data = None  # dict ctx -> NDArray
         self._grad = None
         self._deferred_init = None
+        self._live = None  # see _bind_live
 
     @property
     def grad_stype(self):
@@ -186,6 +187,19 @@ class Parameter:
             raise RuntimeError(
                 "Parameter '%s' was not initialized on context %s" % (self.name, ctx))
         return self._data[ctx]
+
+    def _bind_live(self, source):
+        """`source()` returns the value where it lives now, or None. A
+        `parallel.TrainStep` owns the values of its net while it trains
+        (this parameter's own array is stale until `sync_to_net`) and
+        binds itself here, so that the one reader of non-gradient state
+        outside a step (`nn.SparseMoE`'s telemetry) finds what the last
+        step wrote."""
+        self._live = source
+
+    def _live_data(self):
+        value = self._live() if self._live is not None else None
+        return self.data() if value is None else NDArray(value)
 
     def list_data(self):
         self._check_initialized()

@@ -19,6 +19,15 @@ with the causal block-skip. Peak memory stays O(T·d) where the old
 re-derived `jax.vjp(blockwise_attention)` backward stored O(T²) of
 per-block probabilities across scan steps.
 
+Head widths: q and k share `d_qk`, v and the output have `d_v`, and the
+two may differ (latent attention: 192 and 128). Each width is the whole
+last dimension of its block, so any width the TPU lowering tiles is
+accepted (multiples of 8 seen compiled: 64, 128, 192; 192 is not padded
+to 256 in HBM); `scale` defaults to `d_qk ** -0.5`. Sequence lengths must
+divide by the block sizes. Block sizes left at None take the defaults
+below, chosen from a sweep on a TPU v5e at (1, 32, 4096, 192/128), causal
+(PERF.md, PR 28); a block longer than the sequence is cut to it.
+
 Registered as `_contrib_flash_attention` for `nd`/`sym` access.
 """
 from __future__ import annotations
@@ -28,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import metrics as _tm
 from .registry import register
 
 __all__ = ["flash_attention"]
@@ -92,9 +102,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     i = pl.program_id(1)
 
     def _accumulate():
-        q = q_ref[0]                            # (bq, d)
-        k = k_ref[0]                            # (bk, d)
-        v = v_ref[0]
+        q = q_ref[0]                            # (bq, d_qk)
+        k = k_ref[0]                            # (bk, d_qk)
+        v = v_ref[0]                            # (bk, d_v)
         s = _dot(q, k, _NT) * scale             # (bq, bk) fp32
         if causal:
             mask = _causal_mask(i, j, block_q, block_k)
@@ -126,13 +136,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse_ref[0] = _col_to_row(m_ref[...] + jnp.log(l))
 
 
+# (block_q, block_k) of all three kernels where the caller names none.
+# From the sweep at (1, 32, 4096, 192/128) bf16 causal on a TPU v5e
+# (PERF.md, PR 28).
+DEFAULT_BLOCK = (1024, 1024)
+
+
 def _block_sizes(tq, tk, block_q, block_k):
     block_q = min(block_q, tq)
     block_k = min(block_k, tk)
     if tq % block_q or tk % block_k:
         raise ValueError(
-            "sequence lengths (%d, %d) must divide by blocks (%d, %d)"
-            % (tq, tk, block_q, block_k))
+            "sequence lengths (%d, %d) must divide by blocks (%d, %d); "
+            "any block sizes that divide them are accepted, and the head "
+            "widths d_qk and d_v are free" % (tq, tk, block_q, block_k))
     return block_q, block_k
 
 
@@ -145,43 +162,59 @@ def _compiler_params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _last_k(i, block_q, block_k):
+    """The last k-block a causal q-block `i` needs."""
+    return ((i + 1) * block_q - 1) // block_k
+
+
+def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal):
+    """Block specs of a (bh, q-blocks, k-blocks) grid. Under `causal`
+    a skipped step names the block of the last computed one, so that
+    no copy is started for a block nobody reads."""
+    import jax.experimental.pallas as pl
+
+    def kj(i, j):
+        return jnp.minimum(j, _last_k(i, block_q, block_k)) if causal else j
+
+    q = lambda d: pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
+    k = lambda d: pl.BlockSpec((1, block_k, d),
+                               lambda b_, i, j: (b_, kj(i, j), 0))
+    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i))
+    return q(d_qk), q(d_v), k(d_qk), k(d_v), rowq
+
+
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
+    b, h, tq, d_qk = q.shape
+    tk, d_v = k.shape[2], v.shape[3]
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k)
     bh = b * h
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
+    q3 = q.reshape(bh, tq, d_qk)
+    k3 = k.reshape(bh, tk, d_qk)
+    v3 = v.reshape(bh, tk, d_v)
 
-    grid = (bh, tq // block_q, tk // block_k)
+    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(d_qk, d_v, block_q, block_k,
+                                               causal)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        out_shape=(jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((bh, tq, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-        ],
-        out_specs=(pl.BlockSpec((1, block_q, d),
-                                lambda b_, i, j: (b_, i, 0)),
-                   pl.BlockSpec((1, 1, block_q),
-                                lambda b_, i, j: (b_, 0, i))),
+        grid=(bh, tq // block_q, tk // block_k),
+        in_specs=[q_qk, k_qk, k_v],
+        out_specs=(q_v, rowq),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="mx_flash_fwd",
     )(q3, k3, v3)
-    return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
+    return out.reshape(b, h, tq, d_v), lse.reshape(b, h, tq)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
@@ -206,10 +239,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _accumulate():
-        q = q_ref[0]                          # (bq, d)
-        k = k_ref[0]                          # (bk, d)
-        v = v_ref[0]
-        do = do_ref[0]                        # (bq, d)
+        q = q_ref[0]                          # (bq, d_qk)
+        k = k_ref[0]                          # (bk, d_qk)
+        v = v_ref[0]                          # (bk, d_v)
+        do = do_ref[0]                        # (bq, d_v)
         st = _dot(k, q, _NT) * scale          # (bk, bq) = S^T
         if causal:
             st = jnp.where(_causal_mask(i, j, block_q, block_k,
@@ -217,8 +250,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         pt = jnp.exp(st - lse_ref[0])         # exact probabilities, P^T
         dpt = _dot(v, do, _NT)                # (bk, bq) = dP^T
         dst = pt * (dpt - dlt_ref[0]) * scale
-        dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d)
-        dk_acc[...] += _dot(dst.astype(q.dtype), q, _NN)      # (bk, d)
+        dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d_v)
+        dk_acc[...] += _dot(dst.astype(q.dtype), q, _NN)      # (bk, d_qk)
 
     if causal:
         # q-blocks entirely above the diagonal see zero probability
@@ -251,17 +284,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dlt_col[...] = _row_to_col(dlt_ref[0])
 
     def _accumulate():
-        q = q_ref[0]                          # (bq, d)
-        k = k_ref[0]                          # (bk, d)
-        v = v_ref[0]
-        do = do_ref[0]                        # (bq, d)
+        q = q_ref[0]                          # (bq, d_qk)
+        k = k_ref[0]                          # (bk, d_qk)
+        v = v_ref[0]                          # (bk, d_v)
+        do = do_ref[0]                        # (bq, d_v)
         s = _dot(q, k, _NT) * scale           # (bq, bk)
         if causal:
             s = jnp.where(_causal_mask(i, j, block_q, block_k), s, _NEG)
         p = jnp.exp(s - lse_col[...])         # exact probabilities
         dp = _dot(do, v, _NT)                 # (bq, bk)
         ds = p * (dp - dlt_col[...]) * scale
-        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)       # (bq, d)
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)       # (bq, d_qk)
 
     if causal:
         pl.when(j * block_k <= (i + 1) * block_q - 1)(_accumulate)
@@ -275,28 +308,38 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 def _flash_dkv(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
                block_k, interpret):
-    """The dK/dV pallas_call on (bh, t, d) operands and (bh, 1, tq)
-    row statistics."""
+    """The dK/dV pallas_call on (bh, t, d_qk | d_v) operands and
+    (bh, 1, tq) row statistics."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    qspec = pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
-    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, i))
+    bh, tq, d_qk = q3.shape
+    tk, d_v = k3.shape[1], v3.shape[2]
+
+    def qi(j, i):
+        # Under `causal` the q-blocks before the first computed one
+        # name that one: no copy for a block nobody reads.
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    qspec = lambda d: pl.BlockSpec((1, block_q, d),
+                                   lambda b_, j, i: (b_, qi(j, i), 0))
+    kspec = lambda d: pl.BlockSpec((1, block_k, d),
+                                   lambda b_, j, i: (b_, j, 0))
+    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, qi(j, i)))
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        out_shape=(jax.ShapeDtypeStruct((bh, tk, d), k3.dtype),
-                   jax.ShapeDtypeStruct((bh, tk, d), v3.dtype)),
+        out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype)),
         grid=(bh, tk // block_k, tq // block_q),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
-        out_specs=(kspec, kspec),
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        in_specs=[qspec(d_qk), kspec(d_qk), kspec(d_v), qspec(d_v),
+                  rowq, rowq],
+        out_specs=(kspec(d_qk), kspec(d_v)),
+        scratch_shapes=[pltpu.VMEM((block_k, d_qk), jnp.float32),
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="mx_flash_bwd_dkv",
     )(q3, k3, v3, do3, lse3, delta)
 
 
@@ -306,36 +349,36 @@ def _flash_dq(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d = q3.shape
-    tk = k3.shape[1]
-    qspec = pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0))
-    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i))
+    bh, tq, d_qk = q3.shape
+    d_v = v3.shape[2]
+    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(d_qk, d_v, block_q, block_k,
+                                               causal)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
-        grid=(bh, tq // block_q, tk // block_k),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
-        out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bh, tq, d_qk), q3.dtype),
+        grid=(bh, tq // block_q, k3.shape[1] // block_k),
+        in_specs=[q_qk, k_qk, k_v, q_v, rowq, rowq],
+        out_specs=q_qk,
+        scratch_shapes=[pltpu.VMEM((block_q, d_qk), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="mx_flash_bwd_dq",
     )(q3, k3, v3, do3, lse3, delta)
 
 
 def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
                     block_k, interpret):
-    b, h, tq, d = q.shape
+    b, h, tq, _ = q.shape
     block_q, block_k = _block_sizes(tq, k.shape[2], block_q, block_k)
     bh = b * h
     # delta_i = rowsum(dO_i * O_i) — O(T·d), fused by XLA.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, tq)
-    operands = tuple(a.reshape(bh, -1, d) for a in (q, k, v, g)) + (
-        lse.reshape(bh, 1, tq), delta)
+    operands = tuple(a.reshape(bh, a.shape[2], a.shape[3])
+                     for a in (q, k, v, g)) + (lse.reshape(bh, 1, tq), delta)
     static = (scale, causal, block_q, block_k, interpret)
     dk, dv = _flash_dkv(*operands, *static)
     dq = _flash_dq(*operands, *static)
@@ -343,46 +386,61 @@ def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
             dv.reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k,
-                            interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, causal, blocks, interpret):
+    out, _ = _flash_forward(q, k, v, scale, causal, *blocks, interpret)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k,
-                              interpret)
+def _flash_fwd(q, k, v, scale, causal, blocks, interpret):
+    out, lse = _flash_forward(q, k, v, scale, causal, *blocks, interpret)
     # Residuals are O(T·d) (q/k/v/out) + O(T) (lse) — never O(T²).
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, blocks, interpret, res, g):
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, scale, causal,
-                           block_q, block_k, interpret)
+    return _flash_backward(q, k, v, out, lse, g, scale, causal, *blocks,
+                           interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
+_flash_traced = _tm.REGISTRY.counter(
+    "mx_flash_attention_traced_total",
+    "flash_attention calls traced into a program, by head widths",
+    labels=("d_qk", "d_v"))
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=None):
+
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=None):
     """Blockwise exact attention as one Pallas kernel.
 
-    q/k/v: (batch, heads, seq, head_dim). On non-TPU backends the
-    kernel runs in interpret mode (functional, for tests); pass
-    `interpret` explicitly to override.
+    q, k: (batch, heads, seq, d_qk); v: (batch, heads, seq, d_v); the
+    result has v's width. `scale` defaults to ``d_qk ** -0.5``.
+    `block_q`/`block_k` apply to the forward and both backward kernels;
+    left at None each takes its default (`DEFAULT_BLOCK`). On
+    non-TPU backends the kernel runs in interpret mode (functional, for
+    tests); pass `interpret` explicitly to override.
     """
     if interpret is None:
         interpret = jax.default_backend() not in ("tpu",)
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError("q and k differ in width: %d, %d"
+                         % (q.shape[-1], k.shape[-1]))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash(q, k, v, float(scale), bool(causal), int(block_q),
-                  int(block_k), bool(interpret))
+    blocks = tuple(int(given or default) for given, default
+                   in zip((block_q, block_k), DEFAULT_BLOCK))
+    _flash_traced.labels(d_qk=str(q.shape[-1]), d_v=str(v.shape[-1])).inc()
+    return _flash(q, k, v, float(scale), bool(causal), blocks,
+                  bool(interpret))
 
 
 @register("_contrib_flash_attention", aliases=("flash_attention",))
-def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=128,
-                        block_k=128):
+def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=None,
+                        block_k=None):
+    """Exact attention of q, k (batch, heads, seq, d_qk) and v (batch,
+    heads, seq, d_v), d_qk and d_v free of each other; result (batch,
+    heads, seq_q, d_v); `scale` defaults to ``d_qk ** -0.5``."""
     return flash_attention(q, k, v, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k)

@@ -1,0 +1,124 @@
+"""The pieces of a decoder block that no other operator computes:
+RMSNorm, rotary position embedding on interleaved pairs, the SiLU-gated
+MLP and the `noaux_tc` router of the DeepSeek-V3 family. Plain XLA ops;
+the attention core is `pallas_attention.flash_attention` and the held
+experts' product `moe.moe_held_experts`.
+
+Weights are laid out (out, in), as `FullyConnected`'s.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .registry import register
+
+__all__ = ["rms_norm", "rotary_embedding", "gated_mlp", "noaux_tc_router"]
+
+
+@register("_contrib_RMSNorm", aliases=("RMSNorm",))
+def rms_norm(data, gamma, eps=1e-6):
+    """``gamma * x / sqrt(mean(x^2) + eps)`` over the last axis; the
+    statistics and the scaling in fp32, the result in `data`'s type."""
+    x = data.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+@register("_contrib_rotary_embedding", aliases=("rotary_embedding",))
+def rotary_embedding(data, theta=10000.0, interleaved=True):
+    """Rotary embedding of `data` (..., seq, d) at positions 0..seq-1.
+
+    `interleaved`: pair i is (x[2i], x[2i+1]) and turns by
+    ``pos * theta ** (-2i/d)``; its two results come back at i and
+    i + d/2 (the published `rope_interleave` path leaves them there: a
+    fixed permutation of the width, the same for q and k, so every
+    q.k is that of the interleaved result). Otherwise pair i is
+    (x[i], x[i + d/2]). Angles and products in fp32."""
+    d = data.shape[-1]
+    seq = data.shape[-2]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x = data.astype(jnp.float32)
+    if interleaved:
+        a, b = x[..., 0::2], x[..., 1::2]
+    else:
+        a, b = x[..., :d // 2], x[..., d // 2:]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.astype(data.dtype)
+
+
+@register("_contrib_gated_mlp", aliases=("gated_mlp",))
+def gated_mlp(data, gate_weight, up_weight, down_weight):
+    """``down(silu(gate x) * up x)``, weights (out, in)."""
+    gate = jnp.einsum("...h,fh->...f", data, gate_weight)
+    up = jnp.einsum("...h,fh->...f", data, up_weight)
+    return jnp.einsum("...f,hf->...h", jax.nn.silu(gate) * up, down_weight)
+
+
+def _scores(data, weight):
+    """Sigmoid scores (tokens, experts), fp32 at the highest precision."""
+    return jax.nn.sigmoid(jnp.einsum(
+        "th,eh->te", data.astype(jnp.float32), weight.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+
+
+def _counts(ids, experts):
+    """Tokens that picked each expert: (experts,) int32."""
+    return jnp.sum(ids[:, :, None] == jnp.arange(experts)[None, None, :],
+                   axis=(0, 1), dtype=jnp.int32)
+
+
+def _group_limited(choice, n_group, topk_group):
+    """DeepSeek-V3's node-limited routing: keep the `topk_group` groups
+    whose two best scores sum highest, the rest of `choice` to 0."""
+    tokens, experts = choice.shape
+    grouped = choice.reshape(tokens, n_group, experts // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    mask = jnp.zeros((tokens, n_group), bool).at[
+        jnp.arange(tokens)[:, None], keep].set(True)
+    return jnp.where(mask[:, :, None], grouped, 0.0).reshape(tokens, experts)
+
+
+@register("_contrib_noaux_tc_router", aliases=("noaux_tc_router",),
+          differentiable=True)
+def noaux_tc_router(data, weight, bias_steps, top_k=6, gamma=1e-3,
+                    routed_scaling_factor=1.0, norm_topk_prob=True,
+                    n_group=1, topk_group=1):
+    """The `noaux_tc` router: sigmoid scores over all experts, selection
+    by score plus bias, weights from the score alone.
+
+    data (tokens, hidden); weight (experts, hidden); `bias_steps`
+    (experts,) int32, the selection bias `e_score_correction_bias` in
+    whole steps of `gamma` (the published rule moves it by +-gamma a
+    step, so the count is exact and no cast can move it). The product
+    and the scores are fp32 at the highest matmul precision.
+
+    Returns (weights (tokens, top_k) fp32, ids (tokens, top_k) int32,
+    counts (experts,) int32: the tokens that selected each expert).
+    """
+    with jax.named_scope("moe_route"):
+        score = _scores(data, weight)
+        bias = bias_steps.astype(jnp.float32) * jnp.float32(gamma)
+        choice = jax.lax.stop_gradient(score) + bias
+        if n_group > 1:
+            choice = _group_limited(choice, n_group, topk_group)
+        _, ids = jax.lax.top_k(choice, top_k)
+        picked = jnp.take_along_axis(score, ids, axis=-1)
+        if norm_topk_prob:
+            picked = picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                               + 1e-20)
+        return (picked * jnp.float32(routed_scaling_factor),
+                ids.astype(jnp.int32), _counts(ids, score.shape[1]))
+
+
+@register("_contrib_noaux_tc_bias_update", differentiable=False)
+def bias_steps_update(bias_steps, counts):
+    """The published rule in whole steps: +1 where an expert was picked
+    by fewer tokens than the mean, -1 where by more, 0 at the mean."""
+    experts = counts.shape[0]
+    total = jnp.sum(counts)
+    return bias_steps + jnp.sign(total - counts * experts).astype(
+        bias_steps.dtype)

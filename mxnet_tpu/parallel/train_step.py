@@ -17,6 +17,7 @@ aliasing).
 from __future__ import annotations
 
 import time
+import weakref
 
 import numpy as np
 
@@ -408,6 +409,10 @@ class TrainStep:
         self._param_vals = {p.name: p.data()._data
                             for p in self._train_params}
         self._aux_vals = {p.name: p.data()._data for p in self._aux_params}
+        # Non-gradient state lives here until sync_to_net.
+        me = weakref.ref(self)
+        for p in self._aux_params:
+            p._bind_live(lambda name=p.name: me() and me()._aux_vals[name])
 
         # Optimizer state mirrors param sharding (ZeRO-0; the state is
         # sharded exactly like its weight so updates are local). Always

@@ -1,5 +1,6 @@
 """The chip's compiler, asked without the chip: the three flash-attention
-kernels at real widths, compiled for a described TPU v5e (2x2). What
+kernels at real widths (equal, and latent attention's 192/128 at the
+blocks the kernels default to), compiled for a described TPU v5e (2x2). What
 interpret mode cannot refuse — a block the lowering does not tile, more
 VMEM than a kernel may use — is refused here, at no chip time.
 
@@ -67,3 +68,29 @@ def test_flash_kernel_compiles_for_v5e(one_chip, kernel, d):
     # Flash: the (b*h, T, T) score tensor never exists, nor a fraction.
     scores = _B * _H * _T * _T * 4
     assert compiled.memory_analysis().temp_size_in_bytes < scores // 4
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+def test_flash_kernel_compiles_for_v5e_with_unequal_widths(one_chip,
+                                                           kernel):
+    """(1, 32, 4096) heads 192 wide in q and k, 128 in v, causal, at the
+    default blocks: the benchmark cell's call."""
+    b, h, t, d_qk, d_v = 1, 32, 4096, 192, 128
+    static = (d_qk ** -0.5, True) + pa.DEFAULT_BLOCK + (False,)
+    qk, v = ((b * h, t, d_qk), jnp.bfloat16), ((b * h, t, d_v), jnp.bfloat16)
+    row = ((b * h, 1, t), jnp.float32)
+    if kernel == "fwd":
+        fn = lambda q, k, v_: pa._flash_forward(q, k, v_, *static)
+        shapes = [((b, h, t, d_qk), jnp.bfloat16)] * 2 \
+            + [((b, h, t, d_v), jnp.bfloat16)]
+    else:
+        call = {"dkv": pa._flash_dkv, "dq": pa._flash_dq}[kernel]
+        fn = lambda *a: call(*a, *static)
+        shapes = [qk, qk, v, v, row, row]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < b * h * t * t * 4 // 4
