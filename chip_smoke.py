@@ -230,9 +230,10 @@ def _dense_attention(q, k, v, causal):
 
 
 def phase_kernel(shape, dtype, seed, interpret):
-    """flash_attention forward and both backward kernels against the
-    dense reference. With interpret=False the lowered program must hold
-    the Mosaic kernels (tpu_custom_call), not an interpreter's HLO."""
+    """flash_attention forward and backward (one fused kernel at this
+    size) against the dense reference. With interpret=False the lowered
+    program must hold the Mosaic kernels (tpu_custom_call), not an
+    interpreter's HLO."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas_attention import flash_attention

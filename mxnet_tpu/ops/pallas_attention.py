@@ -12,12 +12,24 @@ MXU).
 `flash_attention` runs the kernel compiled on TPU and in interpret mode
 elsewhere (cpu tests). The backward is flash too (VERDICT r4 #5): a
 custom_vjp saving only (q, k, v, out, logsumexp) — O(T·d) residuals —
-and two Pallas kernels that REGENERATE probability blocks from the
-saved logsumexp (FlashAttention-2 backward): a dK/dV pass iterating
-q-blocks innermost and a dQ pass iterating k-blocks innermost, both
-with the causal block-skip. Peak memory stays O(T·d) where the old
-re-derived `jax.vjp(blockwise_attention)` backward stored O(T²) of
-per-block probabilities across scan steps.
+and Pallas kernels that REGENERATE probability blocks from the saved
+logsumexp (FlashAttention-2 backward), with the causal block-skip. Peak
+memory stays O(T·d) where the old re-derived
+`jax.vjp(blockwise_attention)` backward stored O(T²) of per-block
+probabilities across scan steps.
+
+One backward kernel, `mx_flash_bwd`, where it fits: grid (batch*heads,
+k-blocks, q-blocks), q innermost; per block pair the scores,
+probabilities, dP and dS are formed once (transposed, (block_k,
+block_q)) and feed all three gradients — dK and dV accumulate over the
+inner axis, dQ in a whole head's fp32 accumulator that stays in VMEM
+across both inner axes: five score-sized products a pair. A head whose
+(tq, d_qk) fp32 accumulator passes `FUSED_DQ_BYTES` takes two kernels
+instead, each holding one block of each operand: a dK/dV pass
+(`mx_flash_bwd_dkv`, q-blocks innermost) and a dQ pass
+(`mx_flash_bwd_dq`, k-blocks innermost), which form scores and dS once
+each, seven products a pair. `_flash_backward` chooses from the shapes,
+and `mx_flash_attention_bwd_traced_total{path}` counts which.
 
 Head widths: q and k share `d_qk`, v and the output have `d_v`, and the
 two may differ (latent attention: 192 and 128). Each width is the whole
@@ -25,8 +37,8 @@ last dimension of its block, so any width the TPU lowering tiles is
 accepted (multiples of 8 seen compiled: 64, 128, 192; 192 is not padded
 to 256 in HBM); `scale` defaults to `d_qk ** -0.5`. Sequence lengths must
 divide by the block sizes. Block sizes left at None take the defaults
-below, chosen from a sweep on a TPU v5e at (1, 32, 4096, 192/128), causal
-(PERF.md, PR 28); a block longer than the sequence is cut to it.
+below, chosen from sweeps on a TPU v5e at (1, 32, 4096, 192/128), causal
+(PERF.md, PRs 28 and 29); a block longer than the sequence is cut to it.
 
 Registered as `_contrib_flash_attention` for `nd`/`sym` access.
 """
@@ -84,6 +96,7 @@ def _dot(a, b, contract):
 
 _NT = ((1,), (1,))    # a @ b.T
 _NN = ((1,), (0,))    # a @ b
+_TN = ((0,), (0,))    # a.T @ b
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
@@ -136,10 +149,24 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         lse_ref[0] = _col_to_row(m_ref[...] + jnp.log(l))
 
 
-# (block_q, block_k) of all three kernels where the caller names none.
-# From the sweep at (1, 32, 4096, 192/128) bf16 causal on a TPU v5e
-# (PERF.md, PR 28).
+# (block_q, block_k) of every kernel where the caller names none. From
+# two sweeps at (1, 32, 4096, 192/128) bf16 causal on a TPU v5e: the
+# forward and the dK/dV and dQ pair (PERF.md, PR 28), the fused backward
+# (PERF.md, PR 29), each fastest here.
 DEFAULT_BLOCK = (1024, 1024)
+
+# The fused backward keeps one head's dQ in VMEM as fp32, (tq, d_qk),
+# from the head's first grid step to its last: 3 MiB at 4096 x 192. A
+# head whose accumulator is larger than this takes the dK/dV and dQ
+# kernels, which hold one block of each operand however long the
+# sequence. 16 MiB admits 16k x 256 (16k x 192 is 12 MiB) and leaves,
+# of a v5e core's 128 MiB, room for the output block's two buffers and
+# the score-sized temporaries under the limit below: compiled for a v5e
+# the kernel asks 17.4 MiB at 4096 x 192 and 48.5 MiB at 16k x 256 with
+# 1024x1024 blocks, 80.3 MiB with 2048x2048 (the default scoped limit
+# is 16 MiB).
+FUSED_DQ_BYTES = 16 * 2 ** 20
+FUSED_VMEM_LIMIT = 96 * 2 ** 20
 
 
 def _block_sizes(tq, tk, block_q, block_k):
@@ -153,13 +180,13 @@ def _block_sizes(tq, tk, block_q, block_k):
     return block_q, block_k
 
 
-def _compiler_params():
+def _compiler_params(middle="parallel", **more):
     from jax.experimental.pallas import tpu as pltpu
 
     # The innermost grid axis carries the accumulators; the other two
-    # are independent.
+    # are independent, but for the fused backward's middle one.
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", middle, "arbitrary"), **more)
 
 
 def _last_k(i, block_q, block_k):
@@ -306,6 +333,79 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *,
+                scale, causal, block_q, block_k):
+    """The whole backward in one pass: the dK/dV kernel's grid and
+    body, and dQ out of the same dS^T tile. `dq_acc` holds the whole
+    head's dQ in fp32 across both inner grid axes; a q-block's rows
+    are zeroed at the first k-block, added to at every computed pair
+    (k-blocks ascending, the dQ kernel's order) and written out at the
+    last."""
+    import jax.experimental.pallas as pl
+
+    j = pl.program_id(1)                      # k block (outer)
+    i = pl.program_id(2)                      # q block (inner)
+    nk = pl.num_programs(1)
+    nq = pl.num_programs(2)
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    def _accumulate():
+        q = q_ref[0]                          # (bq, d_qk)
+        k = k_ref[0]                          # (bk, d_qk)
+        v = v_ref[0]                          # (bk, d_v)
+        do = do_ref[0]                        # (bq, d_v)
+        st = _dot(k, q, _NT) * scale          # (bk, bq) = S^T
+        if causal:
+            st = jnp.where(_causal_mask(i, j, block_q, block_k,
+                                        transposed=True), st, _NEG)
+        pt = jnp.exp(st - lse_ref[0])         # exact probabilities, P^T
+        dpt = _dot(v, do, _NT)                # (bk, bq) = dP^T
+        dst = (pt * (dpt - dlt_ref[0]) * scale).astype(q.dtype)
+        dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d_v)
+        dk_acc[...] += _dot(dst, q, _NN)                      # (bk, d_qk)
+        dq_acc[rows, :] += _dot(dst, k, _TN)                  # (bq, d_qk)
+
+    if causal:
+        pl.when((i + 1) * block_q - 1 >= j * block_k)(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when(i == nq - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(j == nk - 1)
+    def _finalize_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal):
+    """Block specs of a (bh, k-blocks, q-blocks) grid. Under `causal`
+    the q-blocks before the first computed one name that one: no copy
+    for a block nobody reads."""
+    import jax.experimental.pallas as pl
+
+    def qi(j, i):
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    q = lambda d: pl.BlockSpec((1, block_q, d),
+                               lambda b_, j, i: (b_, qi(j, i), 0))
+    k = lambda d: pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0))
+    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, qi(j, i)))
+    return q(d_qk), q(d_v), k(d_qk), k(d_v), rowq
+
+
 def _flash_dkv(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
                block_k, interpret):
     """The dK/dV pallas_call on (bh, t, d_qk | d_v) operands and
@@ -315,26 +415,16 @@ def _flash_dkv(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
 
     bh, tq, d_qk = q3.shape
     tk, d_v = k3.shape[1], v3.shape[2]
-
-    def qi(j, i):
-        # Under `causal` the q-blocks before the first computed one
-        # name that one: no copy for a block nobody reads.
-        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
-
-    qspec = lambda d: pl.BlockSpec((1, block_q, d),
-                                   lambda b_, j, i: (b_, qi(j, i), 0))
-    kspec = lambda d: pl.BlockSpec((1, block_k, d),
-                                   lambda b_, j, i: (b_, j, 0))
-    rowq = pl.BlockSpec((1, 1, block_q), lambda b_, j, i: (b_, 0, qi(j, i)))
+    q_qk, q_v, k_qk, k_v, rowq = _k_of_q_specs(d_qk, d_v, block_q, block_k,
+                                               causal)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
                    jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype)),
         grid=(bh, tk // block_k, tq // block_q),
-        in_specs=[qspec(d_qk), kspec(d_qk), kspec(d_v), qspec(d_v),
-                  rowq, rowq],
-        out_specs=(kspec(d_qk), kspec(d_v)),
+        in_specs=[q_qk, k_qk, k_v, q_v, rowq, rowq],
+        out_specs=(k_qk, k_v),
         scratch_shapes=[pltpu.VMEM((block_k, d_qk), jnp.float32),
                         pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=_compiler_params(),
@@ -369,9 +459,49 @@ def _flash_dq(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     )(q3, k3, v3, do3, lse3, delta)
 
 
+def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
+                     block_k, interpret):
+    """dK, dV and dQ from one pallas_call, same operands as
+    :func:`_flash_dkv`; returns (dk, dv, dq)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, tq, d_qk = q3.shape
+    tk, d_v = k3.shape[1], v3.shape[2]
+    q_qk, q_v, k_qk, k_v, rowq = _k_of_q_specs(d_qk, d_v, block_q, block_k,
+                                               causal)
+    # One head's dQ: the same block at every step of a head, so it is
+    # written back once, when the head changes.
+    head = pl.BlockSpec((1, tq, d_qk), lambda b_, j, i: (b_, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype),
+                   jax.ShapeDtypeStruct((bh, tq, d_qk), q3.dtype)),
+        grid=(bh, tk // block_k, tq // block_q),
+        in_specs=[q_qk, k_qk, k_v, q_v, rowq, rowq],
+        out_specs=(k_qk, k_v, head),
+        scratch_shapes=[pltpu.VMEM((block_k, d_qk), jnp.float32),
+                        pltpu.VMEM((block_k, d_v), jnp.float32),
+                        pltpu.VMEM((tq, d_qk), jnp.float32)],
+        # dQ accumulates across the k-blocks too: only heads are
+        # independent.
+        compiler_params=_compiler_params(
+            "arbitrary", vmem_limit_bytes=FUSED_VMEM_LIMIT),
+        interpret=interpret,
+        name="mx_flash_bwd",
+    )(q3, k3, v3, do3, lse3, delta)
+
+
+def _bwd_path(tq, d_qk):
+    """Which backward a call takes, from its shapes alone."""
+    return "fused" if tq * d_qk * 4 <= FUSED_DQ_BYTES else "split"
+
+
 def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
                     block_k, interpret):
-    b, h, tq, _ = q.shape
+    b, h, tq, d_qk = q.shape
     block_q, block_k = _block_sizes(tq, k.shape[2], block_q, block_k)
     bh = b * h
     # delta_i = rowsum(dO_i * O_i) — O(T·d), fused by XLA.
@@ -380,8 +510,13 @@ def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
     operands = tuple(a.reshape(bh, a.shape[2], a.shape[3])
                      for a in (q, k, v, g)) + (lse.reshape(bh, 1, tq), delta)
     static = (scale, causal, block_q, block_k, interpret)
-    dk, dv = _flash_dkv(*operands, *static)
-    dq = _flash_dq(*operands, *static)
+    path = _bwd_path(tq, d_qk)
+    _flash_bwd_traced.labels(path=path).inc()
+    if path == "fused":
+        dk, dv, dq = _flash_bwd_fused(*operands, *static)
+    else:
+        dk, dv = _flash_dkv(*operands, *static)
+        dq = _flash_dq(*operands, *static)
     return (dq.reshape(q.shape), dk.reshape(k.shape),
             dv.reshape(v.shape))
 
@@ -406,6 +541,12 @@ def _flash_bwd(scale, causal, blocks, interpret, res, g):
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
+_flash_bwd_traced = _tm.REGISTRY.counter(
+    "mx_flash_attention_bwd_traced_total",
+    "flash_attention backward passes traced into a program, by the "
+    "kernels taken: one fused pass, or the dK/dV and dQ pair",
+    labels=("path",))
+
 _flash_traced = _tm.REGISTRY.counter(
     "mx_flash_attention_traced_total",
     "flash_attention calls traced into a program, by head widths",
@@ -418,7 +559,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     q, k: (batch, heads, seq, d_qk); v: (batch, heads, seq, d_v); the
     result has v's width. `scale` defaults to ``d_qk ** -0.5``.
-    `block_q`/`block_k` apply to the forward and both backward kernels;
+    `block_q`/`block_k` apply to the forward and the backward kernels;
     left at None each takes its default (`DEFAULT_BLOCK`). On
     non-TPU backends the kernel runs in interpret mode (functional, for
     tests); pass `interpret` explicitly to override.

@@ -435,6 +435,32 @@ def test_two_seeds_give_one_step_program(model):
             "stablehlo.while")
 
 
+def test_a_build_of_the_step_counts_one_fused_backward_a_layer(model):
+    """Each layer's attention takes the one-pass flash backward (a head
+    of the cell's 4,096 x 192 is 3 MiB of fp32 dQ, under the budget;
+    the tiny head here is smaller still) and none the dK/dV and dQ
+    pair: the registry says so after one build of the step's program."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    def counts():
+        return [pa._flash_bwd_traced.labels(path=p).value
+                for p in ("fused", "split")]
+
+    assert pa._bwd_path(4096, 192) == "fused"
+    cfg = _cfg()
+    net, loss_fn = model.build(cfg, 23)
+    x, y = model.make_batch(cfg, jax.random.PRNGKey(23), 1)
+    step = _train_step(net, loss_fn, "bfloat16", optimizer="adam", lr=1e-4)
+    step._materialize(np.asarray(x)[:1])
+    step._build()
+    before = counts()
+    step._jitted.lower(
+        step._param_vals, step._opt_state, step._aux_vals, x, y,
+        jnp.float32(1e-4), jnp.float32(1), jax.random.PRNGKey(0))
+    fused, split = (b - a for a, b in zip(before, counts()))
+    assert (fused, split) == (cfg["num_hidden_layers"], 0)
+
+
 def test_calibration_balances_a_collapsed_residual_stream(model):
     """Tokens that share a large common component all pick the same few
     experts; the published rule, iterated on the batch, spreads them."""

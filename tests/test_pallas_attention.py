@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
+from mxnet_tpu.ops import pallas_attention as pa
 from mxnet_tpu.ops.pallas_attention import flash_attention
 
 
@@ -137,3 +138,79 @@ def test_flash_backward_matches_blockwise_vjp():
             np.testing.assert_allclose(
                 np.asarray(gf), np.asarray(gb), rtol=2e-4, atol=2e-5,
                 err_msg="d%s diverged (causal=%s)" % (name, causal))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2 ** -7)])
+@pytest.mark.parametrize("tq,tk,block_q,block_k", [
+    (256, 256, 64, 128),      # block_q != block_k
+    (256, 256, 128, 64),
+    (128, 384, 64, 128),      # tq != tk
+    (256, 256, 256, 256),     # one block pair
+])
+@pytest.mark.parametrize("d_qk,d_v", [(64, 64), (48, 32)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_matches_split(causal, d_qk, d_v, tq, tk, block_q,
+                                      block_k, dtype, tol):
+    """One pass against the dK/dV and dQ pair on the same operands: the
+    same tiles in the same order, so dK and dV are the pair's to the
+    bit, and dQ (whose product is written the other way round, dS^T
+    contracted over its rows) to fp32 rounding: 1e-5, or one place of
+    a bf16 result."""
+    rng = np.random.RandomState(5)
+    q, k, v, do = (jnp.asarray(rng.randn(2, t, d).astype(np.float32), dtype)
+                   for t, d in ((tq, d_qk), (tk, d_qk), (tk, d_v),
+                                (tq, d_v)))
+    scale = d_qk ** -0.5
+    # The logsumexp and delta a forward over the same q, k, v saves.
+    out, lse = pa._flash_forward(q[None], k[None], v[None], scale, causal,
+                                 block_q, block_k, True)
+    delta = jnp.sum(do.astype(jnp.float32) * out[0].astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    operands = (q, k, v, do, lse[0][:, None, :], delta)
+    static = (scale, causal, block_q, block_k, True)
+    dk, dv, dq = pa._flash_bwd_fused(*operands, *static)
+    dk_ref, dv_ref = pa._flash_dkv(*operands, *static)
+    dq_ref = pa._flash_dq(*operands, *static)
+    for got, want in ((dk, dk_ref), (dv, dv_ref), (dq, dq_ref)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(dk, np.float32),
+                                  np.asarray(dk_ref, np.float32))
+    np.testing.assert_array_equal(np.asarray(dv, np.float32),
+                                  np.asarray(dv_ref, np.float32))
+    np.testing.assert_allclose(np.asarray(dq, np.float32),
+                               np.asarray(dq_ref, np.float32),
+                               rtol=tol, atol=1e-5)
+
+
+def test_backward_path_follows_the_accumulator_budget(monkeypatch):
+    """The path is chosen from (tq, d_qk) alone: one head's fp32 dQ
+    under `FUSED_DQ_BYTES` takes the fused kernel, over it the pair;
+    the counter's labels say which, and both give the same gradients."""
+    rng = np.random.RandomState(6)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, 64, 16).astype(np.float32))
+               for _ in range(3))
+
+    def grads():
+        return jax.grad(lambda a, b, c: (flash_attention(
+            a, b, c, causal=True, block_q=16, block_k=32) ** 2).mean(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    def counts():
+        return {path: pa._flash_bwd_traced.labels(path=path).value
+                for path in ("fused", "split")}
+
+    assert pa._bwd_path(16384, 192) == "fused"
+    before = counts()
+    fused = grads()
+    after = counts()
+    assert (after["fused"] - before["fused"],
+            after["split"] - before["split"]) == (1, 0)
+    monkeypatch.setattr(pa, "FUSED_DQ_BYTES", 64 * 16 * 4 - 1)
+    split = grads()
+    last = counts()
+    assert (last["fused"] - after["fused"],
+            last["split"] - after["split"]) == (0, 1)
+    for a, b in zip(fused, split):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)

@@ -1,6 +1,8 @@
-"""The chip's compiler, asked without the chip: the three flash-attention
-kernels at real widths (equal, and latent attention's 192/128 at the
-blocks the kernels default to), compiled for a described TPU v5e (2x2). What
+"""The chip's compiler, asked without the chip: the flash-attention
+kernels (forward, the fused backward, and the dK/dV and dQ pair of the
+long-sequence path) at real widths (equal, and latent attention's
+192/128 at the blocks the kernels default to), compiled for a described
+TPU v5e (2x2). What
 interpret mode cannot refuse — a block the lowering does not tile, more
 VMEM than a kernel may use — is refused here, at no chip time.
 
@@ -42,6 +44,10 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
+_BACKWARD = {"dkv": pa._flash_dkv, "dq": pa._flash_dq,
+             "fused": pa._flash_bwd_fused}
+
+
 def _launch(kernel, d, scale):
     """(function, argument shapes) of one kernel's pallas_call at
     (4, 16, 2048, d) bf16, causal, blocks 128/128, interpret=False."""
@@ -52,12 +58,12 @@ def _launch(kernel, d, scale):
     if kernel == "fwd":
         return (lambda q, k, v: pa._flash_forward(q, k, v, *static),
                 [((_B, _H, _T, d), jnp.bfloat16)] * 3)
-    fn = {"dkv": pa._flash_dkv, "dq": pa._flash_dq}[kernel]
-    return (lambda *a: fn(*a, *static), [qkv] * 4 + [row] * 2)
+    return (lambda *a: _BACKWARD[kernel](*a, *static),
+            [qkv] * 4 + [row] * 2)
 
 
 @pytest.mark.parametrize("d", [64, 128])
-@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq", "fused"])
 def test_flash_kernel_compiles_for_v5e(one_chip, kernel, d):
     fn, shapes = _launch(kernel, d, d ** -0.5)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
@@ -70,7 +76,7 @@ def test_flash_kernel_compiles_for_v5e(one_chip, kernel, d):
     assert compiled.memory_analysis().temp_size_in_bytes < scores // 4
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq", "fused"])
 def test_flash_kernel_compiles_for_v5e_with_unequal_widths(one_chip,
                                                            kernel):
     """(1, 32, 4096) heads 192 wide in q and k, 128 in v, causal, at the
@@ -84,8 +90,7 @@ def test_flash_kernel_compiles_for_v5e_with_unequal_widths(one_chip,
         shapes = [((b, h, t, d_qk), jnp.bfloat16)] * 2 \
             + [((b, h, t, d_v), jnp.bfloat16)]
     else:
-        call = {"dkv": pa._flash_dkv, "dq": pa._flash_dq}[kernel]
-        fn = lambda *a: call(*a, *static)
+        fn = lambda *a: _BACKWARD[kernel](*a, *static)
         shapes = [qk, qk, v, v, row, row]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
