@@ -893,7 +893,7 @@ def _healthplane_rows():
 def _profiling_rows():
     """Profiling section (ISSUE 12): what always-on continuous
     profiling costs the step path, plus the attribution plane's
-    phase/FLOPs rows. THE CONTRACT ROW:
+    phase rows. THE CONTRACT ROW:
     continuous_profiler_step_overhead_pct <= 1 — the sampler at its
     default rate (MXNET_PROFILE_HZ) against the step path.
 
@@ -907,107 +907,82 @@ def _profiling_rows():
     informative context. Also informative: attribution-derived phase
     shares + bound cause over an attributed run (device spans on, so
     each step is host-synchronous there — that bracket is attribution's
-    documented price, not the profiler's), and achieved GFLOP/s from
-    ``cost_analysis()`` flops at the train_step compile seam."""
-    import shutil
-    import tempfile
-
+    documented price, not the profiler's)."""
     import mxnet_tpu as mx
-    from mxnet_tpu import compile as cc, gluon, telemetry
+    from mxnet_tpu import gluon, telemetry
     from mxnet_tpu.telemetry import attribution as tattr
     from mxnet_tpu.parallel import TrainStep, make_mesh
 
     mx.random.seed(31)
     rng = np.random.RandomState(31)
-    # The executable store routes TrainStep through maybe_cached_jit's
-    # CachedFunction, whose seam records cost_analysis() flops — the
-    # achieved-FLOPs row's input. A throw-away store directory for this
-    # section only; not the program's compile cache.
-    cache_dir = tempfile.mkdtemp(prefix="bench_cc_prof_")
-    cc.configure(cache_dir)
+    net = gluon.nn.HybridSequential(prefix="bench_prof_")
+    net.add(gluon.nn.Dense(1024, activation="relu", in_units=784,
+                           prefix="fc1_"))
+    net.add(gluon.nn.Dense(1024, activation="relu", in_units=1024,
+                           prefix="fc2_"))
+    net.add(gluon.nn.Dense(10, in_units=1024, prefix="fc3_"))
+    net.initialize(mx.init.Xavier())
+    step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                     optimizer="sgd",
+                     optimizer_params={"learning_rate": 0.05},
+                     mesh=make_mesh())
+    x = rng.rand(256, 784).astype(np.float32)
+    y = rng.randint(0, 10, 256)
+    for _ in range(3):                  # compile + settle
+        float(np.asarray(step(x, y)))
+
+    iters = 50
+
+    def timed():
+        times = []
+        for _ in range(iters):
+            t0 = time.perf_counter()
+            loss = step(x, y)
+            float(np.asarray(loss))
+            times.append(time.perf_counter() - t0)
+        return times
+
+    base = timed()
+    profiler = telemetry.ContinuousProfiler().start()
+    profiled = timed()
+    base_med_ms = sorted(base)[len(base) // 2] * 1e3
+    prof_med_ms = sorted(profiled)[len(profiled) // 2] * 1e3
+    _emit("profiling_step_ms_base", round(base_med_ms, 3), "ms")
+    _emit("profiling_step_ms_sampled", round(prof_med_ms, 3), "ms")
+    _emit("continuous_profiler_step_overhead_ab_pct",
+          round((prof_med_ms - base_med_ms) / base_med_ms * 100.0,
+                3), "%")
+
+    # THE CONTRACT ROW: direct hook measurement — per-sample
+    # capture+fold cost x the default sampling rate = the sampler's
+    # steady-state share of wall time.
+    reps = 300
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        profiler.sample()
+    per_sample_s = (time.perf_counter() - t0) / reps
+    profiler.close()
+    _emit("continuous_profiler_sample_ms",
+          round(per_sample_s * 1e3, 4), "ms")
+    _emit("continuous_profiler_step_overhead_pct",
+          round(per_sample_s * profiler.hz * 100.0, 3), "%")
+
+    # Attribution (informative): phase shares + bound cause over an
+    # attributed window.
+    attr = telemetry.StepAttribution(interval_s=0.0)
     try:
-        net = gluon.nn.HybridSequential(prefix="bench_prof_")
-        net.add(gluon.nn.Dense(1024, activation="relu", in_units=784,
-                               prefix="fc1_"))
-        net.add(gluon.nn.Dense(1024, activation="relu", in_units=1024,
-                               prefix="fc2_"))
-        net.add(gluon.nn.Dense(10, in_units=1024, prefix="fc3_"))
-        net.initialize(mx.init.Xavier())
-        step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                         optimizer="sgd",
-                         optimizer_params={"learning_rate": 0.05},
-                         mesh=make_mesh())
-        x = rng.rand(256, 784).astype(np.float32)
-        y = rng.randint(0, 10, 256)
-        for _ in range(3):                  # compile + settle
+        attr.update()                   # drain the span backlog
+        for _ in range(20):
             float(np.asarray(step(x, y)))
-
-        iters = 50
-
-        def timed():
-            times = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                loss = step(x, y)
-                float(np.asarray(loss))
-                times.append(time.perf_counter() - t0)
-            return times
-
-        base = timed()
-        profiler = telemetry.ContinuousProfiler().start()
-        profiled = timed()
-        base_med_ms = sorted(base)[len(base) // 2] * 1e3
-        prof_med_ms = sorted(profiled)[len(profiled) // 2] * 1e3
-        _emit("profiling_step_ms_base", round(base_med_ms, 3), "ms")
-        _emit("profiling_step_ms_sampled", round(prof_med_ms, 3), "ms")
-        _emit("continuous_profiler_step_overhead_ab_pct",
-              round((prof_med_ms - base_med_ms) / base_med_ms * 100.0,
-                    3), "%")
-
-        # THE CONTRACT ROW: direct hook measurement — per-sample
-        # capture+fold cost x the default sampling rate = the sampler's
-        # steady-state share of wall time.
-        reps = 300
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            profiler.sample()
-        per_sample_s = (time.perf_counter() - t0) / reps
-        profiler.close()
-        _emit("continuous_profiler_sample_ms",
-              round(per_sample_s * 1e3, 4), "ms")
-        _emit("continuous_profiler_step_overhead_pct",
-              round(per_sample_s * profiler.hz * 100.0, 3), "%")
-
-        # Attribution (informative): phase shares + bound cause over an
-        # attributed window, and achieved FLOP/s from the executable's
-        # cost analysis.
-        attr = telemetry.StepAttribution(interval_s=0.0)
-        try:
-            attr.update()                   # drain the span backlog
-            attr_steps = 20
-            for _ in range(attr_steps):
-                float(np.asarray(step(x, y)))
-            attr.update()
-            shares = attr.last_shares or {}
-            for phase in tattr.PHASES:
-                _emit("step_phase_share[%s]" % phase,
-                      round(shares.get(phase, 0.0), 4), "share")
-            _emit("step_bound_cause", attr.bound_cause or "unknown",
-                  "cause")
-            cost = tattr.executable_costs().get("train_step")
-            device_s = (attr.last_window or {}).get("device_compute",
-                                                    0.0)
-            if cost and cost.get("flops") and device_s > 0:
-                _emit("train_step_executable_gflop",
-                      round(cost["flops"] / 1e9, 4), "GFLOP")
-                _emit("train_step_achieved_gflops",
-                      round(cost["flops"] * attr_steps / device_s
-                            / 1e9, 2), "GFLOP/s")
-        finally:
-            attr.close()
+        attr.update()
+        shares = attr.last_shares or {}
+        for phase in tattr.PHASES:
+            _emit("step_phase_share[%s]" % phase,
+                  round(shares.get(phase, 0.0), 4), "share")
+        _emit("step_bound_cause", attr.bound_cause or "unknown",
+              "cause")
     finally:
-        cc.reset()
-        shutil.rmtree(cache_dir, ignore_errors=True)
+        attr.close()
 
 
 def _goodput_rows():
@@ -1126,144 +1101,6 @@ def _compile_accounting_rows():
         _emit("compile_count[%s]" % site, rec["count"], "compiles")
         _emit("compile_seconds[%s]" % site, round(rec["total_s"], 3),
               "s")
-
-
-def _compile_cache_child(cache_dir):
-    """One simulated process start with the persistent compile cache at
-    ``cache_dir`` (run twice by `_compile_cache_rows`: cold then warm).
-    Exercises all three cached compile sites the way a real restart
-    does — serving bucket-ladder warmup + first predict, fused-update
-    first step, TrainStep first step — and prints ONE JSON line:
-    time-to-first-batch per surface plus the per-site compile counts
-    this process actually paid (mx_compile_seconds is process-local, so
-    in a fresh child it IS this start's bill)."""
-    t_start = time.perf_counter()
-    _acquire_device()
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import gluon, nd
-    from mxnet_tpu.gluon import nn
-    from mxnet_tpu.gluon import loss as gloss
-    from mxnet_tpu.parallel import TrainStep
-    from mxnet_tpu.serving import InferenceServer
-    from mxnet_tpu.telemetry import memstats
-
-    assert os.environ.get("MXNET_COMPILE_CACHE") == cache_dir
-    rng = np.random.RandomState(0)
-
-    # Serving: a bucket ladder over a small MLP (the cached_op site).
-    w1 = rng.rand(64, 128).astype(np.float32)
-    b1 = rng.rand(128).astype(np.float32)
-    w2 = rng.rand(128, 10).astype(np.float32)
-
-    def fwd(w1_, b1_, w2_, x):
-        return nd.dot(nd.relu(nd.dot(x, w1_) + b1_), w2_)
-
-    server = InferenceServer(fwd, (w1, b1, w2), item_shape=(64,),
-                             max_batch=8)
-    server.predict(rng.rand(3, 64).astype(np.float32))
-    ttfb_serving = time.perf_counter() - t_start
-    server.shutdown()
-
-    # Fused update: one Trainer step (the fused_apply site). Stable
-    # prefix => stable param names => restart-stable executables.
-    net = nn.HybridSequential(prefix="ccbench_")
-    with net.name_scope():
-        for _ in range(3):
-            net.add(nn.Dense(128, activation="relu"))
-        net.add(nn.Dense(10))
-    net.initialize()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1})
-    data = nd.array(rng.rand(8, 64).astype(np.float32))
-    from mxnet_tpu import autograd
-
-    with autograd.record():
-        loss = net(data).sum()
-    loss.backward()
-    trainer.step(8)
-
-    # Whole-step executable (the train_step site).
-    net2 = nn.Dense(10, in_units=32, prefix="ccbench_step_")
-    net2.initialize()
-    step = TrainStep(net2, gloss.L2Loss(), optimizer="sgd",
-                     optimizer_params={"learning_rate": 0.1})
-    loss = step(rng.rand(8, 32).astype(np.float32),
-                rng.rand(8, 10).astype(np.float32))
-    float(np.asarray(loss))             # force completion
-    ttfb_train = time.perf_counter() - t_start
-
-    counts = {site: rec["count"]
-              for site, rec in memstats.compile_stats().items()}
-    print(json.dumps({
-        "ttfb_serving_s": round(ttfb_serving, 3),
-        "ttfb_train_s": round(ttfb_train, 3),
-        "compile_counts": counts,
-    }), flush=True)
-    return 0
-
-
-def _compile_cache_rows():
-    """Compile-cache section (mxnet_tpu.compile, ISSUE 11): cold-vs-warm
-    restart measured honestly — two FRESH child processes sharing one
-    cache directory, each paying real imports, warmup and first batch.
-
-    THE CONTRACT ROW: warm_restart_compile_count == 0 — the second
-    start must load every executable (serving bucket ladder, fused
-    apply chunk, whole-step TrainStep) from the cache and compile
-    nothing at the cached sites. warm_restart_ttfb_seconds is the
-    payoff row (informative: wall time to first train batch of the
-    warm start, vs cold)."""
-    import shutil
-    import subprocess
-    import tempfile
-
-    # A throw-away directory for the repo's own executable store
-    # (MXNET_COMPILE_CACHE): this section measures that store cold and
-    # warm. It is not the program's compile cache (JAX's, placed by
-    # compile.enable_jax_cache), which the children leave off so that
-    # "cold" means compiled.
-    cache_dir = tempfile.mkdtemp(prefix="mx_cc_bench_")
-    env = dict(os.environ, MXNET_COMPILE_CACHE=cache_dir)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-
-    def run_child():
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--compile-cache-child", cache_dir],
-            env=env, capture_output=True, text=True, timeout=600)
-        if proc.returncode != 0:
-            raise RuntimeError("compile-cache child failed:\n%s"
-                               % proc.stderr[-2000:])
-        for line in reversed(proc.stdout.splitlines()):
-            line = line.strip()
-            if line.startswith("{"):
-                return json.loads(line)
-        raise RuntimeError("compile-cache child printed no JSON")
-
-    try:
-        cold = run_child()
-        warm = run_child()
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
-
-    sites = ("cached_op", "fused_apply", "train_step")
-    for site in sites:
-        _emit("compile_cache_cold_count[%s]" % site,
-              cold["compile_counts"].get(site, 0), "compiles")
-        _emit("compile_cache_warm_count[%s]" % site,
-              warm["compile_counts"].get(site, 0), "compiles")
-    _emit("cold_start_ttfb_seconds", cold["ttfb_train_s"], "s")
-    _emit("cold_start_serving_ttfb_seconds", cold["ttfb_serving_s"], "s")
-    # THE CONTRACT ROW: a warm restart compiles NOTHING at the cached
-    # sites — every executable deserializes from the persistent cache.
-    _emit("warm_restart_compile_count",
-          sum(warm["compile_counts"].get(site, 0) for site in sites),
-          "compiles")
-    _emit("warm_restart_ttfb_seconds", warm["ttfb_train_s"], "s")
-    _emit("warm_restart_serving_ttfb_seconds", warm["ttfb_serving_s"],
-          "s")
 
 
 def _load_rows(path):
@@ -1793,16 +1630,9 @@ def main():
                         help="emit per-site compile count/seconds "
                              "deltas (B - A) from two bench outputs "
                              "and exit (no device needed)")
-    parser.add_argument("--compile-cache-child", metavar="CACHE_DIR",
-                        help="internal: run one simulated process start "
-                             "against CACHE_DIR and print its TTFB + "
-                             "compile counts (the compile_cache "
-                             "section's cold/warm worker)")
     args = parser.parse_args()
     if args.compare:
         return compare(args.compare[0], args.compare[1])
-    if args.compile_cache_child:
-        return _compile_cache_child(args.compile_cache_child)
 
     failed = []
 
@@ -1815,11 +1645,6 @@ def main():
             print("bench %s failed:" % name, file=sys.stderr)
             traceback.print_exc()
             failed.append(name)
-
-    # A chip belongs to one process at a time. This section's two fresh
-    # children each need it, so they run BEFORE this process touches
-    # JAX; afterwards the parent holds the chip and starts no child.
-    section("compile_cache", _compile_cache_rows)
 
     from mxnet_tpu.compile import enable_jax_cache
 

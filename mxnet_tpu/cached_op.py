@@ -107,18 +107,6 @@ class CachedOp:
         self._op = Operator(name, pure, needs_rng=True, train_aware=True)
         self._op.fwd_name = "mx_cached_fwd"
         self._op.vjp_name = "mx_cached_vjp"
-        # Persistent compilation cache (mxnet_tpu.compile): when enabled,
-        # this op's per-signature executables build through the cached
-        # seam — a warm restart (or an elastic peer with a warm pod
-        # cache) traces but does NOT compile. The attrs/named key is
-        # restart-stable; the per-process op counter in `name`
-        # deliberately is NOT part of the cache key — the HLO
-        # fingerprint identifies the graph.
-        from . import compile as _cc
-
-        if _cc.enabled():
-            self._op.jit_wrapper = lambda fn, key: _cc.cached_compile(
-                fn, "cached_op", key_parts=("cached_op", key))
         # Off-ladder shape canonicalization (recompile elimination):
         # set via pad_to_buckets().
         self._pad_policy = None

@@ -61,11 +61,6 @@ CATALOGUE = [
          "low-precision weight dtypes that keep an fp32 master copy "
          "when multi_precision=True (mp_sgd/mp_adam master-weight "
          "contract)", False),
-    Knob("MXNET_COMPILE_CACHE_SHARED", bool, False, "compile/",
-         "every rank's MXNET_COMPILE_CACHE points at ONE shared "
-         "directory (NFS/GCS-fuse): skip the kvstore cc_* distribution "
-         "channel — entries already commit atomically, so concurrent "
-         "ranks are safe", False),
     Knob("MXNET_GATEWAY_MAX_QUEUE", int, 256, "serving/gateway.py",
          "inference gateway: TOTAL queued requests across all "
          "registered models (one bounded admission pool); past it "
@@ -135,19 +130,6 @@ CATALOGUE = [
     Knob("MXNET_DATA_MAX_WORKERS", int, 16, "data/autoscale.py",
          "decode-pool autoscaling ceiling: DecodeAutoscaler never grows "
          "a pool past this many workers", False),
-    Knob("MXNET_COMPILE_CACHE", str, "", "compile/",
-         "persistent compilation cache directory (empty = disabled): "
-         "warm restarts load executables instead of recompiling at the "
-         "cached_op / fused_apply / train_step seams", False),
-    Knob("MXNET_COMPILE_CACHE_MB", int, 2048, "compile/store.py",
-         "compile-cache retention budget; oldest-by-mtime entries are "
-         "retired past it (hits re-touch their entry)", False),
-    Knob("MXNET_PS_CC_ENTRY_MB", int, 64, "compile/distribute.py",
-         "largest compile-cache entry distributed over the kvstore; "
-         "bigger executables stay local-only", False),
-    Knob("MXNET_PS_CC_BUFFER_MB", int, 256, "kvstore_server.py",
-         "kvstore server's compile-cache buffer bound (total bytes, "
-         "drop-oldest)", False),
     Knob("MXNET_TPU_PS_HEARTBEAT", float, 5.0, "kvstore_dist.py",
          "worker->scheduler liveness ping interval in seconds (feeds "
          "get_dead_nodes)", False),

@@ -254,19 +254,12 @@ class Executor:
         if fn is None:
             fn = self._forward_fn(is_train)
             if not self._node_device:
-                # One XLA executable for the whole graph, built through
-                # the persistent-compile-cache seam: a warm restart (or
-                # a gateway checkpoint-model warmup) loads the
-                # executable instead of recompiling — simple_bind
-                # Executors were the last compile site outside the
-                # cached seams. With group placement active the graph
-                # instead runs eagerly so each op executes on its
-                # group's device (a single executable cannot span
-                # explicitly placed devices without a mesh).
-                from . import compile as _cc
-
-                fn = _cc.maybe_cached_jit(
-                    fn, "executor", key_parts=("executor", bool(is_train)))
+                # One XLA executable for the whole graph. With group
+                # placement active the graph instead runs eagerly so
+                # each op executes on its group's device (a single
+                # executable cannot span explicitly placed devices
+                # without a mesh).
+                fn = jax.jit(fn)
             self._fwd_cache[is_train] = fn
         arg_vals = [a._data for a in self.arg_arrays]
         aux_vals = [a._data for a in self.aux_arrays]

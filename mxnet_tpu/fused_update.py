@@ -555,12 +555,6 @@ class FusedApplier:
         # prefix under site fused_apply, where it never counted.
         chunk_fn.__name__ = "mx_fused_" + spec.name
 
-        # Persistent compilation cache (mxnet_tpu.compile): the chunk
-        # executable is THE fused_apply compile site — under the cache a
-        # warm restart deserializes it instead of recompiling. The
-        # flatten executable rides the same seam.
-        from . import compile as _cc
-
         # Donation (TPU/GPU): the flat weight and state inputs alias
         # their same-shaped outputs, so the steady-state fused cache
         # holds one flat copy, not two. The mp variant's low-precision
@@ -569,14 +563,8 @@ class FusedApplier:
         jit_kwargs = {}
         if donate_enabled():
             jit_kwargs["donate_argnums"] = (2,) if spec.mp else (1, 2)
-        key = ("fused_apply", spec.name, repr(spec.statics), repr(sig),
-               "scale" if with_scale else "",
-               "donate" if jit_kwargs else "")
         ch = _ApplyChunk(
-            _cc.maybe_cached_jit(chunk_fn, "fused_apply", key_parts=key,
-                                 **jit_kwargs),
-            _cc.maybe_cached_jit(mx_flatten_chunk, "fused_flatten",
-                                 key_parts=("fused_flatten", repr(sig))),
+            jax.jit(chunk_fn, **jit_kwargs), jax.jit(mx_flatten_chunk),
             tuple(shapes), sizes, offsets, k)
         ch.mp = spec.mp
         ch.base_k = spec.base_k

@@ -646,32 +646,6 @@ class KVStoreDist(KVStoreLocal):
         ``(seq, kind, msg)`` (seq 0 = never requested)."""
         return self._call(0, ("diag_request_check", _xtrace.inject()))
 
-    # -- pod compile-cache channel (compile.distribute rides this) ------------
-    # Persistent-compile-cache entries cross the same worker->server
-    # wire (server 0, the telemetry/diag convention): rank 0 publishes
-    # executables it compiled fire-and-forget; a rank that misses
-    # locally probes + pulls instead of compiling. Entries are NOT
-    # drained on pull — they serve every later elastic joiner — and the
-    # server bounds its buffer by total bytes, dropping oldest.
-
-    def cc_push(self, key, meta, blob):
-        """Publish one compile-cache entry (pipelined ack, push fast
-        path)."""
-        self._post(0, ("cc_push", key, meta, blob,
-                          _xtrace.inject()))
-
-    def cc_probe(self, keys=None):
-        """Which of ``keys`` the pod rendezvous currently holds;
-        ``None`` enumerates EVERY held key (one round-trip for a
-        joiner's whole-store prefetch)."""
-        return self._call(0, ("cc_probe",
-                              None if keys is None else list(keys),
-                              _xtrace.inject()))
-
-    def cc_pull(self, key):
-        """Fetch one entry: ``(meta, blob)`` or None."""
-        return self._call(0, ("cc_pull", key, _xtrace.inject()))
-
     def set_gradient_compression(self, compression_params):
         from .gradient_compression import GradientCompression
 

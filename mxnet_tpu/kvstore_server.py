@@ -315,15 +315,6 @@ class KVStoreServer:
         self._diag_bound = int(os.environ.get(
             "MXNET_PS_DIAG_BUFFER", "16"))
         self._diag_request = (0, None, None)    # (seq, kind, msg)
-        # Compile-cache rendezvous (mxnet_tpu.compile.distribute):
-        # key -> (meta, blob), insertion-ordered so the byte bound drops
-        # the OLDEST entries (the executables a joiner still wants are
-        # the newest ladder's). Entries are never drained on pull —
-        # unlike diag bundles they serve every later elastic joiner.
-        self._cc = {}
-        self._cc_bytes = 0
-        self._cc_bound = int(os.environ.get(
-            "MXNET_PS_CC_BUFFER_MB", "256")) * (1 << 20)
         self._updater = None
         self._opt_blob = None       # pickled optimizer for snapshots
         self._sync_mode = True
@@ -628,29 +619,6 @@ class KVStoreServer:
             self._send(conn, ("val", seq))
         elif cmd == "diag_request_check":
             self._send(conn, ("val", self._diag_request))
-        elif cmd == "cc_push":
-            # Compile-cache rendezvous: (key, meta, blob[, ctx]).
-            # Replacing an existing key re-inserts it at the fresh end;
-            # the byte bound then retires oldest-first. Pipelined ack.
-            key, meta, blob = msg[1], msg[2], msg[3]
-            old = self._cc.pop(key, None)
-            if old is not None:
-                self._cc_bytes -= len(old[1])
-            if self._cc_bound > 0 and len(blob) <= self._cc_bound:
-                self._cc[key] = (meta, blob)
-                self._cc_bytes += len(blob)
-                while self._cc_bytes > self._cc_bound and self._cc:
-                    oldest = next(iter(self._cc))    # insertion order
-                    self._cc_bytes -= len(self._cc.pop(oldest)[1])
-            self._send(conn, ("ok",))
-        elif cmd == "cc_probe":
-            # keys=None enumerates every held key — the one-round
-            # whole-buffer listing a joiner's prefetch rides.
-            self._send(conn, ("val", list(self._cc)
-                              if msg[1] is None else
-                              [k for k in msg[1] if k in self._cc]))
-        elif cmd == "cc_pull":
-            self._send(conn, ("val", self._cc.get(msg[1])))
         elif cmd == "profiler":
             # Remote server profiling (reference
             # KVStoreServerProfilerCommand, include/mxnet/kvstore.h:49,

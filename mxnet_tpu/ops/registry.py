@@ -85,12 +85,6 @@ class Operator:
         self.aliases = tuple(aliases)
         self.needs_rng = needs_rng
         self.train_aware = train_aware
-        # Optional compile seam: when set (CachedOp under the persistent
-        # compilation cache), jitted() builds executables through
-        # `jit_wrapper(bound_fn, (attrs_key, named))` instead of a plain
-        # jax.jit — generic small ops never pay the wrapper's per-call
-        # signature hash; only whole-graph CachedOps opt in.
-        self.jit_wrapper = None
         # Names of this op's forward and vjp executables (named_fn); a
         # CachedOp, whose `name` holds a per-process counter, overrides
         # both.
@@ -125,12 +119,9 @@ class Operator:
         hit = self._jit_cache.get(key)
         if hit is None:
             fn = named_fn(self.bound_fn(attrs, named), self.fwd_name)
-            if self.jit_wrapper is not None:
-                hit = self.jit_wrapper(fn, key)
-            else:
-                import jax
+            import jax
 
-                hit = jax.jit(fn)
+            hit = jax.jit(fn)
             self._jit_cache[key] = hit
         return hit
 

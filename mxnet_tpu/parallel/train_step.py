@@ -582,17 +582,11 @@ class TrainStep:
                         self._repl, self._repl, self._repl)
         out_shardings = (shardings, state_shardings, aux_shardings,
                          self._repl)
-        # Persistent compilation cache (mxnet_tpu.compile): the whole-
-        # step executable is the single largest compile in the system —
-        # under the cache a warm restart deserializes it. key_parts are
-        # the restart-stable configuration; param shapes/dtypes and the
-        # step graph itself are covered by the HLO fingerprint.
-        self._jitted = _cc.maybe_cached_jit(
-            step, "train_step",
-            key_parts=("train_step", self.optimizer,
-                       repr(sorted(self.mesh.shape.items())),
-                       repr(self._dtype), self.deterministic_reduction),
-            in_shardings=in_shardings, out_shardings=out_shardings,
+        # The whole-step executable is the single largest compile in
+        # the system; a warm restart loads it from JAX's persistent
+        # cache (compile.enable_jax_cache).
+        self._jitted = jax.jit(
+            step, in_shardings=in_shardings, out_shardings=out_shardings,
             donate_argnums=(0, 1, 2))
 
     # -- public API -----------------------------------------------------------

@@ -27,8 +27,8 @@ fix, per Orca's iteration-level scheduling and vLLM's paged KV state
   page-count, so the step executable signature is (page-count,) — slot
   churn, ragged lengths, and admit/retire at every iteration never
   retrace. Prompts canonicalize onto a length ladder the same way.
-  Every executable builds through ``compile.maybe_cached_jit`` (site
-  ``"decode_step"``) and so rides the persistent compile cache.
+  Every executable is a plain ``jax.jit``, warm on a second start from
+  JAX's persistent cache (``compile.enable_jax_cache``).
 
 Telemetry: ``mx_decode_slot_occupancy`` / ``mx_decode_tokens_total`` /
 ``mx_decode_steps_total`` / ``mx_decode_ttft_seconds`` (all
@@ -270,9 +270,8 @@ class _Sequence:
 
 class _DecodeBackend:
     """Device half of the decode loop: the paged state buffers and the
-    jitted step/prefill/place executables, all through
-    ``compile.maybe_cached_jit`` (site ``"decode_step"``) so a warm
-    restart traces but does not compile.
+    jitted step/prefill/place executables, which a warm restart
+    traces but loads from JAX's persistent cache.
 
     ``compile_count`` counts trace events exactly like CachedOp
     ``num_traces`` (the counter body runs only at trace time): flat
@@ -286,8 +285,6 @@ class _DecodeBackend:
         import jax
 
         from .. import autograd
-        from .. import compile as _cc
-
         from ..context import current_context
 
         self.config = config
@@ -345,15 +342,13 @@ class _DecodeBackend:
             next_tok = jnp.where(active, tok.astype(jnp.int32), tokens)
             return tuple(merged), next_tok
 
-        self._step = _cc.maybe_cached_jit(
-            step_pure, "decode_step", key_parts=("decode_step", name))
+        self._step = jax.jit(step_pure)
 
         def place_pure(page, row, idx):
             backend.num_traces += 1
             return jax.lax.dynamic_update_index_in_dim(page, row, idx, 0)
 
-        self._place = _cc.maybe_cached_jit(
-            place_pure, "decode_step", key_parts=("decode_place", name))
+        self._place = jax.jit(place_pure)
         self._zero_rows = [np.zeros(shape, cfg.state_dtype)
                            for shape in cfg.state_shapes]
 
@@ -375,9 +370,7 @@ class _DecodeBackend:
                 f = first._data if isinstance(first, NDArray) else first
                 return rows, jnp.squeeze(f.astype(jnp.int32), axis=0)
 
-            self._prefill = _cc.maybe_cached_jit(
-                prefill_pure, "decode_step",
-                key_parts=("decode_prefill", name))
+            self._prefill = jax.jit(prefill_pure)
         else:
             self._prefill = None
 

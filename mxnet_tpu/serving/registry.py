@@ -219,8 +219,7 @@ class ModelSpec:
                                  % self.name)
             pvals = self.params if params is None else list(params)
             if self.mesh_axes is not None:
-                return MeshShardedModel(self.fn, pvals, self.mesh_axes,
-                                        name=self.name)
+                return MeshShardedModel(self.fn, pvals, self.mesh_axes)
             if self.quantize:
                 return QuantizedFnModel(self.fn, pvals, self.quantize)
             return _FnModel(self.fn, pvals)
@@ -400,8 +399,7 @@ class MeshShardedModel:
     """fn backend whose bucket executables are compiled over a
     ``jax.sharding.Mesh`` — params laid out sharded (the Megatron-ish
     ``parallel.mesh.shard_params`` rule), batch and outputs replicated,
-    one executable per bucket shape through
-    ``compile.maybe_cached_jit(site="serving_mesh")``.
+    one ``jax.jit`` executable per bucket shape.
 
     Multi-process contract (a mesh spanning processes): every process
     must call the backend in LOCKSTEP with identical data — the device
@@ -409,12 +407,10 @@ class MeshShardedModel:
     2-process acceptance test (tests/gateway_mesh_prog.py) drives it
     with a deterministic request schedule."""
 
-    def __init__(self, fn, params, mesh_axes, name="mesh",
-                 param_rule=None):
+    def __init__(self, fn, params, mesh_axes, param_rule=None):
         import jax
 
         from .. import autograd
-        from .. import compile as _cc
         from .. import random as _random
         from ..parallel.mesh import make_mesh, replicate, shard_params
 
@@ -457,10 +453,9 @@ class MeshShardedModel:
                 return tuple(o._data for o in out)
             return out._data
 
-        self._exec = _cc.maybe_cached_jit(
-            pure, "serving_mesh", key_parts=("serving_mesh", name),
-            in_shardings=tuple([self._repl] + self.param_shardings
-                               + [self._repl]),
+        self._exec = jax.jit(
+            pure, in_shardings=tuple([self._repl] + self.param_shardings
+                                     + [self._repl]),
             out_shardings=self._repl)
         self._shapes = set()
 

@@ -113,9 +113,8 @@ wall-clock go" on a HEALTHY pod:
 * :mod:`.attribution` — :class:`StepAttribution`:
   ``mx_step_phase_seconds{phase}`` per-step decomposition (data_wait /
   h2d / dispatch / device_compute / allreduce / checkpoint / other),
-  the one-hot ``mx_step_bound{cause}`` classifier + ``input_bound``
-  anomaly, and ``mx_executable_flops{site}`` from ``cost_analysis()``
-  at the compile seam (achieved-FLOPs accounting).
+  and the one-hot ``mx_step_bound{cause}`` classifier + ``input_bound``
+  anomaly.
 * :mod:`.remote_write` — the Prometheus remote-write wire format
   (pure-python protobuf ``WriteRequest`` + snappy framing) as
   ``PushExporter(wire_format="remote_write")``.

@@ -241,11 +241,6 @@ class LocalBus:
     # own bound so LocalBus tests exercise the same drop behavior.
     MAX_DIAG_PER_RANK = 16
 
-    # Compile-cache buffer bound (bytes), matching the kvstore server's
-    # MXNET_PS_CC_BUFFER_MB default so LocalBus tests exercise the same
-    # drop-oldest behavior.
-    MAX_CC_BYTES = 256 << 20
-
     def __init__(self, num_workers=1, clock=time.monotonic):
         self.num_workers = int(num_workers)
         self._clock = clock
@@ -253,8 +248,6 @@ class LocalBus:
         self._store = {}            # rank -> (received_at, blob)
         self._diag = {}             # rank -> [(name, blob), ...]
         self._diag_request = (0, None, None)    # (seq, kind, msg)
-        self._cc = {}               # key -> (meta, blob), insertion order
-        self._cc_bytes = 0
 
     def push(self, rank, blob):
         with self._lock:
@@ -290,33 +283,6 @@ class LocalBus:
         with self._lock:
             return self._diag_request
 
-    # -- compile-cache channel (compile.distribute rides this) ----------------
-
-    def cc_push(self, key, meta, blob):
-        with self._lock:
-            old = self._cc.pop(key, None)
-            if old is not None:
-                self._cc_bytes -= len(old[1])
-            bound = self.MAX_CC_BYTES
-            if bound > 0 and len(blob) <= bound:
-                self._cc[key] = (meta, blob)
-                self._cc_bytes += len(blob)
-                while self._cc_bytes > bound and self._cc:
-                    oldest = next(iter(self._cc))
-                    self._cc_bytes -= len(self._cc.pop(oldest)[1])
-
-    def cc_probe(self, keys=None):
-        # keys=None enumerates every held key (whole-store prefetch),
-        # mirroring the kvstore server's cc_probe contract.
-        with self._lock:
-            if keys is None:
-                return list(self._cc)
-            return [k for k in keys if k in self._cc]
-
-    def cc_pull(self, key):
-        with self._lock:
-            return self._cc.get(key)
-
     def endpoint(self, rank):
         return _LocalEndpoint(self, int(rank))
 
@@ -344,15 +310,6 @@ class _LocalEndpoint:
 
     def diag_request_check(self):
         return self._bus.diag_request_check()
-
-    def cc_push(self, key, meta, blob):
-        self._bus.cc_push(key, meta, blob)
-
-    def cc_probe(self, keys=None):
-        return self._bus.cc_probe(keys)
-
-    def cc_pull(self, key):
-        return self._bus.cc_pull(key)
 
 
 # -- the aggregator -----------------------------------------------------------

@@ -38,14 +38,6 @@ one-hot ``mx_step_bound{cause}`` gauge (``input-bound`` /
 stays above threshold for K consecutive windows — the "your accelerator
 is starving" page, fired from measurements, not vibes.
 
-The module also owns the **achieved-FLOPs substrate**: the
-``compile.maybe_cached_jit`` seam reports each executable's
-``cost_analysis()`` flops/bytes per (site, key) via
-:func:`record_executable_cost` into ``mx_executable_flops{site}`` /
-``mx_executable_bytes{site}``, so bench (and ``/debug/attribution``)
-can report achieved-FLOPs utilization = executable flops × steps /
-device seconds.
-
 Span consumption is **non-destructive**: the evaluator snapshots the
 live trace rings (``trace.chrome_trace``) and advances a
 span-*end-time* watermark, so streaming export, flight-recorder span
@@ -54,7 +46,6 @@ each other.
 """
 from __future__ import annotations
 
-import threading
 import time
 
 from . import metrics as _metrics
@@ -62,8 +53,7 @@ from . import trace as _trace
 from .. import log as _log
 
 __all__ = ["StepAttribution", "PHASES", "BOUND_CAUSES",
-           "set_device_spans", "device_spans_enabled",
-           "record_executable_cost", "executable_costs"]
+           "set_device_spans", "device_spans_enabled"]
 
 PHASES = ("data_wait", "h2d", "dispatch", "device_compute", "allreduce",
           "checkpoint", "other")
@@ -91,14 +81,6 @@ _bound_gauge = _metrics.REGISTRY.gauge(
     "One-hot bound-cause classification of the last attribution window "
     "(input-bound / compute-bound / comm-bound / host-bound)",
     labels=("cause",))
-_flops_gauge = _metrics.REGISTRY.gauge(
-    "mx_executable_flops",
-    "cost_analysis() flops of the newest executable compiled/loaded at "
-    "each maybe_cached_jit site", labels=("site",))
-_bytes_gauge = _metrics.REGISTRY.gauge(
-    "mx_executable_bytes",
-    "cost_analysis() bytes accessed of the newest executable at each "
-    "maybe_cached_jit site", labels=("site",))
 
 # Device-span switch (train_step::device block_until_ready bracket).
 # A list cell, the metrics._enabled idiom: modules that cached a
@@ -119,61 +101,6 @@ def set_device_spans(on):
 
 def device_spans_enabled():
     return _device_spans[0]
-
-
-# -- executable cost accounting (the compile seam reports here) ---------------
-
-_costs = {}                 # site -> {key, flops, bytes_accessed, ...}
-_costs_lock = threading.Lock()
-
-
-def _cost_scalar(analysis, field):
-    """cost_analysis() returns one dict (or a per-device list of them,
-    older jax) of float properties; absent fields are None."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    try:
-        value = analysis.get(field)
-    except AttributeError:
-        return None
-    return None if value is None else float(value)
-
-
-def record_executable_cost(site, compiled, key=None):
-    """Record one compiled/loaded executable's ``cost_analysis()``
-    flops + bytes under its compile site. Failures return None — cost
-    analysis is advisory (deserialized executables on some backends
-    cannot produce it) and must never fail a dispatch."""
-    try:
-        analysis = compiled.cost_analysis()
-    except Exception:
-        return None
-    flops = _cost_scalar(analysis, "flops")
-    nbytes = _cost_scalar(analysis, "bytes accessed")
-    if flops is None and nbytes is None:
-        return None
-    rec = {"key": key, "flops": flops, "bytes_accessed": nbytes,
-           "recorded": time.time()}
-    with _costs_lock:
-        _costs[str(site)] = rec
-    if flops is not None:
-        _flops_gauge.labels(site=str(site)).set(flops)
-    if nbytes is not None:
-        _bytes_gauge.labels(site=str(site)).set(nbytes)
-    return rec
-
-
-def executable_costs():
-    """``{site: {key, flops, bytes_accessed, recorded}}`` — the newest
-    per-site executable cost records (bench's achieved-FLOPs input)."""
-    with _costs_lock:
-        return {site: dict(rec) for site, rec in _costs.items()}
-
-
-def reset_costs():
-    """Forget recorded executable costs (test isolation)."""
-    with _costs_lock:
-        _costs.clear()
 
 
 # -- the attributor -----------------------------------------------------------
@@ -350,7 +277,6 @@ class StepAttribution:
             "bound_cause": self.bound_cause,
             "input_bound_streak": self._streak,
             "windows": self.windows,
-            "executables": executable_costs(),
         }
 
     def close(self):

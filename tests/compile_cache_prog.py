@@ -1,110 +1,145 @@
-"""Worker program for the 2-process compile-cache acceptance test
-(tests/test_compile_cache.py, launched via tools/launch.py roles).
+"""One process start against JAX's persistent cache, the child of
+tests/test_compile_cache.py: `python compile_cache_prog.py OUT.json`.
 
-Proves the ISSUE 11 distribution property over a REAL dist kvstore:
-rank 0 compiles the shared executables (a CachedOp bucket ladder, a
-fused-update chunk, a whole-step TrainStep) with the persistent cache
-enabled and publishes every entry over ``cc_push``; rank 1 starts with
-an EMPTY local cache directory, builds the same workload after a
-barrier, and performs ZERO local compiles at the shared sites — every
-executable arrives over ``cc_probe``/``cc_pull`` (and is committed to
-rank 1's own disk, so its NEXT restart doesn't even need the pod).
-"""
+Builds the programs of every compile seam from fixed seeds, with the
+cache where `JAX_COMPILATION_CACHE_DIR` says (placed by
+`compile.enable_jax_cache()`, as the entry points place it), and writes
+for each group of seams the `build` records of `compile.build_log()`
+made while it ran, as `[fun_name, outcome]`, and the values it computed,
+as hex. The test starts it twice on one directory: the second start has
+to load every program and return the first one's values to the bit."""
 import json
 import os
 import sys
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("XLA_FLAGS",
+                      "--xla_force_host_platform_device_count=2")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-import numpy as np                                      # noqa: E402
+import numpy as np  # noqa: E402
 
-import mxnet_tpu as mx                                  # noqa: E402
-from mxnet_tpu import autograd, gluon, nd               # noqa: E402
-from mxnet_tpu import compile as cc                     # noqa: E402
-from mxnet_tpu.cached_op import CachedOp                # noqa: E402
-from mxnet_tpu.gluon import nn                          # noqa: E402
-from mxnet_tpu.gluon import loss as gloss               # noqa: E402
-from mxnet_tpu.parallel import TrainStep                # noqa: E402
-from mxnet_tpu.telemetry import memstats                # noqa: E402
-from mxnet_tpu.telemetry import metrics as tmetrics     # noqa: E402
-
-SITES = ("cached_op", "fused_apply", "train_step")
+import mxnet_tpu as mx  # noqa: E402
+from mxnet_tpu import autograd, compile as cc, gluon, nd  # noqa: E402
+from mxnet_tpu.cached_op import CachedOp  # noqa: E402
+from mxnet_tpu.gluon import loss as gloss, nn  # noqa: E402
 
 
-def build_workload(rng):
-    """The shared executables: identical graphs on both ranks (fixed
-    prefixes => restart/rank-stable param names => identical HLO)."""
-    # CachedOp bucket ladder (the serving warmup shape).
-    w = nd.array(rng.rand(16, 8).astype(np.float32))
+def _rand(seed, *shape):
+    return np.random.RandomState(seed).rand(*shape).astype(np.float32)
 
-    def fwd(w_, x):
-        return nd.dot(x, w_)
 
-    op = CachedOp(fwd, num_params=1)
-    for rows in (1, 2, 4):
-        op.inference(w, nd.array(rng.rand(rows, 16).astype(np.float32)))
-
-    # Fused-update chunk.
-    net = nn.Dense(8, in_units=16, prefix="ccprog_")
-    net.initialize()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
-                            {"learning_rate": 0.1})
+def cached_op():
+    w, x = nd.array(_rand(1, 6, 3)), nd.array(_rand(2, 2, 6))
+    op = CachedOp(lambda w_, x_: nd.dot(x_, w_), num_params=1)
     with autograd.record():
-        loss = net(nd.array(rng.rand(4, 16).astype(np.float32))).sum()
-    loss.backward()
-    trainer.step(4)
+        w.attach_grad()
+        out = op(w, x)
+    out.backward()
+    return [out.asnumpy(), w.grad.asnumpy()]
 
-    # Whole-step TrainStep executable.
-    net2 = nn.Dense(4, in_units=8, prefix="ccprog_step_")
-    net2.initialize()
-    step = TrainStep(net2, gloss.L2Loss(), optimizer="sgd",
+
+def executor():
+    data = mx.sym.var("data")
+    net = mx.sym.FullyConnected(data, num_hidden=5, name="ccx_fc")
+    args = {"ccx_fc_weight": nd.array(_rand(3, 5, 7)),
+            "ccx_fc_bias": nd.zeros((5,)),
+            "data": nd.array(_rand(4, 3, 7))}
+    return [net.bind(mx.cpu(), args).forward(is_train=False)[0].asnumpy()]
+
+
+def fused():
+    mx.random.seed(5)
+    net = nn.Dense(8, in_units=16, prefix="cc_fused_")
+    net.initialize(force_reinit=True)
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    for seed in (6, 7):
+        with autograd.record():
+            loss = net(nd.array(_rand(seed, 4, 16))).sum()
+        loss.backward()
+        trainer.step(4)
+    return [p.data().asnumpy() for p in net.collect_params().values()]
+
+
+def train_step():
+    from mxnet_tpu.parallel import TrainStep
+
+    mx.random.seed(11)
+    net = nn.Dense(4, in_units=8, prefix="cc_step_")
+    net.initialize(force_reinit=True)
+    step = TrainStep(net, gloss.L2Loss(), optimizer="sgd",
                      optimizer_params={"learning_rate": 0.1})
-    out = step(rng.rand(4, 8).astype(np.float32),
-               rng.rand(4, 4).astype(np.float32))
-    float(np.asarray(out))
+    x, y = _rand(8, 8, 8), _rand(9, 8, 4)
+    return [np.asarray(step(x, y)) for _ in range(3)]
 
 
-def main():
-    out_dir = sys.argv[1]
-    kv = mx.kvstore.create("dist_sync")
-    rank = kv.rank
+def decode():
+    from mxnet_tpu.serving import DecodeConfig, ModelSpec
 
-    # Private per-rank cache directory — rank 1's starts EMPTY and
-    # nothing below may read a peer's disk.
-    local_dir = os.path.join(out_dir, "cache_rank%d" % rank)
-    cc.configure(local_dir)
-    cc.attach_kvstore(kv)
+    def step(w, state, tokens, pos):
+        return state + w, tokens + 1
 
-    rng = np.random.RandomState(7)      # identical shapes on both ranks
-    if rank == 0:
-        build_workload(rng)
-        kv.barrier()                    # entries pushed + acked first
-    else:
-        kv.barrier()                    # wait for rank 0's publishes
-        build_workload(rng)
+    def init(w, prompt, length):
+        return nd.reshape(w, (1, 4)) * 2, length + 3
 
-    counts = {site: rec["count"]
-              for site, rec in memstats.compile_stats().items()}
-    hits = {}
-    reg = tmetrics.REGISTRY.get("mx_compile_cache_hits_total")
-    for (site, source), child in reg.collect():
-        hits["%s/%s" % (site, source)] = child.value
-    result = {
-        "rank": rank,
-        "compile_counts": counts,
-        "hits": hits,
-        "local_entries": sorted(os.listdir(local_dir))
-        if os.path.isdir(local_dir) else [],
-    }
-    with open(os.path.join(out_dir, "result_rank%d.json" % rank),
-              "w") as f:
+    cfg = DecodeConfig(step, state_shape=(4,), init=init, page_slots=2,
+                       max_tokens=4, max_prompt_len=4)
+    spec = ModelSpec("cc_decode", params=[nd.array(_rand(12, 4))],
+                     max_batch=4, decode=cfg)
+    backend = spec.build_backend()
+    backend.warm()
+    first = backend.admit(1, [5, 6, 7])
+    n = backend.config.page_slots
+    tok = backend.step(1, np.full(n, first, np.int32),
+                       np.zeros(n, np.int32), np.array([False, True]))
+    return [np.asarray(first), tok, np.asarray(backend.pages[0][0])]
+
+
+def serving_mesh():
+    from mxnet_tpu.serving.registry import MeshShardedModel
+
+    model = MeshShardedModel(lambda w, x: nd.dot(x, w),
+                             [nd.array(_rand(13, 4, 6))], {"tp": 2})
+    return [model(nd.array(_rand(14, b, 4))).asnumpy() for b in (1, 2)]
+
+
+def inference_server():
+    from mxnet_tpu.serving import InferenceServer
+
+    srv = InferenceServer(lambda w, x: nd.dot(x, w),
+                          [nd.array(_rand(15, 4, 3))], item_shape=(4,),
+                          max_batch=4, max_delay_ms=5)
+    with srv:
+        srv.warmup()
+        return [srv.predict(_rand(16, 1, 4)).asnumpy(),
+                np.asarray(srv.compile_count)]
+
+
+GROUPS = (cached_op, executor, fused, train_step, decode, serving_mesh,
+          inference_server)
+
+
+def main(out_path):
+    cache_dir = cc.enable_jax_cache()
+    result = {"cache_dir": cache_dir, "groups": {}}
+    for group in GROUPS:
+        seen = len(cc.build_log())
+        try:
+            values = [np.ascontiguousarray(v).tobytes().hex()
+                      for v in group()]
+            error = None
+        except Exception as exc:      # one seam's fault is that case's
+            values, error = [], "%s: %s" % (type(exc).__name__, exc)
+        result["groups"][group.__name__] = {
+            "values": values, "error": error,
+            "builds": [[r.fun_name, r.outcome]
+                       for r in cc.build_log()[seen:] if r.kind == "build"]}
+    with open(out_path, "w") as f:
         json.dump(result, f)
-
-    kv.barrier()
-    kv.close()
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1]))

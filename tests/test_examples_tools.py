@@ -432,31 +432,3 @@ def test_train_resnet_trainstep_blessed_path():
                 "--steps", "18", "--batch-size", "16",
                 "--samples", "128"], timeout=500)
     assert "img/s (post-compile)" in out and "checkpoint" in out
-
-
-def test_compile_cache_tool_smoke(tmp_path):
-    """tools/compile_cache.py inspect/verify/gc over a real store
-    layout (entries written through the store's commit protocol)."""
-    import json
-
-    from mxnet_tpu.compile.store import CompileCacheStore, make_key
-
-    cache = str(tmp_path / "cc")
-    store = CompileCacheStore(cache)
-    for i in range(2):
-        store.put(make_key(["tool_smoke", i]), b"payload" * 50,
-                  {"site": "cached_op", "compile_seconds": 1.5,
-                   "backend": {"platform": "cpu", "device_kind": "cpu",
-                               "num_devices": 2, "jax": "x",
-                               "jaxlib": "y"}})
-    out = json.loads(_run([sys.executable, "tools/compile_cache.py",
-                           "inspect", cache]))
-    assert out["entries"] == 2
-    assert out["by_site"]["cached_op"]["entries"] == 2
-    assert out["warm_restart_saves_seconds"] == 3.0
-    out = json.loads(_run([sys.executable, "tools/compile_cache.py",
-                           "verify", cache]))
-    assert out["valid"] == 2 and out["damaged"] == 0
-    out = json.loads(_run([sys.executable, "tools/compile_cache.py",
-                           "gc", cache, "--max-mb", "0"]))
-    assert out["removed_entries"] == 2 and out["bytes_after"] == 0

@@ -672,12 +672,12 @@ class TestTracePropagation:
 class TestRetraceHazard:
     def test_if_on_traced_arg_fires(self, tmp_path):
         res = lint(tmp_path, """
-            from mxnet_tpu.compile import maybe_cached_jit
+            import jax
             def step(state, tokens):
                 if tokens > 0:
                     return state + 1
                 return state
-            _step = maybe_cached_jit(step, "decode_step")
+            _step = jax.jit(step)
             """, checks=["retrace-hazard"])
         assert checks_of(res) == ["retrace-hazard"]
         assert "'tokens'" in res.findings[0].message
@@ -686,14 +686,14 @@ class TestRetraceHazard:
         # The dominant repo idiom: the pure fn is a closure built in
         # __init__ and handed to the jit seam by name.
         res = lint(tmp_path, """
-            from mxnet_tpu.compile import maybe_cached_jit
+            import jax
             class Backend:
                 def __init__(self, cfg):
                     def step_pure(params, x):
                         if x.sum() > 0:
                             return x * params
                         return x
-                    self._step = maybe_cached_jit(step_pure, "s")
+                    self._step = jax.jit(step_pure)
             """, checks=["retrace-hazard"])
         assert checks_of(res) == ["retrace-hazard"]
 
@@ -751,26 +751,26 @@ class TestRetraceHazard:
         # Branching on config captured by closure (not a traced arg)
         # is trace-time specialization by design.
         res = lint(tmp_path, """
-            from mxnet_tpu.compile import maybe_cached_jit
+            import jax
             def build(cfg):
                 def step(state, x):
                     if cfg.single_state:
                         return state + x
                     return tuple(s + x for s in state)
-                return maybe_cached_jit(step, "site")
+                return jax.jit(step)
             """, checks=["retrace-hazard"])
         assert res.findings == []
 
     def test_justified_suppression_honored(self, tmp_path):
         res = lint(tmp_path, """
-            from mxnet_tpu.compile import maybe_cached_jit
+            import jax
             def step(x):
                 # mxlint: disable=retrace-hazard -- x is always a
                 # concrete host scalar at this seam, two traces total
                 if x > 0:
                     return x
                 return -x
-            _f = maybe_cached_jit(step, "site")
+            _f = jax.jit(step)
             """, checks=["retrace-hazard"])
         assert res.findings == [] and res.suppressed == 1
 

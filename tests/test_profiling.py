@@ -55,14 +55,12 @@ def _clean_state():
     twd.reset()
     hp.reset()
     attribution.set_device_spans(False)
-    attribution.reset_costs()
     yield
     if profiling.active_profiler() is not None:
         profiling.active_profiler().close()
     twd.reset()
     hp.reset()
     attribution.set_device_spans(False)
-    attribution.reset_costs()
 
 
 def _can_bind_localhost():
@@ -502,29 +500,6 @@ def test_train_step_device_span_gated_by_attribution():
         sums = attr.update()
         assert sums["device_compute"] >= 0.0
     assert not attribution.device_spans_enabled()   # restored
-
-
-def test_executable_cost_recording_via_compile_seam(tmp_path):
-    import jax.numpy as jnp
-
-    from mxnet_tpu import compile as cc
-
-    cc.reset()
-    try:
-        cc.configure(str(tmp_path / "cache"))
-        fn = cc.maybe_cached_jit(lambda a: (a * 2.0).sum(),
-                                 "prof_test_site")
-        assert isinstance(fn, cc.CachedFunction)
-        fn(jnp.ones((8, 8), jnp.float32))
-        costs = attribution.executable_costs()
-        assert "prof_test_site" in costs
-        rec = costs["prof_test_site"]
-        assert rec["flops"] is not None and rec["flops"] > 0
-        gauge = tmetrics.REGISTRY.get("mx_executable_flops")
-        assert gauge.labels(site="prof_test_site").value == \
-            rec["flops"]
-    finally:
-        cc.reset()
 
 
 # -- decode-pool autoscaling --------------------------------------------------

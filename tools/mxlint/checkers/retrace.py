@@ -3,16 +3,16 @@
 The recompile-elimination discipline (bucket ladders, pad-to-bucket
 canonicalization, the `num_traces` regression tests) dies quietly at one
 construct: a Python ``if`` whose condition reads a traced argument
-inside a function handed to ``maybe_cached_jit``/``cached_compile``/
-``jax.jit``. Under tracing the condition must concretize an abstract
-value — either it raises (``TracerBoolConversionError``) or, when the
-value happens to be concrete at trace time, it silently bakes one
-branch into the executable and every new value mints a fresh trace.
+inside a function handed to ``jax.jit``. Under tracing the condition
+must concretize an abstract value — either it raises
+(``TracerBoolConversionError``) or, when the value happens to be
+concrete at trace time, it silently bakes one branch into the
+executable and every new value mints a fresh trace.
 Both failure modes are invisible in small tests and catastrophic on a
 serving hot path.
 
-Enforced (narrow first cut): inside a function passed to one of the
-jit entry points (first positional argument, or a ``jit`` decorator),
+Enforced (narrow first cut): inside a function passed to ``jit``
+(first positional argument, or a ``jit`` decorator),
 an ``if`` STATEMENT whose test uses a parameter of that function is a
 finding, unless the use is trace-safe:
 
@@ -37,7 +37,6 @@ import ast
 from ..astutil import dotted
 from ..core import Checker, Finding
 
-_JIT_CALLEES = {"maybe_cached_jit", "cached_compile", "jit"}
 _SAFE_CALLS = {"isinstance", "len", "hasattr", "getattr", "callable",
                "type"}
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "weak_type"}
@@ -125,7 +124,7 @@ def _dynamic_uses(test, params):
 class RetraceHazardChecker(Checker):
     name = "retrace-hazard"
     description = ("no Python `if` on traced-array arguments inside "
-                   "functions passed to maybe_cached_jit/jax.jit — "
+                   "functions passed to jax.jit — "
                    "branch with jnp.where/lax.cond or mark the arg "
                    "static")
 
@@ -147,7 +146,7 @@ class RetraceHazardChecker(Checker):
         for node in ast.walk(mod.tree):
             if isinstance(node, ast.Call):
                 callee = (dotted(node.func) or "").split(".")[-1]
-                if callee not in _JIT_CALLEES or not node.args:
+                if callee != "jit" or not node.args:
                     continue
                 snames, snums = _static_params(node)
                 first = node.args[0]
@@ -164,17 +163,17 @@ class RetraceHazardChecker(Checker):
                         inner = (dotted(d.func) or "").split(".")[-1]
                         if inner == "partial" and d.args and (
                                 (dotted(d.args[0]) or "")
-                                .split(".")[-1] in _JIT_CALLEES):
+                                .split(".")[-1] == "jit"):
                             snames, snums = _static_params(d)
                             note(node, _traced_params(node, snames,
                                                       snums))
                             continue
-                        if inner in _JIT_CALLEES:
+                        if inner == "jit":
                             snames, snums = _static_params(d)
                             note(node, _traced_params(node, snames,
                                                       snums))
                             continue
-                    if (dotted(d) or "").split(".")[-1] in _JIT_CALLEES:
+                    if (dotted(d) or "").split(".")[-1] == "jit":
                         note(node, _traced_params(node))
 
         findings = []
