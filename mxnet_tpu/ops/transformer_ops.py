@@ -8,9 +8,12 @@ Weights are laid out (out, in), as `FullyConnected`'s.
 """
 from __future__ import annotations
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import metrics as _tm
 from .registry import register
 
 __all__ = ["rms_norm", "rotary_embedding", "gated_mlp", "noaux_tc_router"]
@@ -25,6 +28,19 @@ def rms_norm(data, gamma, eps=1e-6):
     return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
+_rotary_traced = _tm.REGISTRY.counter(
+    "mx_rotary_embedding_traced_total",
+    "rotary_embedding calls traced into a program, by pairing",
+    labels=("interleaved",))
+
+
+def _pairs_apart(d, dtype):
+    """The (d, d) 0/1 matrix that sends column 2i to i and 2i+1 to
+    i + d/2."""
+    return jnp.asarray(np.eye(d, dtype=np.float32)[:, np.r_[0:d:2, 1:d:2]],
+                       dtype)
+
+
 @register("_contrib_rotary_embedding", aliases=("rotary_embedding",))
 def rotary_embedding(data, theta=10000.0, interleaved=True):
     """Rotary embedding of `data` (..., seq, d) at positions 0..seq-1.
@@ -34,19 +50,38 @@ def rotary_embedding(data, theta=10000.0, interleaved=True):
     i + d/2 (the published `rope_interleave` path leaves them there: a
     fixed permutation of the width, the same for q and k, so every
     q.k is that of the interleaved result). Otherwise pair i is
-    (x[i], x[i + d/2]). Angles and products in fp32."""
-    d = data.shape[-1]
-    seq = data.shape[-2]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x = data.astype(jnp.float32)
-    if interleaved:
-        a, b = x[..., 0::2], x[..., 1::2]
-    else:
-        a, b = x[..., :d // 2], x[..., d // 2:]
-    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return out.astype(data.dtype)
+    (x[i], x[i + d/2]). Angles and products in fp32.
+
+    The interleaved pairs are parted by a product with a constant 0/1
+    matrix, accumulated in fp32: every output is one input times 1.0, so
+    the values are those of ``x[..., 0::2]`` and ``x[..., 1::2]`` to the
+    bit, and nothing is gathered forward or scattered backward (a
+    stride-2 pick of the minor axis is no lane operation on the TPU).
+    That holds for finite input: as through any product, an Inf or NaN
+    spreads over its row (`x * 0`), and a -0.0 may come back as 0.0."""
+    _rotary_traced.labels(interleaved=str(bool(interleaved)).lower()).inc()
+    with jax.named_scope("rotary_embedding"):
+        d = data.shape[-1]
+        seq = data.shape[-2]
+        inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        angle = jnp.arange(seq, dtype=jnp.float32)[:, None] \
+            * inv_freq[None, :]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        x = data.astype(jnp.float32)
+        if interleaved:
+            # a bf16 operand goes in as it is and the product widens it;
+            # any other as fp32 in as many passes as keep every bit
+            narrow = data.dtype == jnp.bfloat16
+            operand = data if narrow else x
+            x = jnp.einsum(
+                "...k,kj->...j", operand, _pairs_apart(d, operand.dtype),
+                precision=None if narrow else jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+        # split, not two slices: its transpose is one concatenate
+        a, b = jnp.split(x, 2, axis=-1)
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                              axis=-1)
+        return out.astype(data.dtype)
 
 
 @register("_contrib_gated_mlp", aliases=("gated_mlp",))

@@ -99,3 +99,30 @@ def test_flash_kernel_compiles_for_v5e_with_unequal_widths(one_chip,
     compiled = lowered.compile()
     assert compiled.memory_analysis().temp_size_in_bytes \
         < b * h * t * t * 4 // 4
+
+
+def test_rotary_compiles_for_v5e_without_gather_or_scatter(one_chip):
+    """Interleaved rotary at latent attention's q-rope, (1, 32, 4096, 64)
+    bf16, value and gradient: the pairs are parted by a product, so the
+    chip's compiler is left no gather, no scatter and no custom fusion
+    (how it keeps a stride-2 pick of the minor axis). `bytes accessed`
+    reads 473 MB, 7.0 times the four arrays a forward and backward must
+    move (x and the cotangent in, the value and the gradient out), where
+    the strided form read 1,210 MB: every fp32 intermediate is counted
+    whole, so three times is out of XLA's reach and eight is the bound."""
+    from mxnet_tpu.ops.transformer_ops import rotary_embedding
+
+    shape = (1, 32, 4096, 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def value_and_gradient(a, g):
+        out, vjp = jax.vjp(lambda b: rotary_embedding(b, theta=1e6), a)
+        return out, vjp(g)[0]
+
+    compiled = jax.jit(value_and_gradient).lower(x, x).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert " scatter(" not in text
+    assert "kind=kCustom" not in text
+    must = 4 * 2 * 32 * 4096 * 64
+    assert compiled.cost_analysis()["bytes accessed"] < 8 * must
