@@ -1,9 +1,10 @@
-"""Decoder-block layers: RMSNorm, latent attention (MLA), the SiLU-gated
+"""Decoder-block layers: RMSNorm, latent attention (MLA), gated
+grouped-query attention, Gated DeltaNet linear attention, the SiLU-gated
 MLP and a sparse mixture-of-experts layer that holds some of its experts.
 
 No reference counterpart (MXNet 1.3 predates them); parameter names and
-the equations follow the published `deepseek_v3` modeling code. Inputs
-are (batch, seq, hidden).
+the equations follow the published `deepseek_v3` and `qwen3_next`
+modeling code. Inputs are (batch, seq, hidden).
 """
 from __future__ import annotations
 
@@ -17,20 +18,25 @@ import jax
 from ..block import HybridBlock
 from ...telemetry import metrics as _tm
 
-__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "SparseMoE"]
+__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "GatedAttention",
+           "GatedDeltaNet", "SparseMoE"]
 
 
 class RMSNorm(HybridBlock):
-    """``weight * x / sqrt(mean(x^2) + epsilon)`` over the last axis."""
+    """``weight * x / sqrt(mean(x^2) + epsilon)`` over the last axis;
+    `zero_centered`: ``(1 + weight)``, the weight starting at 0."""
 
-    def __init__(self, in_channels, epsilon=1e-6, **kwargs):
+    def __init__(self, in_channels, epsilon=1e-6, zero_centered=False,
+                 **kwargs):
         super().__init__(**kwargs)
-        self._epsilon = epsilon
-        self.weight = self.params.get("weight", shape=(in_channels,),
-                                      init="ones")
+        self._epsilon, self._zero_centered = epsilon, bool(zero_centered)
+        self.weight = self.params.get(
+            "weight", shape=(in_channels,),
+            init="zeros" if zero_centered else "ones")
 
     def hybrid_forward(self, F, x, weight):
-        return F.contrib.RMSNorm(x, weight, eps=self._epsilon)
+        return F.contrib.RMSNorm(x, weight, eps=self._epsilon,
+                                 zero_centered=self._zero_centered)
 
 
 class GatedMLP(HybridBlock):
@@ -50,6 +56,17 @@ class GatedMLP(HybridBlock):
                        down_proj_weight):
         return F.contrib.gated_mlp(x, gate_proj_weight, up_proj_weight,
                                    down_proj_weight)
+
+
+def _proj(F, a, w):
+    return F.FullyConnected(a, w, no_bias=True, flatten=False,
+                            num_hidden=w.shape[0])
+
+
+def _heads_first(F, x, heads, width):
+    """(B, T, heads * width) -> (B, heads, T, width)."""
+    return F.transpose(F.reshape(x, shape=(0, 0, heads, width)),
+                       axes=(0, 2, 1, 3))
 
 
 class MLAttention(HybridBlock):
@@ -95,17 +112,11 @@ class MLAttention(HybridBlock):
                 kv_lora_rank))
         weight("o_proj", (hidden_size, num_heads * v_head_dim))
 
-    def _heads_first(self, F, x, width):
-        """(B, T, heads * width) -> (B, heads, T, width)."""
-        return F.transpose(F.reshape(x, shape=(0, 0, self._heads, width)),
-                           axes=(0, 2, 1, 3))
-
     def hybrid_forward(self, F, x, kv_a_proj_with_mqa_weight,
                        kv_b_proj_weight, o_proj_weight, q_proj_weight=None,
                        q_a_proj_weight=None, q_b_proj_weight=None):
         def proj(a, w):
-            return F.FullyConnected(a, w, no_bias=True, flatten=False,
-                                    num_hidden=w.shape[0])
+            return _proj(F, a, w)
 
         def rope(a):
             return F.contrib.rotary_embedding(
@@ -117,7 +128,7 @@ class MLAttention(HybridBlock):
             else:
                 q = proj(self.q_a_layernorm(proj(x, q_a_proj_weight)),
                          q_b_proj_weight)
-            q = self._heads_first(F, q, self._nope + self._rope)
+            q = _heads_first(F, q, self._heads, self._nope + self._rope)
             q_nope = F.slice_axis(q, axis=-1, begin=0, end=self._nope)
             q_rope = F.slice_axis(q, axis=-1, begin=self._nope, end=None)
 
@@ -126,8 +137,8 @@ class MLAttention(HybridBlock):
                                   end=None)
             latent = self.kv_a_layernorm(
                 F.slice_axis(latent, axis=-1, begin=0, end=self._kv_rank))
-            kv = self._heads_first(F, proj(latent, kv_b_proj_weight),
-                                   self._nope + self._v)
+            kv = _heads_first(F, proj(latent, kv_b_proj_weight),
+                              self._heads, self._nope + self._v)
             k_nope = F.slice_axis(kv, axis=-1, begin=0, end=self._nope)
             v = F.slice_axis(kv, axis=-1, begin=self._nope, end=None)
 
@@ -140,6 +151,154 @@ class MLAttention(HybridBlock):
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))
             return proj(out, o_proj_weight)
+
+
+class GatedAttention(HybridBlock):
+    """Causal grouped-query attention with an output gate, as the
+    `qwen3_next` family's full-attention layers have it.
+
+    ``[q | gate] = x Wq`` per head; q and k pass a zero-centred RMSNorm
+    over the head's width, then rotary embedding on the first
+    `partial_rotary_factor` of it, halves paired; `num_heads` query
+    heads read `num_kv_heads` key/value heads in groups
+    (`flash_attention`); the result times ``sigmoid(gate)`` goes through
+    the output projection. No bias anywhere."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
+                 rope_theta=10000.0, partial_rotary_factor=1.0,
+                 epsilon=1e-6, weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        self._heads, self._kv_heads, self._width = \
+            num_heads, num_kv_heads, head_dim
+        self._theta = float(rope_theta)
+        self._rotary = int(head_dim * partial_rotary_factor)
+
+        def weight(name, shape):
+            setattr(self, name + "_weight", self.params.get(
+                name + "_weight", shape=shape, init=weight_initializer))
+
+        weight("q_proj", (num_heads * head_dim * 2, hidden_size))
+        weight("k_proj", (num_kv_heads * head_dim, hidden_size))
+        weight("v_proj", (num_kv_heads * head_dim, hidden_size))
+        weight("o_proj", (hidden_size, num_heads * head_dim))
+        self.q_norm = RMSNorm(head_dim, epsilon, zero_centered=True,
+                              prefix=self.prefix + "q_norm_")
+        self.k_norm = RMSNorm(head_dim, epsilon, zero_centered=True,
+                              prefix=self.prefix + "k_norm_")
+
+    def _rope(self, F, x):
+        """Rotary embedding on the first `_rotary` of (B, heads, T, d)."""
+        if self._rotary == self._width:
+            return F.contrib.rotary_embedding(x, theta=self._theta,
+                                              interleaved=False)
+        turned = F.contrib.rotary_embedding(
+            F.slice_axis(x, axis=-1, begin=0, end=self._rotary),
+            theta=self._theta, interleaved=False)
+        return F.concat(turned, F.slice_axis(
+            x, axis=-1, begin=self._rotary, end=None), dim=-1)
+
+    def hybrid_forward(self, F, x, q_proj_weight, k_proj_weight,
+                       v_proj_weight, o_proj_weight):
+        heads, kv, d = self._heads, self._kv_heads, self._width
+        with jax.named_scope("gated_attention"):
+            qg = F.reshape(_proj(F, x, q_proj_weight),
+                           shape=(0, 0, heads, 2 * d))
+            gate = F.reshape(F.slice_axis(qg, axis=-1, begin=d, end=None),
+                             shape=(0, 0, -1))
+            q = self.q_norm(F.slice_axis(qg, axis=-1, begin=0, end=d))
+            k = self.k_norm(F.reshape(_proj(F, x, k_proj_weight),
+                                      shape=(0, 0, kv, d)))
+            q = self._rope(F, F.transpose(q, axes=(0, 2, 1, 3)))
+            k = self._rope(F, F.transpose(k, axes=(0, 2, 1, 3)))
+            v = _heads_first(F, _proj(F, x, v_proj_weight), kv, d)
+            out = F.contrib.flash_attention(q, k, v, causal=True)
+            out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
+                            shape=(0, 0, -1))
+            return _proj(F, out * F.sigmoid(gate), o_proj_weight)
+
+
+class GatedDeltaNet(HybridBlock):
+    """Gated DeltaNet linear attention (`qwen3_next`'s other token
+    mixer): one projection to ``[q | k | v | z]`` and one to ``[b | a]``,
+    a causal depthwise convolution with SiLU over q, k and v, q and k
+    L2-normalised per head, the gated delta rule with
+    ``beta = sigmoid(b)`` and log decay
+    ``g = -exp(A_log) * softplus(a + dt_bias)`` per value head
+    (`ops/linear_attention.py`), a gated RMSNorm per head with
+    ``silu(z)``, the output projection.
+
+    `in_proj_qkvz_weight` holds q, k, v and z one after the other (the
+    published checkpoint interleaves them per key head: a storage order);
+    `in_proj_ba_weight` b then a; `conv1d_weight` is (channels, width)
+    over q, k, v in that order. `A_log` starts at ``log U(0, 16)`` and
+    `dt_bias` at 1, as the published code has them."""
+
+    def __init__(self, hidden_size, num_k_heads, num_v_heads, head_k_dim,
+                 head_v_dim, conv_kernel=4, epsilon=1e-6, chunk=64,
+                 weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        from ... import initializer as _init
+
+        self._k_heads, self._v_heads = num_k_heads, num_v_heads
+        self._dk, self._dv = head_k_dim, head_v_dim
+        self._epsilon, self._chunk = epsilon, chunk
+        key, value = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+
+        def param(name, shape, init=weight_initializer):
+            setattr(self, name, self.params.get(name, shape=shape,
+                                                init=init))
+
+        param("in_proj_qkvz_weight", (2 * key + 2 * value, hidden_size))
+        param("in_proj_ba_weight", (2 * num_v_heads, hidden_size))
+        param("conv1d_weight", (2 * key + value, conv_kernel))
+        param("A_log", (num_v_heads,), _init.LogUniform(0.0, 16.0))
+        param("dt_bias", (num_v_heads,), "ones")
+        param("norm_weight", (head_v_dim,), "ones")
+        param("out_proj_weight", (hidden_size, value))
+
+    def hybrid_forward(self, F, x, in_proj_qkvz_weight, in_proj_ba_weight,
+                       conv1d_weight, A_log, dt_bias, norm_weight,
+                       out_proj_weight):
+        hk, hv, dk, dv = self._k_heads, self._v_heads, self._dk, self._dv
+        key, value = hk * dk, hv * dv
+
+        def unit(a, scale):
+            """a / |a| over the head's width, in fp32, times scale."""
+            a32 = F.cast(a, dtype="float32")
+            inv = F.rsqrt(F.sum(F.square(a32), axis=-1, keepdims=True)
+                          + 1e-6)
+            return F.cast(a32 * inv * scale, dtype=a.dtype)
+
+        with jax.named_scope("gated_delta_net"):
+            qkvz = _proj(F, x, in_proj_qkvz_weight)
+            mixed = F.contrib.causal_conv1d(
+                F.slice_axis(qkvz, axis=-1, begin=0, end=2 * key + value),
+                conv1d_weight)
+            mixed = mixed * F.sigmoid(mixed)                    # SiLU
+            z = F.slice_axis(qkvz, axis=-1, begin=2 * key + value, end=None)
+            q = unit(_heads_first(F, F.slice_axis(
+                mixed, axis=-1, begin=0, end=key), hk, dk), dk ** -0.5)
+            k = unit(_heads_first(F, F.slice_axis(
+                mixed, axis=-1, begin=key, end=2 * key), hk, dk), 1.0)
+            v = _heads_first(F, F.slice_axis(
+                mixed, axis=-1, begin=2 * key, end=None), hv, dv)
+            # (B, T, 2 * hv) -> (B, hv, T) each, fp32
+            ba = F.cast(F.transpose(_proj(F, x, in_proj_ba_weight),
+                                    axes=(0, 2, 1)), dtype="float32")
+            beta = F.sigmoid(F.slice_axis(ba, axis=1, begin=0, end=hv))
+            a = F.slice_axis(ba, axis=1, begin=hv, end=None)
+            per_head = lambda p: F.reshape(F.cast(p, dtype="float32"),
+                                           shape=(1, -1, 1))
+            g = -F.exp(per_head(A_log)) * F.Activation(
+                a + per_head(dt_bias), act_type="softrelu")
+            out = F.contrib.gated_delta_rule(q, k, v, g, beta,
+                                             chunk=self._chunk)
+            out = F.contrib.gated_rms_norm(
+                F.transpose(out, axes=(0, 2, 1, 3)),
+                F.reshape(z, shape=(0, 0, hv, dv)), norm_weight,
+                eps=self._epsilon)
+            return _proj(F, F.reshape(out, shape=(0, 0, -1)),
+                         out_proj_weight)
 
 
 _MOE_GAUGES = {
@@ -215,11 +374,16 @@ class SparseMoE(HybridBlock):
     `num_experts` routed experts (an expert-parallel chip's share), with
     `n_shared_experts` always-on experts fused into one wider MLP.
 
-    The `noaux_tc` router scores every expert; the held experts' part of
+    The router (`router`: ``"noaux_tc"``, sigmoid scores with a
+    selection bias, or ``"softmax"``, a softmax top-k with neither bias
+    nor state) scores every expert; the held experts' part of
     the result is computed here (`ops/moe.py`), the rest is left to the
-    chips that hold them. Non-gradient state, written by the training
+    chips that hold them. `shared_expert_gate`: the shared experts'
+    result is multiplied by ``sigmoid(x w)``, ``w`` (1, hidden).
+    Non-gradient state, written by the training
     forward as BatchNorm writes its running statistics:
-    `e_score_correction_steps` (the selection bias in whole steps of
+    `e_score_correction_steps` (`noaux_tc` alone: the selection bias in
+    whole steps of
     `bias_update_rate`; after each training step +1 for an expert picked
     by fewer tokens than the mean, -1 for more), and what telemetry
     reads: `expert_counts` (tokens that picked each expert) and
@@ -232,8 +396,12 @@ class SparseMoE(HybridBlock):
                  held=None, top_k=6, n_shared_experts=0,
                  routed_scaling_factor=1.0, norm_topk_prob=True, n_group=1,
                  topk_group=1, bias_update_rate=1e-3, capacity_factor=1.5,
+                 router="noaux_tc", shared_expert_gate=False,
                  weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
+        if router not in ("noaux_tc", "softmax"):
+            raise ValueError("router %r is neither noaux_tc nor softmax"
+                             % (router,))
         self._held = tuple(range(num_experts)) if held is None \
             else tuple(int(e) for e in held)
         self._num_experts, self._top_k = num_experts, top_k
@@ -241,11 +409,13 @@ class SparseMoE(HybridBlock):
         # what the telemetry hook has folded in: (steps_seen,
         # held_rows_sum, overflow_steps) as last read, and the rows in all
         self._folded, self._rows_total = (0, 0, 0), 0
-        self._router = dict(
-            top_k=top_k, gamma=float(bias_update_rate),
-            routed_scaling_factor=float(routed_scaling_factor),
-            norm_topk_prob=bool(norm_topk_prob), n_group=n_group,
-            topk_group=topk_group)
+        self._router_kind = router
+        self._router = dict(top_k=top_k, norm_topk_prob=bool(norm_topk_prob))
+        if router == "noaux_tc":
+            self._router.update(
+                gamma=float(bias_update_rate),
+                routed_scaling_factor=float(routed_scaling_factor),
+                n_group=n_group, topk_group=topk_group)
         n, width = len(self._held), moe_intermediate_size
 
         def param(name, shape, **kw):
@@ -257,7 +427,8 @@ class SparseMoE(HybridBlock):
 
         param("gate_weight", (num_experts, hidden_size),
               init=weight_initializer)
-        state("e_score_correction_steps", (num_experts,))
+        if router == "noaux_tc":
+            state("e_score_correction_steps", (num_experts,))
         state("expert_counts", (num_experts,))
         state("held_rows", (1,))
         state("steps_seen", (1,))
@@ -275,6 +446,9 @@ class SparseMoE(HybridBlock):
             hidden_size, width * n_shared_experts,
             weight_initializer=weight_initializer,
             prefix=self.prefix + "shared_experts_") if n_shared_experts else None
+        if shared_expert_gate:
+            param("shared_expert_gate_weight", (1, hidden_size),
+                  init=weight_initializer)
         with _moe_lock:
             _moe_layers[:] = [r for r in _moe_layers if r() is not None]
             _moe_layers.append(weakref.ref(self))
@@ -287,15 +461,21 @@ class SparseMoE(HybridBlock):
 
     def route(self, F, tokens, gate_weight, steps):
         """The router on `tokens` (rows, hidden): (weights (rows, top_k)
-        fp32, ids (rows, top_k) int32, counts (num_experts,) int32)."""
+        fp32, ids (rows, top_k) int32, counts (num_experts,) int32).
+        `steps` is the `noaux_tc` router's selection bias, None for the
+        softmax router, which has none."""
+        if self._router_kind == "softmax":
+            return F.contrib.softmax_topk_router(tokens, gate_weight,
+                                                 **self._router)
         return F.contrib.noaux_tc_router(tokens, gate_weight, steps,
                                          **self._router)
 
-    def hybrid_forward(self, F, x, gate_weight, e_score_correction_steps,
-                       expert_counts, held_rows, steps_seen, held_rows_sum,
-                       peak_count, overflow_steps,
+    def hybrid_forward(self, F, x, gate_weight, expert_counts, held_rows,
+                       steps_seen, held_rows_sum, peak_count, overflow_steps,
                        experts_gate_proj_weight, experts_up_proj_weight,
-                       experts_down_proj_weight):
+                       experts_down_proj_weight,
+                       e_score_correction_steps=None,
+                       shared_expert_gate_weight=None):
         from ... import autograd
 
         tokens = F.reshape(x, shape=(-3, 0))
@@ -309,12 +489,17 @@ class SparseMoE(HybridBlock):
         out = F.reshape_like(routed, x)
         if self.shared_experts is not None:
             with jax.named_scope("moe_shared"):
-                out = out + self.shared_experts(x)
+                shared = self.shared_experts(x)
+                if shared_expert_gate_weight is not None:
+                    shared = shared * F.sigmoid(
+                        _proj(F, x, shared_expert_gate_weight))
+                out = out + shared
         if autograd.is_training():
             rows = F.reshape(rows, shape=(1,))
-            self.e_score_correction_steps.set_data(
-                F.contrib.noaux_tc_bias_update(e_score_correction_steps,
-                                               counts))
+            if e_score_correction_steps is not None:
+                self.e_score_correction_steps.set_data(
+                    F.contrib.noaux_tc_bias_update(e_score_correction_steps,
+                                                   counts))
             self.expert_counts.set_data(counts)
             self.held_rows.set_data(rows)
             self.steps_seen.set_data(steps_seen + 1)

@@ -16,8 +16,9 @@ from . import random as _random
 from .registry_util import Registry
 
 __all__ = ["InitDesc", "Initializer", "Uniform", "Normal", "Xavier",
-           "MSRAPrelu", "Orthogonal", "Bilinear", "One", "Zero", "Constant",
-           "LSTMBias", "Mixed", "registry", "register"]
+           "LogUniform", "DeviceNormal", "MSRAPrelu", "Orthogonal",
+           "Bilinear", "One", "Zero", "Constant", "LSTMBias", "Mixed",
+           "registry", "register"]
 
 registry = Registry("initializer")
 
@@ -132,6 +133,40 @@ class Normal(Initializer):
     def _init_weight(self, desc, arr):
         arr[...] = _rng().normal(0, self.sigma, arr.shape)
         return arr
+
+
+@register("loguniform")
+class LogUniform(Initializer):
+    """``log(U(low, high))``: the log of a positive rate drawn evenly
+    (Gated DeltaNet's `A_log`)."""
+
+    def __init__(self, low=0.0, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, desc, arr):
+        arr[...] = np.log(np.maximum(
+            _rng().uniform(self.low, self.high, arr.shape),
+            np.finfo(np.float32).tiny))
+        return arr
+
+
+@register("devicenormal")
+class DeviceNormal(Initializer):
+    """`Normal`, drawn on the device by `mx.nd.random.normal` under
+    `mx.random.seed`: no host array of the weight's size is drawn or
+    copied (a 500 M-parameter model draws for 20 s with numpy). Other
+    values than `Normal`'s for the same seed."""
+
+    def __init__(self, sigma=0.01):
+        super().__init__(sigma=sigma)
+        self.sigma = sigma
+
+    def _init_weight(self, desc, arr):
+        from . import ndarray as nd
+
+        return nd.random.normal(0, self.sigma, shape=arr.shape,
+                                dtype=arr.dtype)
 
 
 @register("xavier")

@@ -24,5 +24,6 @@ from . import rcnn_ops  # noqa: F401
 from . import pallas_attention  # noqa: F401
 from . import transformer_ops  # noqa: F401
 from . import moe  # noqa: F401
+from . import linear_attention  # noqa: F401
 
 __all__ = ["registry", "register", "get", "list_all_ops", "OP_REGISTRY"]

@@ -40,6 +40,17 @@ divide by the block sizes. Block sizes left at None take the defaults
 below, chosen from sweeps on a TPU v5e at (1, 32, 4096, 192/128), causal
 (PERF.md, PRs 28 and 29); a block longer than the sequence is cut to it.
 
+Grouped queries: k and v may have fewer heads than q, a divisor; query
+head h then reads key/value head ``h // group``. The forward and the dQ
+kernel index their K and V blocks so; the dK/dV kernel walks the q-blocks
+of all the group's heads in its inner axis; the fused backward of a group
+(`_bwd_group_kernel`, the same tile under the same name `mx_flash_bwd`)
+puts the group's heads in a grid axis of their own between the key/value
+head and the k-blocks, with one key/value head's dK and dV in fp32 in
+VMEM across it, so dK and dV come out per key/value head and no
+per-query-head copy of them exists. At a group of one every kernel is
+what it was.
+
 Registered as `_contrib_flash_attention` for `nd`/`sym` access.
 """
 from __future__ import annotations
@@ -194,18 +205,22 @@ def _last_k(i, block_q, block_k):
     return ((i + 1) * block_q - 1) // block_k
 
 
-def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal):
+def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal, group=1):
     """Block specs of a (bh, q-blocks, k-blocks) grid. Under `causal`
     a skipped step names the block of the last computed one, so that
-    no copy is started for a block nobody reads."""
+    no copy is started for a block nobody reads. Query head `b_` reads
+    key/value head ``b_ // group``."""
     import jax.experimental.pallas as pl
 
     def kj(i, j):
         return jnp.minimum(j, _last_k(i, block_q, block_k)) if causal else j
 
+    def kv(b_):
+        return b_ if group == 1 else b_ // group
+
     q = lambda d: pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0))
     k = lambda d: pl.BlockSpec((1, block_k, d),
-                               lambda b_, i, j: (b_, kj(i, j), 0))
+                               lambda b_, i, j: (kv(b_), kj(i, j), 0))
     rowq = pl.BlockSpec((1, 1, block_q), lambda b_, i, j: (b_, 0, i))
     return q(d_qk), q(d_v), k(d_qk), k(d_v), rowq
 
@@ -219,11 +234,11 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     block_q, block_k = _block_sizes(tq, tk, block_q, block_k)
     bh = b * h
     q3 = q.reshape(bh, tq, d_qk)
-    k3 = k.reshape(bh, tk, d_qk)
-    v3 = v.reshape(bh, tk, d_v)
+    k3 = k.reshape(b * k.shape[1], tk, d_qk)
+    v3 = v.reshape(b * k.shape[1], tk, d_v)
 
-    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(d_qk, d_v, block_q, block_k,
-                                               causal)
+    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(
+        d_qk, d_v, block_q, block_k, causal, h // k.shape[1])
     out, lse = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
@@ -244,11 +259,27 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     return out.reshape(b, h, tq, d_v), lse.reshape(b, h, tq)
 
 
+def _transposed_tile(q, k, v, do, lse_row, dlt_row, i, j, *, scale, causal,
+                     block_q, block_k):
+    """(P^T, dS^T) of block pair (i, j), both (block_k, block_q) fp32:
+    the scores regenerated from q and k with the saved logsumexp row,
+    dP^T from v and dO, dS^T = P^T (dP^T - delta) scale."""
+    st = _dot(k, q, _NT) * scale              # (bk, bq) = S^T
+    if causal:
+        st = jnp.where(_causal_mask(i, j, block_q, block_k,
+                                    transposed=True), st, _NEG)
+    pt = jnp.exp(st - lse_row)                # exact probabilities, P^T
+    dpt = _dot(v, do, _NT)                    # (bk, bq) = dP^T
+    return pt, pt * (dpt - dlt_row) * scale
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale, causal, block_q, block_k):
+                    scale, causal, block_q, block_k, q_blocks=None):
     """dK/dV pass: grid (bh, k-blocks, q-blocks); the q dimension
     iterates innermost, accumulating this k-block's gradients in VMEM.
+    With grouped queries the inner axis walks the `q_blocks` blocks of
+    each of the group's heads in turn.
     Probabilities are REGENERATED from q/k + the saved logsumexp — no
     O(T²) residual ever exists (the whole point of a flash backward).
     The block is formed TRANSPOSED, (block_k, block_q): the saved rows
@@ -257,26 +288,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     import jax.experimental.pallas as pl
 
     j = pl.program_id(1)                      # k block (outer)
-    i = pl.program_id(2)                      # q block (inner)
-    nq = pl.num_programs(2)
+    step = pl.program_id(2)                   # q block (inner)
+    last = pl.num_programs(2) - 1
+    i = step if q_blocks is None else step % q_blocks   # within its head
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def _accumulate():
-        q = q_ref[0]                          # (bq, d_qk)
-        k = k_ref[0]                          # (bk, d_qk)
-        v = v_ref[0]                          # (bk, d_v)
-        do = do_ref[0]                        # (bq, d_v)
-        st = _dot(k, q, _NT) * scale          # (bk, bq) = S^T
-        if causal:
-            st = jnp.where(_causal_mask(i, j, block_q, block_k,
-                                        transposed=True), st, _NEG)
-        pt = jnp.exp(st - lse_ref[0])         # exact probabilities, P^T
-        dpt = _dot(v, do, _NT)                # (bk, bq) = dP^T
-        dst = pt * (dpt - dlt_ref[0]) * scale
+        q, do = q_ref[0], do_ref[0]           # (bq, d_qk), (bq, d_v)
+        pt, dst = _transposed_tile(
+            q, k_ref[0], v_ref[0], do, lse_ref[0], dlt_ref[0], i, j,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
         dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d_v)
         dk_acc[...] += _dot(dst.astype(q.dtype), q, _NN)      # (bk, d_qk)
 
@@ -287,7 +312,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     else:
         _accumulate()
 
-    @pl.when(i == nq - 1)
+    @pl.when(step == last)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -360,17 +385,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
     def _accumulate():
-        q = q_ref[0]                          # (bq, d_qk)
-        k = k_ref[0]                          # (bk, d_qk)
-        v = v_ref[0]                          # (bk, d_v)
-        do = do_ref[0]                        # (bq, d_v)
-        st = _dot(k, q, _NT) * scale          # (bk, bq) = S^T
-        if causal:
-            st = jnp.where(_causal_mask(i, j, block_q, block_k,
-                                        transposed=True), st, _NEG)
-        pt = jnp.exp(st - lse_ref[0])         # exact probabilities, P^T
-        dpt = _dot(v, do, _NT)                # (bk, bq) = dP^T
-        dst = (pt * (dpt - dlt_ref[0]) * scale).astype(q.dtype)
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        pt, dst = _transposed_tile(
+            q, k, v_ref[0], do, lse_ref[0], dlt_ref[0], i, j,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+        dst = dst.astype(q.dtype)
         dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d_v)
         dk_acc[...] += _dot(dst, q, _NN)                      # (bk, d_qk)
         dq_acc[rows, :] += _dot(dst, k, _TN)                  # (bq, d_qk)
@@ -390,14 +409,73 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
-def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal):
+def _bwd_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                      dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *,
+                      scale, causal, block_q, block_k):
+    """`_bwd_kernel` for grouped queries: grid (key/value heads, group,
+    k-blocks, q-blocks). One query head's dQ stays in `dq_acc` across
+    the two inner axes, as there; one key/value head's dK and dV stay,
+    whole and in fp32, in `dk_acc` and `dv_acc` across the three, each
+    k-block's rows zeroed at the group's first head and written out at
+    its last."""
+    import jax.experimental.pallas as pl
+
+    g = pl.program_id(1)                      # query head of the group
+    j = pl.program_id(2)                      # k block
+    i = pl.program_id(3)                      # q block (inner)
+    ng, nk, nq = (pl.num_programs(a) for a in (1, 2, 3))
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+    krows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    @pl.when((g == 0) & (i == 0))
+    def _init():
+        dk_acc[krows, :] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
+        dv_acc[krows, :] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
+
+    def _accumulate():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        pt, dst = _transposed_tile(
+            q, k, v_ref[0], do, lse_ref[0], dlt_ref[0], i, j,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+        dst = dst.astype(q.dtype)
+        dv_acc[krows, :] += _dot(pt.astype(do.dtype), do, _NN)
+        dk_acc[krows, :] += _dot(dst, q, _NN)
+        dq_acc[rows, :] += _dot(dst, k, _TN)
+
+    if causal:
+        pl.when((i + 1) * block_q - 1 >= j * block_k)(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when((g == ng - 1) & (i == nq - 1))
+    def _finalize():
+        dk_ref[0, krows, :] = dk_acc[krows, :].astype(dk_ref.dtype)
+        dv_ref[0, krows, :] = dv_acc[krows, :].astype(dv_ref.dtype)
+
+    @pl.when(j == nk - 1)
+    def _finalize_dq():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal, q_blocks=None):
     """Block specs of a (bh, k-blocks, q-blocks) grid. Under `causal`
     the q-blocks before the first computed one name that one: no copy
-    for a block nobody reads."""
+    for a block nobody reads. With grouped queries the q operands are
+    seen as (key/value heads, group * tq, d) and the inner axis runs
+    over the `q_blocks` blocks of each head of the group in turn."""
     import jax.experimental.pallas as pl
 
     def qi(j, i):
-        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+        if not causal:
+            return i
+        first = (j * block_k) // block_q
+        if q_blocks is None:
+            return jnp.maximum(i, first)
+        return i - i % q_blocks + jnp.maximum(i % q_blocks, first)
 
     q = lambda d: pl.BlockSpec((1, block_q, d),
                                lambda b_, j, i: (b_, qi(j, i), 0))
@@ -409,20 +487,29 @@ def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal):
 def _flash_dkv(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
                block_k, interpret):
     """The dK/dV pallas_call on (bh, t, d_qk | d_v) operands and
-    (bh, 1, tq) row statistics."""
+    (bh, 1, tq) row statistics; k3 and v3 may have a divisor of the
+    other operands' heads, and dK and dV have theirs."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, tq, d_qk = q3.shape
-    tk, d_v = k3.shape[1], v3.shape[2]
+    tq, d_qk = q3.shape[1:]
+    bh, tk, d_v = v3.shape
+    group = q3.shape[0] // bh
+    q_blocks = None
+    if group > 1:
+        # a key/value head's query heads end to end along the rows
+        q_blocks = tq // block_q
+        q3, do3 = (a.reshape(bh, group * tq, a.shape[2]) for a in (q3, do3))
+        lse3, delta = (a.reshape(bh, 1, group * tq) for a in (lse3, delta))
     q_qk, q_v, k_qk, k_v, rowq = _k_of_q_specs(d_qk, d_v, block_q, block_k,
-                                               causal)
+                                               causal, q_blocks)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k,
+                          q_blocks=q_blocks),
         out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
                    jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype)),
-        grid=(bh, tk // block_k, tq // block_q),
+        grid=(bh, tk // block_k, group * tq // block_q),
         in_specs=[q_qk, k_qk, k_v, q_v, rowq, rowq],
         out_specs=(k_qk, k_v),
         scratch_shapes=[pltpu.VMEM((block_k, d_qk), jnp.float32),
@@ -441,8 +528,8 @@ def _flash_dq(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
 
     bh, tq, d_qk = q3.shape
     d_v = v3.shape[2]
-    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(d_qk, d_v, block_q, block_k,
-                                               causal)
+    q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(
+        d_qk, d_v, block_q, block_k, causal, bh // k3.shape[0])
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
@@ -468,6 +555,9 @@ def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
 
     bh, tq, d_qk = q3.shape
     tk, d_v = k3.shape[1], v3.shape[2]
+    if bh != k3.shape[0]:
+        return _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal,
+                                block_q, block_k, interpret)
     q_qk, q_v, k_qk, k_v, rowq = _k_of_q_specs(d_qk, d_v, block_q, block_k,
                                                causal)
     # One head's dQ: the same block at every step of a head, so it is
@@ -494,9 +584,57 @@ def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     )(q3, k3, v3, do3, lse3, delta)
 
 
-def _bwd_path(tq, d_qk):
-    """Which backward a call takes, from its shapes alone."""
-    return "fused" if tq * d_qk * 4 <= FUSED_DQ_BYTES else "split"
+def _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
+                     block_k, interpret):
+    """The fused backward where k3 and v3 have a divisor of q3's heads:
+    (dk, dv, dq), dk and dv per key/value head."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tq, d_qk = q3.shape[1:]
+    bh, tk, d_v = v3.shape
+    group = q3.shape[0] // bh
+
+    def qi(j, i):
+        return jnp.maximum(i, (j * block_k) // block_q) if causal else i
+
+    q = lambda d: pl.BlockSpec(
+        (1, block_q, d), lambda b_, g, j, i: (b_ * group + g, qi(j, i), 0))
+    k = lambda d: pl.BlockSpec((1, block_k, d),
+                               lambda b_, g, j, i: (b_, j, 0))
+    rowq = pl.BlockSpec(
+        (1, 1, block_q), lambda b_, g, j, i: (b_ * group + g, 0, qi(j, i)))
+    # whole heads: written back when the head they belong to changes
+    whole = lambda t, d, head: pl.BlockSpec(
+        (1, t, d), lambda b_, g, j, i: (head(b_, g), 0, 0))
+    kv_head = lambda b_, g: b_
+    return pl.pallas_call(
+        functools.partial(_bwd_group_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype),
+                   jax.ShapeDtypeStruct(q3.shape, q3.dtype)),
+        grid=(bh, group, tk // block_k, tq // block_q),
+        in_specs=[q(d_qk), k(d_qk), k(d_v), q(d_v), rowq, rowq],
+        out_specs=(whole(tk, d_qk, kv_head), whole(tk, d_v, kv_head),
+                   whole(tq, d_qk, lambda b_, g: b_ * group + g)),
+        scratch_shapes=[pltpu.VMEM((tk, d_qk), jnp.float32),
+                        pltpu.VMEM((tk, d_v), jnp.float32),
+                        pltpu.VMEM((tq, d_qk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) + ("arbitrary",) * 3,
+            vmem_limit_bytes=FUSED_VMEM_LIMIT),
+        interpret=interpret,
+        name="mx_flash_bwd",
+    )(q3, k3, v3, do3, lse3, delta)
+
+
+def _bwd_path(tq, d_qk, tk=0, d_v=0, group=1):
+    """Which backward a call takes, from its shapes alone: the fused
+    one where its whole-head fp32 accumulators (dQ; with grouped
+    queries dK and dV too) are within `FUSED_DQ_BYTES`."""
+    held = tq * d_qk if group == 1 else tq * d_qk + tk * (d_qk + d_v)
+    return "fused" if held * 4 <= FUSED_DQ_BYTES else "split"
 
 
 def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
@@ -507,10 +645,10 @@ def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
     # delta_i = rowsum(dO_i * O_i) — O(T·d), fused by XLA.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, tq)
-    operands = tuple(a.reshape(bh, a.shape[2], a.shape[3])
+    operands = tuple(a.reshape(b * a.shape[1], a.shape[2], a.shape[3])
                      for a in (q, k, v, g)) + (lse.reshape(bh, 1, tq), delta)
     static = (scale, causal, block_q, block_k, interpret)
-    path = _bwd_path(tq, d_qk)
+    path = _bwd_path(tq, d_qk, k.shape[2], v.shape[3], h // k.shape[1])
     _flash_bwd_traced.labels(path=path).inc()
     if path == "fused":
         dk, dv, dq = _flash_bwd_fused(*operands, *static)
@@ -552,13 +690,22 @@ _flash_traced = _tm.REGISTRY.counter(
     "flash_attention calls traced into a program, by head widths",
     labels=("d_qk", "d_v"))
 
+_flash_group_traced = _tm.REGISTRY.counter(
+    "mx_flash_attention_group_traced_total",
+    "flash_attention calls traced into a program, by query heads a "
+    "key/value head",
+    labels=("group",))
+
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None):
     """Blockwise exact attention as one Pallas kernel.
 
-    q, k: (batch, heads, seq, d_qk); v: (batch, heads, seq, d_v); the
-    result has v's width. `scale` defaults to ``d_qk ** -0.5``.
+    q: (batch, heads, seq, d_qk); k: (batch, kv_heads, seq, d_qk); v:
+    (batch, kv_heads, seq, d_v), `kv_heads` a divisor of `heads` (query
+    head h reads key/value head ``h // (heads / kv_heads)``); the
+    result has q's heads and v's width. `scale` defaults to
+    ``d_qk ** -0.5``.
     `block_q`/`block_k` apply to the forward and the backward kernels;
     left at None each takes its default (`DEFAULT_BLOCK`). On
     non-TPU backends the kernel runs in interpret mode (functional, for
@@ -569,10 +716,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if q.shape[-1] != k.shape[-1]:
         raise ValueError("q and k differ in width: %d, %d"
                          % (q.shape[-1], k.shape[-1]))
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError("%d query heads on %d key and %d value heads"
+                         % (q.shape[1], k.shape[1], v.shape[1]))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     blocks = tuple(int(given or default) for given, default
                    in zip((block_q, block_k), DEFAULT_BLOCK))
     _flash_traced.labels(d_qk=str(q.shape[-1]), d_v=str(v.shape[-1])).inc()
+    _flash_group_traced.labels(group=str(q.shape[1] // k.shape[1])).inc()
     return _flash(q, k, v, float(scale), bool(causal), blocks,
                   bool(interpret))
 
@@ -580,8 +731,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=None,
                         block_k=None):
-    """Exact attention of q, k (batch, heads, seq, d_qk) and v (batch,
-    heads, seq, d_v), d_qk and d_v free of each other; result (batch,
+    """Exact attention of q (batch, heads, seq, d_qk), k (batch,
+    kv_heads, seq, d_qk) and v (batch, kv_heads, seq, d_v), d_qk and d_v
+    free of each other, kv_heads a divisor of heads; result (batch,
     heads, seq_q, d_v); `scale` defaults to ``d_qk ** -0.5``."""
     return flash_attention(q, k, v, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k)

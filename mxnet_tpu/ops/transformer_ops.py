@@ -1,8 +1,11 @@
 """The pieces of a decoder block that no other operator computes:
-RMSNorm, rotary position embedding on interleaved pairs, the SiLU-gated
-MLP and the `noaux_tc` router of the DeepSeek-V3 family. Plain XLA ops;
-the attention core is `pallas_attention.flash_attention` and the held
-experts' product `moe.moe_held_experts`.
+RMSNorm (plain, zero-centred, and gated per head), rotary position
+embedding on interleaved pairs or halves, the SiLU-gated MLP, the
+`noaux_tc` router of the DeepSeek-V3 family and the softmax top-k router
+of the `qwen3_next` family. Plain XLA ops; the attention core is
+`pallas_attention.flash_attention`, the linear-attention core
+`linear_attention.gated_delta_rule` and the held experts' product
+`moe.moe_held_experts`.
 
 Weights are laid out (out, in), as `FullyConnected`'s.
 """
@@ -16,16 +19,32 @@ import jax.numpy as jnp
 from ..telemetry import metrics as _tm
 from .registry import register
 
-__all__ = ["rms_norm", "rotary_embedding", "gated_mlp", "noaux_tc_router"]
+__all__ = ["rms_norm", "gated_rms_norm", "rotary_embedding", "gated_mlp",
+           "noaux_tc_router", "softmax_topk_router"]
 
 
 @register("_contrib_RMSNorm", aliases=("RMSNorm",))
-def rms_norm(data, gamma, eps=1e-6):
+def rms_norm(data, gamma, eps=1e-6, zero_centered=False):
     """``gamma * x / sqrt(mean(x^2) + eps)`` over the last axis; the
-    statistics and the scaling in fp32, the result in `data`'s type."""
+    statistics and the scaling in fp32, the result in `data`'s type.
+    `zero_centered`: the scale is ``1 + gamma`` (a weight that starts
+    at 0)."""
     x = data.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+    scale = gamma.astype(jnp.float32)
+    if zero_centered:
+        scale = 1.0 + scale
+    return (x * inv * scale).astype(data.dtype)
+
+
+@register("_contrib_gated_rms_norm", aliases=("gated_rms_norm",))
+def gated_rms_norm(data, gate, gamma, eps=1e-6):
+    """``gamma * x / sqrt(mean(x^2) + eps) * silu(gate)`` over the last
+    axis (a head's width), in fp32; the result in `data`'s type."""
+    x = data.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    out = x * inv * gamma.astype(jnp.float32)
+    return (out * jax.nn.silu(gate.astype(jnp.float32))).astype(data.dtype)
 
 
 _rotary_traced = _tm.REGISTRY.counter(
@@ -147,6 +166,34 @@ def noaux_tc_router(data, weight, bias_steps, top_k=6, gamma=1e-3,
                                + 1e-20)
         return (picked * jnp.float32(routed_scaling_factor),
                 ids.astype(jnp.int32), _counts(ids, score.shape[1]))
+
+
+_softmax_router_traced = _tm.REGISTRY.counter(
+    "mx_softmax_router_traced_total",
+    "softmax_topk_router calls traced into a program")
+
+
+@register("_contrib_softmax_topk_router", aliases=("softmax_topk_router",),
+          differentiable=True)
+def softmax_topk_router(data, weight, top_k=10, norm_topk_prob=True):
+    """Softmax over all experts, the `top_k` most probable, their
+    probabilities divided by their sum where `norm_topk_prob`. No bias,
+    no state.
+
+    data (tokens, hidden); weight (experts, hidden). The product and the
+    softmax are fp32 at the highest matmul precision. Returns what
+    `noaux_tc_router` returns: (weights (tokens, top_k) fp32, ids
+    (tokens, top_k) int32, counts (experts,) int32)."""
+    _softmax_router_traced.inc()
+    with jax.named_scope("moe_route"):
+        prob = jax.nn.softmax(jnp.einsum(
+            "th,eh->te", data.astype(jnp.float32),
+            weight.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        picked, ids = jax.lax.top_k(prob, top_k)
+        if norm_topk_prob:
+            picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+        return picked, ids.astype(jnp.int32), _counts(ids, prob.shape[1])
 
 
 @register("_contrib_noaux_tc_bias_update", differentiable=False)

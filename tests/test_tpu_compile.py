@@ -1,8 +1,10 @@
 """The chip's compiler, asked without the chip: the flash-attention
 kernels (forward, the fused backward, and the dK/dV and dQ pair of the
-long-sequence path) at real widths (equal, and latent attention's
-192/128 at the blocks the kernels default to), compiled for a described
-TPU v5e (2x2). What
+long-sequence path) at real widths (equal, latent attention's
+192/128, and 16 query heads on 2 key/value heads at 256, at the blocks
+the kernels default to), the delta rule's kernels at (1, 32, 4096, 128),
+and the whole training step of the `qwen3_next_80b_a3b` configuration,
+compiled for a described TPU v5e (2x2). What
 interpret mode cannot refuse — a block the lowering does not tile, more
 VMEM than a kernel may use — is refused here, at no chip time.
 
@@ -126,3 +128,134 @@ def test_rotary_compiles_for_v5e_without_gather_or_scatter(one_chip):
     assert "kind=kCustom" not in text
     must = 4 * 2 * 32 * 4096 * 64
     assert compiled.cost_analysis()["bytes accessed"] < 8 * must
+
+
+def test_grouped_flash_kernels_compile_for_v5e_at_width_256(one_chip):
+    """16 query heads on 2 key/value heads, 256 wide, 4,096 tokens,
+    causal, at the default blocks: the forward, the fused backward of a
+    group and the dK/dV and dQ pair; dK and dV per key/value head."""
+    b, h, kv, t, d = 1, 16, 2, 4096, 256
+    static = (d ** -0.5, True) + pa.DEFAULT_BLOCK + (False,)
+    assert pa._bwd_path(t, d, t, d, h // kv) == "fused"
+    spec = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                           sharding=one_chip)
+    row = jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32,
+                               sharding=one_chip)
+    q3, k3 = spec(b * h, t, d), spec(b * kv, t, d)
+    calls = {"fwd": (lambda q, k, v: pa._flash_forward(q, k, v, *static),
+                     [spec(b, h, t, d), spec(b, kv, t, d),
+                      spec(b, kv, t, d)])}
+    for name, fn in _BACKWARD.items():
+        calls[name] = (lambda *a, fn=fn: fn(*a, *static),
+                       [q3, k3, k3, q3, row, row])
+    for name, (fn, args) in calls.items():
+        lowered = jax.jit(fn).lower(*args)
+        assert "tpu_custom_call" in lowered.as_text(), name
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < b * h * t * t * 4 // 4, name
+        if name in ("dkv", "fused"):
+            assert [o.shape for o in jax.eval_shape(fn, *args)][:2] \
+                == [(b * kv, t, d)] * 2
+
+
+def test_delta_rule_kernels_compile_for_v5e(one_chip):
+    """`mx_gdn_fwd` and `mx_gdn_bwd` at (1, 32, 4096, 128) bf16, chunk
+    64, value and all five gradients: two kernels, and no residual that
+    grows with a state a token (one fp32 state a chunk is 134 MB)."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    b, h, t, d = 1, 32, 4096, 128
+    x = jax.ShapeDtypeStruct((b, h, t, d), jnp.bfloat16, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((b, h, t), jnp.float32, sharding=one_chip)
+
+    def value_and_gradients(q, k, v, g_, beta, cot):
+        out, pull = jax.vjp(lambda *a: la.gated_delta_rule(
+            *a, chunk=64, interpret=False), q, k, v, g_, beta)
+        return out, pull(cot)
+
+    lowered = jax.jit(value_and_gradients).lower(x, x, x, g, g, x)
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    compiled = lowered.compile()
+    a_state_a_token = b * h * t * d * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < a_state_a_token // 8
+
+
+def test_qwen3_next_step_fits_the_chip(one_chip):
+    """The whole `TrainStep` program of the `qwen3_next_80b_a3b`
+    configuration (one sequence of 4,096 tokens, bf16 with fp32 masters,
+    Adam), from shapes alone: arguments and temporaries stay under the
+    16 GB of `peaks.json`, with room for the imperative gradient buffers
+    that the process also holds (4 bytes a parameter)."""
+    import json
+
+    import numpy as np
+
+    from mxnet_tpu import gluon, initializer
+    from mxnet_tpu.gluon.model_zoo import qwen3_next as zoo
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.parallel import TrainStep, make_mesh
+    from mxnet_tpu.parallel.mesh import data_sharding
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "qwen3_next_80b_a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench", "peaks.json")) as f:
+        hbm = json.load(f)["TPU v5 lite"]["hbm_bytes"]
+    held = cfg["num_experts"]
+    # the parameters exist as shapes alone: nothing of their size is drawn
+    net = zoo.qwen3_next(dict(
+        cfg, num_experts=cfg["published"]["num_experts"],
+        held_experts=list(range(held))))
+    shapes = {name: jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype))
+              for name, p in net.collect_params().items()}
+    count = sum(int(np.prod(p.shape))
+                for p in net.collect_params().values()
+                if p.grad_req != "null")
+    assert 420e6 < count < 630e6
+    device = one_chip._device
+    step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                     optimizer=cfg["optimizer"]["name"],
+                     optimizer_params=dict(cfg["optimizer"]["params"]),
+                     mesh=make_mesh({"dp": -1}, devices=[device]),
+                     dtype="bfloat16")
+    spec = jax.ShapeDtypeStruct
+
+    class _Shaped:
+        """What `_materialize` reads of a parameter's data."""
+
+        def __init__(self, shape):
+            self._data = shape
+
+    for name, p in net.collect_params().items():
+        p._data = {None: _Shaped(shapes[name])}
+    step._place = lambda v, sharding: spec(v.shape, v.dtype,
+                                           sharding=sharding)
+    step._opt_init = lambda v: (spec(v.shape, jnp.float32),) * 2
+    step._materialize(None)
+    # off the TPU the kernels would take interpret mode: compile them
+    real = (pa.flash_attention, la.gated_delta_rule)
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        step._build()
+        tokens = spec((1, cfg["bptt"]), jnp.int32,
+                      sharding=data_sharding(step.mesh))
+        lowered = step._jitted.lower(
+            step._param_vals, step._opt_state, step._aux_vals, tokens,
+            tokens, spec((), jnp.float32), spec((), jnp.float32),
+            spec((2,), jnp.uint32))
+    finally:
+        jax.default_backend = backend
+    assert real == (pa.flash_attention, la.gated_delta_rule)
+    text = lowered.as_text()
+    # three delta-rule layers and one attention layer, forward and back
+    assert text.count("mx_gdn_fwd") >= 3 and text.count("mx_gdn_bwd") >= 3
+    assert "mx_flash_bwd" in text
+    memory = lowered.compile().memory_analysis()
+    program = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    assert memory.argument_size_in_bytes >= 12 * count
+    assert program + 4 * count < hbm
