@@ -79,13 +79,8 @@ def _recurrence(model, q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1)[None]
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("seq,chunk", [(64, 64), (128, 64), (16, 16),
-                                       (48, 16)])
-def test_gated_delta_rule_equals_the_recurrence(model, seq, chunk, dtype):
-    """Values and all five gradients, at one chunk and at several."""
-    args, cot = _rule_inputs(seq + chunk, 1, 2, seq, 16, 24, dtype)
-
+def _rule_and_recurrence(model, args, cot, chunk):
+    """(o, dq, dk, dv, dg, dbeta) of the operator and of the recurrence."""
     def both(fn):
         out, pull = jax.vjp(fn, *args)
         return (out,) + pull(cot.astype(out.dtype))
@@ -94,11 +89,42 @@ def test_gated_delta_rule_equals_the_recurrence(model, seq, chunk, dtype):
         lambda *a: la.gated_delta_rule(*a, chunk=chunk)))()
     want = jax.jit(lambda: both(
         lambda *a: _recurrence(model, *a).astype(args[2].dtype)))()
-    tol = 1e-4 if dtype == "float32" else _BF16_RULE
+    return got, want
+
+
+def _assert_rule_close(got, want, tol):
     for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
         a, b = (np.asarray(x, np.float32) for x in (a, b))
         assert a.shape == b.shape
         assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1e-3), name
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seq,chunk,dk,dv", [
+    (64, 64, 16, 24), (128, 64, 16, 24), (16, 16, 16, 24), (48, 16, 16, 24),
+    # the widths of `qwen3_next_80b_a3b`: two tiles of two chunks
+    (256, 64, 128, 128)])
+def test_gated_delta_rule_equals_the_recurrence(model, seq, chunk, dk, dv,
+                                                dtype):
+    """Values and all five gradients, at one chunk and at several, one
+    key head under two value heads."""
+    args, cot = _rule_inputs(seq + chunk, 1, 2, seq, dk, dv, dtype)
+    got, want = _rule_and_recurrence(model, args, cot, chunk)
+    _assert_rule_close(got, want, 1e-4 if dtype == "float32" else _BF16_RULE)
+
+
+def test_gated_delta_rule_walks_a_grid_of_head_and_chunk_blocks(model):
+    """Four key heads under eight value heads and sixteen chunks: the
+    preparation's grid is four key heads by two blocks of four tiles, the
+    scan's two blocks of four value heads by two blocks of eight chunks,
+    so every index map is walked past its first block, q and k by key
+    head. Each head and chunk has its own data: a block read from the
+    wrong place shows."""
+    args, cot = _rule_inputs(11, 4, 8, 1024, 16, 24, "float32")
+    heads, chunks = la._grid(8, 1024 // 64)
+    assert (8 // heads, (1024 // 64) // chunks) == (2, 2)
+    got, want = _rule_and_recurrence(model, args, cot, 64)
+    _assert_rule_close(got, want, 1e-4)
 
 
 def test_gated_delta_rule_with_strong_decay_and_similar_keys_stays_finite(
