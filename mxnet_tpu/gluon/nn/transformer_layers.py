@@ -1,10 +1,11 @@
-"""Decoder-block layers: RMSNorm, latent attention (MLA), gated
-grouped-query attention, Gated DeltaNet linear attention, the SiLU-gated
-MLP and a sparse mixture-of-experts layer that holds some of its experts.
+"""Decoder-block layers: RMSNorm, latent attention (MLA), grouped-query
+attention (plain or over a sliding window, and with an output gate),
+Gated DeltaNet linear attention, the SiLU-gated MLP and a sparse
+mixture-of-experts layer that holds some of its experts.
 
 No reference counterpart (MXNet 1.3 predates them); parameter names and
-the equations follow the published `deepseek_v3` and `qwen3_next`
-modeling code. Inputs are (batch, seq, hidden).
+the equations follow the published `deepseek_v3`, `qwen3_next` and
+`qwen3_moe` modeling code. Inputs are (batch, seq, hidden).
 """
 from __future__ import annotations
 
@@ -18,8 +19,8 @@ import jax
 from ..block import HybridBlock
 from ...telemetry import metrics as _tm
 
-__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "GatedAttention",
-           "GatedDeltaNet", "SparseMoE"]
+__all__ = ["RMSNorm", "GatedMLP", "MLAttention", "GroupedQueryAttention",
+           "GatedAttention", "GatedDeltaNet", "SparseMoE"]
 
 
 class RMSNorm(HybridBlock):
@@ -153,68 +154,106 @@ class MLAttention(HybridBlock):
             return proj(out, o_proj_weight)
 
 
-class GatedAttention(HybridBlock):
-    """Causal grouped-query attention with an output gate, as the
-    `qwen3_next` family's full-attention layers have it.
+class GroupedQueryAttention(HybridBlock):
+    """Causal grouped-query attention, full or over a sliding window, as
+    the Qwen3-MoE and `mellum` families have it.
 
-    ``[q | gate] = x Wq`` per head; q and k pass a zero-centred RMSNorm
-    over the head's width, then rotary embedding on the first
-    `partial_rotary_factor` of it, halves paired; `num_heads` query
+    q, k and v are projected per head; q and k pass an RMSNorm over the
+    head's width, then rotary embedding on the first
+    `partial_rotary_factor` of it, halves paired, with the frequencies
+    `rope_scaling` gives (None: plain; a dict of `rotary_embedding`'s
+    YaRN keywords: `scaling_factor`, `original_max_position`,
+    `beta_fast`, `beta_slow`, `attention_factor`); `num_heads` query
     heads read `num_kv_heads` key/value heads in groups
-    (`flash_attention`); the result times ``sigmoid(gate)`` goes through
-    the output projection. No bias anywhere."""
+    (`flash_attention`), each query the `window` keys up to its own, or
+    every earlier key; then the output projection. No bias anywhere.
+    The scope in the program's metadata is `sliding_attention` under a
+    window, else `full_attention`."""
+
+    _gated = False                # an output gate beside each query head
+    _zero_centered = False        # the q and k norms' scale is 1 + weight
+    _scope = None                 # named by the window unless a subclass does
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  rope_theta=10000.0, partial_rotary_factor=1.0,
-                 epsilon=1e-6, weight_initializer=None, **kwargs):
+                 rope_scaling=None, window=None, epsilon=1e-6,
+                 weight_initializer=None, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._kv_heads, self._width = \
             num_heads, num_kv_heads, head_dim
-        self._theta = float(rope_theta)
         self._rotary = int(head_dim * partial_rotary_factor)
+        self._rope_args = dict(rope_scaling or {}, theta=float(rope_theta),
+                               interleaved=False)
+        self._flash_args = {} if window is None else {"window": int(window)}
+        if self._scope is None:
+            self._scope = "sliding_attention" if self._flash_args \
+                else "full_attention"
 
         def weight(name, shape):
             setattr(self, name + "_weight", self.params.get(
                 name + "_weight", shape=shape, init=weight_initializer))
 
-        weight("q_proj", (num_heads * head_dim * 2, hidden_size))
+        weight("q_proj", (num_heads * head_dim * (2 if self._gated else 1),
+                          hidden_size))
         weight("k_proj", (num_kv_heads * head_dim, hidden_size))
         weight("v_proj", (num_kv_heads * head_dim, hidden_size))
         weight("o_proj", (hidden_size, num_heads * head_dim))
-        self.q_norm = RMSNorm(head_dim, epsilon, zero_centered=True,
+        self.q_norm = RMSNorm(head_dim, epsilon,
+                              zero_centered=self._zero_centered,
                               prefix=self.prefix + "q_norm_")
-        self.k_norm = RMSNorm(head_dim, epsilon, zero_centered=True,
+        self.k_norm = RMSNorm(head_dim, epsilon,
+                              zero_centered=self._zero_centered,
                               prefix=self.prefix + "k_norm_")
 
     def _rope(self, F, x):
         """Rotary embedding on the first `_rotary` of (B, heads, T, d)."""
         if self._rotary == self._width:
-            return F.contrib.rotary_embedding(x, theta=self._theta,
-                                              interleaved=False)
+            return F.contrib.rotary_embedding(x, **self._rope_args)
         turned = F.contrib.rotary_embedding(
             F.slice_axis(x, axis=-1, begin=0, end=self._rotary),
-            theta=self._theta, interleaved=False)
+            **self._rope_args)
         return F.concat(turned, F.slice_axis(
             x, axis=-1, begin=self._rotary, end=None), dim=-1)
 
     def hybrid_forward(self, F, x, q_proj_weight, k_proj_weight,
                        v_proj_weight, o_proj_weight):
         heads, kv, d = self._heads, self._kv_heads, self._width
-        with jax.named_scope("gated_attention"):
-            qg = F.reshape(_proj(F, x, q_proj_weight),
-                           shape=(0, 0, heads, 2 * d))
-            gate = F.reshape(F.slice_axis(qg, axis=-1, begin=d, end=None),
-                             shape=(0, 0, -1))
-            q = self.q_norm(F.slice_axis(qg, axis=-1, begin=0, end=d))
+        with jax.named_scope(self._scope):
+            q = _proj(F, x, q_proj_weight)
+            if self._gated:
+                # [q | gate] per head
+                qg = F.reshape(q, shape=(0, 0, heads, 2 * d))
+                gate = F.reshape(
+                    F.slice_axis(qg, axis=-1, begin=d, end=None),
+                    shape=(0, 0, -1))
+                q = F.slice_axis(qg, axis=-1, begin=0, end=d)
+            else:
+                q = F.reshape(q, shape=(0, 0, heads, d))
+            q = self.q_norm(q)
             k = self.k_norm(F.reshape(_proj(F, x, k_proj_weight),
                                       shape=(0, 0, kv, d)))
             q = self._rope(F, F.transpose(q, axes=(0, 2, 1, 3)))
             k = self._rope(F, F.transpose(k, axes=(0, 2, 1, 3)))
             v = _heads_first(F, _proj(F, x, v_proj_weight), kv, d)
-            out = F.contrib.flash_attention(q, k, v, causal=True)
+            out = F.contrib.flash_attention(q, k, v, causal=True,
+                                            **self._flash_args)
             out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                             shape=(0, 0, -1))
-            return _proj(F, out * F.sigmoid(gate), o_proj_weight)
+            if self._gated:
+                out = out * F.sigmoid(gate)
+            return _proj(F, out, o_proj_weight)
+
+
+class GatedAttention(GroupedQueryAttention):
+    """Causal grouped-query attention with an output gate, as the
+    `qwen3_next` family's full-attention layers have it:
+    ``[q | gate] = x Wq`` per head, the q and k norms zero-centred, and
+    the result times ``sigmoid(gate)`` goes through the output
+    projection; the rest is `GroupedQueryAttention`'s."""
+
+    _gated = True
+    _zero_centered = True
+    _scope = "gated_attention"
 
 
 class GatedDeltaNet(HybridBlock):

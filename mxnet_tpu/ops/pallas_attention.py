@@ -51,6 +51,18 @@ VMEM across it, so dK and dV come out per key/value head and no
 per-query-head copy of them exists. At a group of one every kernel is
 what it was.
 
+Sliding window: with `window` = W a query at position i sees the keys
+``i - W < j <= i`` (W keys, its own among them). The forward and the
+fused backward (of one head or of a group) then run as
+`mx_flash_swa_fwd` and `mx_flash_swa_bwd`: the inner grid axis has only
+as many steps as one outer block's window can touch (`_inner_blocks`),
+the index maps start it at the outer block's first allowed block, and a
+block with no allowed pair is neither copied nor computed; every
+computed block takes the two-edged mask (a variant that left it off the
+blocks no edge crosses read the same times on the chip: PERF.md, PR 37).
+The dK/dV and dQ pair takes no window. At `window=None` every kernel
+is what it was.
+
 Registered as `_contrib_flash_attention` for `nd`/`sym` access.
 """
 from __future__ import annotations
@@ -89,14 +101,57 @@ def _row_to_col(row):
     return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
 
 
-def _causal_mask(i, j, block_q, block_k, transposed=False):
-    """q_pos >= k_pos for block (i, j); (block_k, block_q) if transposed."""
+def _causal_mask(i, j, block_q, block_k, transposed=False, window=None):
+    """q_pos >= k_pos for block (i, j), and q_pos - k_pos < window under
+    one; (block_k, block_q) if transposed."""
     shape = (block_k, block_q) if transposed else (block_q, block_k)
     q_pos = i * block_q + jax.lax.broadcasted_iota(
         jnp.int32, shape, 1 if transposed else 0)
     k_pos = j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, shape, 0 if transposed else 1)
-    return q_pos >= k_pos
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
+
+
+def _clamp(x, lo, hi):
+    """min(max(x, lo), hi) of a Python int (a grid's extent) or of a
+    traced index (inside a kernel or an index map)."""
+    if isinstance(x, int):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+def _inner_blocks(o, block_o, block_i, n_inner, back, ahead):
+    """(first, last) of the `n_inner` inner blocks that hold a position
+    from `back` before outer block `o`'s first to `ahead` after its last.
+    Keys of a q-block under a window W: back W - 1, ahead 0; queries of
+    a k-block: back 0, ahead W - 1."""
+    first = _clamp(o * block_o - back, 0, n_inner * block_i - 1) // block_i
+    last = _clamp(((o + 1) * block_o - 1 + ahead) // block_i, 0, n_inner - 1)
+    return first, last
+
+
+def _windowed_block(o, step, *geometry):
+    """The inner block that step `step` of outer block `o` names: its
+    first allowed one and on, the last again past it (no copy for a
+    block nobody reads)."""
+    first, last = _inner_blocks(o, *geometry)
+    return jnp.minimum(first + step, last)
+
+
+def _window_geometry(n_outer, n_inner, block_o, block_i, window, keys):
+    """(extent of a grid's inner axis; the keywords of its kernel and
+    block specs). Under a window the most inner blocks any outer block
+    needs, the inner blocks being a q-block's keys (`keys`) or a
+    k-block's queries; at no window the whole axis and no keyword."""
+    if window is None:
+        return n_inner, {}
+    reach = (window - 1, 0) if keys else (0, window - 1)
+    spans = (_inner_blocks(o, block_o, block_i, n_inner, *reach)
+             for o in range(n_outer))
+    return (max(last - first + 1 for first, last in spans),
+            dict(window=window, inner_blocks=n_inner))
 
 
 def _dot(a, b, contract):
@@ -111,7 +166,7 @@ _TN = ((0,), (0,))    # a.T @ b
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-            scale, causal, block_q, block_k):
+            scale, causal, block_q, block_k, window=None, inner_blocks=None):
     import jax.experimental.pallas as pl
 
     j = pl.program_id(2)
@@ -124,6 +179,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     i = pl.program_id(1)
+    step = j
+    if window is not None:
+        # the inner axis counts from this q-block's first allowed k-block
+        first, last_allowed = _inner_blocks(i, block_q, block_k,
+                                            inner_blocks, window - 1, 0)
+        j = first + step
 
     def _accumulate():
         q = q_ref[0]                            # (bq, d_qk)
@@ -131,7 +192,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         v = v_ref[0]                            # (bk, d_v)
         s = _dot(q, k, _NT) * scale             # (bq, bk) fp32
         if causal:
-            mask = _causal_mask(i, j, block_q, block_k)
+            mask = _causal_mask(i, j, block_q, block_k, window=window)
             s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[...]                     # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -143,7 +204,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + _dot(p.astype(v.dtype), v, _NN)
 
-    if causal:
+    if window is not None:
+        pl.when(j <= last_allowed)(_accumulate)
+    elif causal:
         # k-blocks wholly above the diagonal (first key after this
         # q-block's last query) contribute nothing: skip their matmuls
         # (~2x causal throughput, standard FlashAttention pruning).
@@ -151,7 +214,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
     else:
         _accumulate()
 
-    @pl.when(j == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -165,6 +228,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 # forward and the dK/dV and dQ pair (PERF.md, PR 28), the fused backward
 # (PERF.md, PR 29), each fastest here.
 DEFAULT_BLOCK = (1024, 1024)
+
+# Under a window a block is computed whole where the window's edge
+# crosses it, so smaller blocks waste less and cost more steps; from a
+# sweep at (1, 32 on 4, 8192, 128), window 1024, on a TPU v5e: the
+# fused backward fastest here (3.28 ms; 3.72 at 1024x1024), the forward
+# second (2.93; 2.66 at 1024x1024) (PERF.md, PR 37).
+DEFAULT_WINDOW_BLOCK = (512, 512)
 
 # The fused backward keeps one head's dQ in VMEM as fp32, (tq, d_qk),
 # from the head's first grid step to its last: 3 MiB at 4096 x 192. A
@@ -205,14 +275,19 @@ def _last_k(i, block_q, block_k):
     return ((i + 1) * block_q - 1) // block_k
 
 
-def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal, group=1):
+def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal, group=1,
+                  window=None, inner_blocks=None):
     """Block specs of a (bh, q-blocks, k-blocks) grid. Under `causal`
     a skipped step names the block of the last computed one, so that
     no copy is started for a block nobody reads. Query head `b_` reads
-    key/value head ``b_ // group``."""
+    key/value head ``b_ // group``. Under a window the inner axis counts
+    from the q-block's first allowed k-block (`_inner_blocks`)."""
     import jax.experimental.pallas as pl
 
     def kj(i, j):
+        if window is not None:
+            return _windowed_block(i, j, block_q, block_k, inner_blocks,
+                                   window - 1, 0)
         return jnp.minimum(j, _last_k(i, block_q, block_k)) if causal else j
 
     def kv(b_):
@@ -225,7 +300,8 @@ def _q_of_k_specs(d_qk, d_v, block_q, block_k, causal, group=1):
     return q(d_qk), q(d_v), k(d_qk), k(d_v), rowq
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret,
+                   window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -237,14 +313,16 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
     k3 = k.reshape(b * k.shape[1], tk, d_qk)
     v3 = v.reshape(b * k.shape[1], tk, d_v)
 
+    k_steps, geometry = _window_geometry(
+        tq // block_q, tk // block_k, block_q, block_k, window, keys=True)
     q_qk, q_v, k_qk, k_v, rowq = _q_of_k_specs(
-        d_qk, d_v, block_q, block_k, causal, h // k.shape[1])
+        d_qk, d_v, block_q, block_k, causal, h // k.shape[1], **geometry)
     out, lse = pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, **geometry),
         out_shape=(jax.ShapeDtypeStruct((bh, tq, d_v), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32)),
-        grid=(bh, tq // block_q, tk // block_k),
+        grid=(bh, tq // block_q, k_steps),
         in_specs=[q_qk, k_qk, k_v],
         out_specs=(q_v, rowq),
         scratch_shapes=[
@@ -254,20 +332,21 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
-        name="mx_flash_fwd",
+        name="mx_flash_fwd" if window is None else "mx_flash_swa_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, tq, d_v), lse.reshape(b, h, tq)
 
 
 def _transposed_tile(q, k, v, do, lse_row, dlt_row, i, j, *, scale, causal,
-                     block_q, block_k):
+                     block_q, block_k, window=None):
     """(P^T, dS^T) of block pair (i, j), both (block_k, block_q) fp32:
     the scores regenerated from q and k with the saved logsumexp row,
     dP^T from v and dO, dS^T = P^T (dP^T - delta) scale."""
     st = _dot(k, q, _NT) * scale              # (bk, bq) = S^T
     if causal:
         st = jnp.where(_causal_mask(i, j, block_q, block_k,
-                                    transposed=True), st, _NEG)
+                                    transposed=True, window=window),
+                       st, _NEG)
     pt = jnp.exp(st - lse_row)                # exact probabilities, P^T
     dpt = _dot(v, do, _NT)                    # (bk, bq) = dP^T
     return pt, pt * (dpt - dlt_row) * scale
@@ -358,29 +437,46 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _q_blocks_of_k(j, step, block_q, block_k, window, q_blocks, k_blocks):
+    """A windowed backward's step `step` at k-block `j`: (i, the q-block
+    it names; whether that block is one of `j`'s; the first and the last
+    k-block of q-block i, where its dQ rows start and end)."""
+    first, last = _inner_blocks(j, block_k, block_q, q_blocks, 0, window - 1)
+    i = jnp.minimum(first + step, q_blocks - 1)
+    return (i, first + step <= last) + _inner_blocks(
+        i, block_q, block_k, k_blocks, window - 1, 0)
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                 dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *,
-                scale, causal, block_q, block_k):
+                scale, causal, block_q, block_k, window=None,
+                inner_blocks=None):
     """The whole backward in one pass: the dK/dV kernel's grid and
     body, and dQ out of the same dS^T tile. `dq_acc` holds the whole
     head's dQ in fp32 across both inner grid axes; a q-block's rows
     are zeroed at the first k-block, added to at every computed pair
     (k-blocks ascending, the dQ kernel's order) and written out at the
-    last."""
+    last. Under a window the inner axis counts from the k-block's first
+    allowed q-block, and a q-block's first and last k-blocks are its
+    window's."""
     import jax.experimental.pallas as pl
 
     j = pl.program_id(1)                      # k block (outer)
     i = pl.program_id(2)                      # q block (inner)
     nk = pl.num_programs(1)
     nq = pl.num_programs(2)
+    step = i
+    if window is not None:
+        i, computed, first_k, last_k = _q_blocks_of_k(
+            j, step, block_q, block_k, window, inner_blocks, nk)
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == 0)
+    @pl.when(j == 0 if window is None else computed & (j == first_k))
     def _init_dq():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
@@ -388,30 +484,34 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         pt, dst = _transposed_tile(
             q, k, v_ref[0], do, lse_ref[0], dlt_ref[0], i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            window=window)
         dst = dst.astype(q.dtype)
         dv_acc[...] += _dot(pt.astype(do.dtype), do, _NN)     # (bk, d_v)
         dk_acc[...] += _dot(dst, q, _NN)                      # (bk, d_qk)
         dq_acc[rows, :] += _dot(dst, k, _TN)                  # (bq, d_qk)
 
-    if causal:
+    if window is not None:
+        pl.when(computed)(_accumulate)
+    elif causal:
         pl.when((i + 1) * block_q - 1 >= j * block_k)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(i == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == nk - 1 if window is None else computed & (j == last_k))
     def _finalize_dq():
         dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
 def _bwd_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                       dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *,
-                      scale, causal, block_q, block_k):
+                      scale, causal, block_q, block_k, window=None,
+                      inner_blocks=None):
     """`_bwd_kernel` for grouped queries: grid (key/value heads, group,
     k-blocks, q-blocks). One query head's dQ stays in `dq_acc` across
     the two inner axes, as there; one key/value head's dK and dV stay,
@@ -424,15 +524,19 @@ def _bwd_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     j = pl.program_id(2)                      # k block
     i = pl.program_id(3)                      # q block (inner)
     ng, nk, nq = (pl.num_programs(a) for a in (1, 2, 3))
+    step = i
+    if window is not None:
+        i, computed, first_k, last_k = _q_blocks_of_k(
+            j, step, block_q, block_k, window, inner_blocks, nk)
     rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
     krows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
 
-    @pl.when((g == 0) & (i == 0))
+    @pl.when((g == 0) & (step == 0))
     def _init():
         dk_acc[krows, :] = jnp.zeros((block_k, dk_acc.shape[1]), jnp.float32)
         dv_acc[krows, :] = jnp.zeros((block_k, dv_acc.shape[1]), jnp.float32)
 
-    @pl.when(j == 0)
+    @pl.when(j == 0 if window is None else computed & (j == first_k))
     def _init_dq():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
@@ -440,36 +544,45 @@ def _bwd_group_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         pt, dst = _transposed_tile(
             q, k, v_ref[0], do, lse_ref[0], dlt_ref[0], i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            window=window)
         dst = dst.astype(q.dtype)
         dv_acc[krows, :] += _dot(pt.astype(do.dtype), do, _NN)
         dk_acc[krows, :] += _dot(dst, q, _NN)
         dq_acc[rows, :] += _dot(dst, k, _TN)
 
-    if causal:
+    if window is not None:
+        pl.when(computed)(_accumulate)
+    elif causal:
         pl.when((i + 1) * block_q - 1 >= j * block_k)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when((g == ng - 1) & (i == nq - 1))
+    @pl.when((g == ng - 1) & (step == nq - 1))
     def _finalize():
         dk_ref[0, krows, :] = dk_acc[krows, :].astype(dk_ref.dtype)
         dv_ref[0, krows, :] = dv_acc[krows, :].astype(dv_ref.dtype)
 
-    @pl.when(j == nk - 1)
+    @pl.when(j == nk - 1 if window is None else computed & (j == last_k))
     def _finalize_dq():
         dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
 
-def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal, q_blocks=None):
+def _k_of_q_specs(d_qk, d_v, block_q, block_k, causal, q_blocks=None,
+                  window=None, inner_blocks=None):
     """Block specs of a (bh, k-blocks, q-blocks) grid. Under `causal`
     the q-blocks before the first computed one name that one: no copy
     for a block nobody reads. With grouped queries the q operands are
     seen as (key/value heads, group * tq, d) and the inner axis runs
-    over the `q_blocks` blocks of each head of the group in turn."""
+    over the `q_blocks` blocks of each head of the group in turn. Under
+    a window (one head a grid row, `inner_blocks` its q-blocks) the
+    inner axis counts from the k-block's first allowed q-block."""
     import jax.experimental.pallas as pl
 
     def qi(j, i):
+        if window is not None:
+            return _windowed_block(j, i, block_k, block_q, inner_blocks, 0,
+                                   window - 1)
         if not causal:
             return i
         first = (j * block_k) // block_q
@@ -547,7 +660,7 @@ def _flash_dq(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
 
 
 def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
-                     block_k, interpret):
+                     block_k, interpret, window=None):
     """dK, dV and dQ from one pallas_call, same operands as
     :func:`_flash_dkv`; returns (dk, dv, dq)."""
     import jax.experimental.pallas as pl
@@ -557,19 +670,21 @@ def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     tk, d_v = k3.shape[1], v3.shape[2]
     if bh != k3.shape[0]:
         return _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal,
-                                block_q, block_k, interpret)
+                                block_q, block_k, interpret, window)
+    q_steps, geometry = _window_geometry(
+        tk // block_k, tq // block_q, block_k, block_q, window, keys=False)
     q_qk, q_v, k_qk, k_v, rowq = _k_of_q_specs(d_qk, d_v, block_q, block_k,
-                                               causal)
+                                               causal, **geometry)
     # One head's dQ: the same block at every step of a head, so it is
     # written back once, when the head changes.
     head = pl.BlockSpec((1, tq, d_qk), lambda b_, j, i: (b_, 0, 0))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, **geometry),
         out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
                    jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype),
                    jax.ShapeDtypeStruct((bh, tq, d_qk), q3.dtype)),
-        grid=(bh, tk // block_k, tq // block_q),
+        grid=(bh, tk // block_k, q_steps),
         in_specs=[q_qk, k_qk, k_v, q_v, rowq, rowq],
         out_specs=(k_qk, k_v, head),
         scratch_shapes=[pltpu.VMEM((block_k, d_qk), jnp.float32),
@@ -580,12 +695,12 @@ def _flash_bwd_fused(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
         compiler_params=_compiler_params(
             "arbitrary", vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
-        name="mx_flash_bwd",
+        name="mx_flash_bwd" if window is None else "mx_flash_swa_bwd",
     )(q3, k3, v3, do3, lse3, delta)
 
 
 def _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
-                     block_k, interpret):
+                     block_k, interpret, window=None):
     """The fused backward where k3 and v3 have a divisor of q3's heads:
     (dk, dv, dq), dk and dv per key/value head."""
     import jax.experimental.pallas as pl
@@ -594,8 +709,13 @@ def _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     tq, d_qk = q3.shape[1:]
     bh, tk, d_v = v3.shape
     group = q3.shape[0] // bh
+    q_steps, geometry = _window_geometry(
+        tk // block_k, tq // block_q, block_k, block_q, window, keys=False)
 
     def qi(j, i):
+        if window is not None:
+            return _windowed_block(j, i, block_k, block_q, tq // block_q, 0,
+                                   window - 1)
         return jnp.maximum(i, (j * block_k) // block_q) if causal else i
 
     q = lambda d: pl.BlockSpec(
@@ -610,11 +730,11 @@ def _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
     kv_head = lambda b_, g: b_
     return pl.pallas_call(
         functools.partial(_bwd_group_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, **geometry),
         out_shape=(jax.ShapeDtypeStruct((bh, tk, d_qk), k3.dtype),
                    jax.ShapeDtypeStruct((bh, tk, d_v), v3.dtype),
                    jax.ShapeDtypeStruct(q3.shape, q3.dtype)),
-        grid=(bh, group, tk // block_k, tq // block_q),
+        grid=(bh, group, tk // block_k, q_steps),
         in_specs=[q(d_qk), k(d_qk), k(d_v), q(d_v), rowq, rowq],
         out_specs=(whole(tk, d_qk, kv_head), whole(tk, d_v, kv_head),
                    whole(tq, d_qk, lambda b_, g: b_ * group + g)),
@@ -625,7 +745,7 @@ def _flash_bwd_group(q3, k3, v3, do3, lse3, delta, scale, causal, block_q,
             dimension_semantics=("parallel",) + ("arbitrary",) * 3,
             vmem_limit_bytes=FUSED_VMEM_LIMIT),
         interpret=interpret,
-        name="mx_flash_bwd",
+        name="mx_flash_bwd" if window is None else "mx_flash_swa_bwd",
     )(q3, k3, v3, do3, lse3, delta)
 
 
@@ -638,7 +758,7 @@ def _bwd_path(tq, d_qk, tk=0, d_v=0, group=1):
 
 
 def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
-                    block_k, interpret):
+                    block_k, interpret, window=None):
     b, h, tq, d_qk = q.shape
     block_q, block_k = _block_sizes(tq, k.shape[2], block_q, block_k)
     bh = b * h
@@ -651,7 +771,12 @@ def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
     path = _bwd_path(tq, d_qk, k.shape[2], v.shape[3], h // k.shape[1])
     _flash_bwd_traced.labels(path=path).inc()
     if path == "fused":
-        dk, dv, dq = _flash_bwd_fused(*operands, *static)
+        dk, dv, dq = _flash_bwd_fused(*operands, *static, window)
+    elif window is not None:
+        raise ValueError(
+            "a window of %d on heads of (%d, %d): the dK/dV and dQ pair, "
+            "which heads over FUSED_DQ_BYTES take, has no window"
+            % (window, tq, d_qk))
     else:
         dk, dv = _flash_dkv(*operands, *static)
         dq = _flash_dq(*operands, *static)
@@ -659,22 +784,24 @@ def _flash_backward(q, k, v, out, lse, g, scale, causal, block_q,
             dv.reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, blocks, interpret):
-    out, _ = _flash_forward(q, k, v, scale, causal, *blocks, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, blocks, interpret, window):
+    out, _ = _flash_forward(q, k, v, scale, causal, *blocks, interpret,
+                            window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, blocks, interpret):
-    out, lse = _flash_forward(q, k, v, scale, causal, *blocks, interpret)
+def _flash_fwd(q, k, v, scale, causal, blocks, interpret, window):
+    out, lse = _flash_forward(q, k, v, scale, causal, *blocks, interpret,
+                              window)
     # Residuals are O(T·d) (q/k/v/out) + O(T) (lse) — never O(T²).
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, blocks, interpret, res, g):
+def _flash_bwd(scale, causal, blocks, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, scale, causal, *blocks,
-                           interpret)
+                           interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -697,17 +824,28 @@ _flash_group_traced = _tm.REGISTRY.counter(
     labels=("group",))
 
 
+_flash_window_traced = _tm.REGISTRY.counter(
+    "mx_flash_attention_window_traced_total",
+    "flash_attention calls traced into a program, by the sliding window "
+    "in keys; none: every earlier key",
+    labels=("window",))
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None):
+                    block_k=None, interpret=None, window=None):
     """Blockwise exact attention as one Pallas kernel.
 
     q: (batch, heads, seq, d_qk); k: (batch, kv_heads, seq, d_qk); v:
     (batch, kv_heads, seq, d_v), `kv_heads` a divisor of `heads` (query
     head h reads key/value head ``h // (heads / kv_heads)``); the
     result has q's heads and v's width. `scale` defaults to
-    ``d_qk ** -0.5``.
+    ``d_qk ** -0.5``. `window`: with `causal`, the query at position i
+    sees the `window` keys ``i - window < j <= i`` (its own among them),
+    and blocks with no such pair are skipped forward and backward; a
+    window of at least the sequence is no window.
     `block_q`/`block_k` apply to the forward and the backward kernels;
-    left at None each takes its default (`DEFAULT_BLOCK`). On
+    left at None each takes its default (`DEFAULT_BLOCK`, or
+    `DEFAULT_WINDOW_BLOCK` under a window). On
     non-TPU backends the kernel runs in interpret mode (functional, for
     tests); pass `interpret` explicitly to override.
     """
@@ -719,21 +857,33 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
         raise ValueError("%d query heads on %d key and %d value heads"
                          % (q.shape[1], k.shape[1], v.shape[1]))
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1 or q.shape[2] != k.shape[2]:
+            raise ValueError(
+                "a window (%d) is of at least one key, under causal=True, "
+                "on queries and keys of one length" % window)
+        if window >= k.shape[2]:
+            window = None
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     blocks = tuple(int(given or default) for given, default
-                   in zip((block_q, block_k), DEFAULT_BLOCK))
+                   in zip((block_q, block_k),
+                          DEFAULT_BLOCK if window is None
+                          else DEFAULT_WINDOW_BLOCK))
     _flash_traced.labels(d_qk=str(q.shape[-1]), d_v=str(v.shape[-1])).inc()
     _flash_group_traced.labels(group=str(q.shape[1] // k.shape[1])).inc()
+    _flash_window_traced.labels(window=str(window).lower()).inc()
     return _flash(q, k, v, float(scale), bool(causal), blocks,
-                  bool(interpret))
+                  bool(interpret), window)
 
 
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(q, k, v, causal=False, scale=None, block_q=None,
-                        block_k=None):
+                        block_k=None, window=None):
     """Exact attention of q (batch, heads, seq, d_qk), k (batch,
     kv_heads, seq, d_qk) and v (batch, kv_heads, seq, d_v), d_qk and d_v
     free of each other, kv_heads a divisor of heads; result (batch,
-    heads, seq_q, d_v); `scale` defaults to ``d_qk ** -0.5``."""
+    heads, seq_q, d_v); `scale` defaults to ``d_qk ** -0.5``; `window`:
+    under `causal`, the keys ``i - window < j <= i`` alone."""
     return flash_attention(q, k, v, causal=causal, scale=scale,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, window=window)

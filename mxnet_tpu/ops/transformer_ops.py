@@ -1,6 +1,7 @@
 """The pieces of a decoder block that no other operator computes:
 RMSNorm (plain, zero-centred, and gated per head), rotary position
-embedding on interleaved pairs or halves, the SiLU-gated MLP, the
+embedding on interleaved pairs or halves with plain or YaRN-scaled
+frequencies, the SiLU-gated MLP, the
 `noaux_tc` router of the DeepSeek-V3 family and the softmax top-k router
 of the `qwen3_next` family. Plain XLA ops; the attention core is
 `pallas_attention.flash_attention`, the linear-attention core
@@ -60,8 +61,34 @@ def _pairs_apart(d, dtype):
                        dtype)
 
 
+_rotary_scaling_traced = _tm.REGISTRY.counter(
+    "mx_rotary_embedding_scaling_traced_total",
+    "rotary_embedding calls traced into a program, by the scaling of "
+    "their frequencies",
+    labels=("scaling",))
+
+
+def _yarn_ramp(d, theta, original_max_position, beta_fast, beta_slow):
+    """YaRN's blend per pair, (d / 2,): 0 where a pair turns more than
+    `beta_fast` times over the original length (its frequency stays), 1
+    where fewer than `beta_slow` (its frequency is divided by the
+    factor), linear between; the two correction dims truncated."""
+    def correction_dim(rotations):
+        return d * np.log(original_max_position / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    return np.clip((np.arange(d // 2) - low) / (high - low), 0, 1).astype(
+        np.float32)
+
+
 @register("_contrib_rotary_embedding", aliases=("rotary_embedding",))
-def rotary_embedding(data, theta=10000.0, interleaved=True):
+def rotary_embedding(data, theta=10000.0, interleaved=True,
+                     scaling_factor=None, original_max_position=None,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None):
     """Rotary embedding of `data` (..., seq, d) at positions 0..seq-1.
 
     `interleaved`: pair i is (x[2i], x[2i+1]) and turns by
@@ -71,6 +98,14 @@ def rotary_embedding(data, theta=10000.0, interleaved=True):
     q.k is that of the interleaved result). Otherwise pair i is
     (x[i], x[i + d/2]). Angles and products in fp32.
 
+    `scaling_factor` with `original_max_position`: YaRN. Pair i's
+    frequency is ``theta ** (-2i/d)`` where it turns more than
+    `beta_fast` times over the original length, that over the factor
+    where fewer than `beta_slow`, a linear blend between (`_yarn_ramp`),
+    at every length; cos and sin are both multiplied by
+    `attention_factor` (``0.1 ln(factor) + 1`` where None), so every
+    q.k is by its square.
+
     The interleaved pairs are parted by a product with a constant 0/1
     matrix, accumulated in fp32: every output is one input times 1.0, so
     the values are those of ``x[..., 0::2]`` and ``x[..., 1::2]`` to the
@@ -79,13 +114,25 @@ def rotary_embedding(data, theta=10000.0, interleaved=True):
     That holds for finite input: as through any product, an Inf or NaN
     spreads over its row (`x * 0`), and a -0.0 may come back as 0.0."""
     _rotary_traced.labels(interleaved=str(bool(interleaved)).lower()).inc()
+    _rotary_scaling_traced.labels(
+        scaling="none" if scaling_factor is None else "yarn").inc()
     with jax.named_scope("rotary_embedding"):
         d = data.shape[-1]
         seq = data.shape[-2]
         inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        if scaling_factor is not None:
+            ramp = _yarn_ramp(d, theta, original_max_position, beta_fast,
+                              beta_slow)
+            inv_freq = inv_freq / jnp.float32(scaling_factor) * ramp \
+                + inv_freq * (1 - ramp)
         angle = jnp.arange(seq, dtype=jnp.float32)[:, None] \
             * inv_freq[None, :]
         cos, sin = jnp.cos(angle), jnp.sin(angle)
+        if scaling_factor is not None:
+            factor = jnp.float32(
+                0.1 * np.log(scaling_factor) + 1.0
+                if attention_factor is None else attention_factor)
+            cos, sin = cos * factor, sin * factor
         x = data.astype(jnp.float32)
         if interleaved:
             # a bf16 operand goes in as it is and the product widens it;

@@ -182,6 +182,25 @@ def tiny_qwen3next():
     return dict(TINY_QWEN3NEXT)
 
 
+# The same for `mellum2_12b_a2_5b` (PR 37): the published layer pattern,
+# rotary parameters and keys stay; widths, window and counts shrink.
+TINY_MELLUM = {
+    "hidden_size": 64, "head_dim": 16, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "moe_intermediate_size": 32,
+    "num_experts": 2, "num_experts_per_tok": 3, "num_hidden_layers": 4,
+    "vocab_size": 48, "bptt": 32, "sliding_window": 8,
+    "published": {"num_hidden_layers": 28, "num_experts": 8,
+                  "vocab_size": 384},
+    "classes": 48,
+    "optimizer": {"name": "adam", "params": {"learning_rate": 1e-3}},
+}
+
+
+@pytest.fixture
+def tiny_mellum():
+    return dict(TINY_MELLUM)
+
+
 @pytest.fixture(autouse=True)
 def _tiny_qwen3next_for_chipbench(request):
     """Every loaded copy of that module, under whatever name a test
@@ -198,3 +217,4 @@ def _tiny_qwen3next_for_chipbench(request):
         table = getattr(module, "_TINY_CFG", None)
         if isinstance(table, dict) and "resnet50_v1" in table:
             table.setdefault("qwen3_next_80b_a3b", TINY_QWEN3NEXT)
+            table.setdefault("mellum2_12b_a2_5b", TINY_MELLUM)

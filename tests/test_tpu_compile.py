@@ -2,9 +2,11 @@
 kernels (forward, the fused backward, and the dK/dV and dQ pair of the
 long-sequence path) at real widths (equal, latent attention's
 192/128, and 16 query heads on 2 key/value heads at 256, at the blocks
-the kernels default to), the delta rule's kernels at (1, 32, 4096, 128),
-and the whole training step of the `qwen3_next_80b_a3b` configuration,
-compiled for a described TPU v5e (2x2). What
+the kernels default to, and 32 on 4 at 128 over 8,192 tokens under a
+window of 1,024), the delta rule's kernels at (1, 32, 4096, 128), and
+the whole training steps of the `qwen3_next_80b_a3b` and
+`mellum2_12b_a2_5b` configurations, compiled for a described TPU v5e
+(2x2). What
 interpret mode cannot refuse — a block the lowering does not tile, more
 VMEM than a kernel may use — is refused here, at no chip time.
 
@@ -198,39 +200,33 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip):
         < a_state_a_token // 8
 
 
-def test_qwen3_next_step_fits_the_chip(one_chip):
-    """The whole `TrainStep` program of the `qwen3_next_80b_a3b`
-    configuration (one sequence of 4,096 tokens, bf16 with fp32 masters,
-    Adam), from shapes alone: arguments and temporaries stay under the
-    16 GB of `peaks.json`, with room for the imperative gradient buffers
-    that the process also holds (4 bytes a parameter)."""
+def _config_and_hbm(name):
     import json
 
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "chipbench", "peaks.json")) as f:
+        return cfg, json.load(f)["TPU v5 lite"]["hbm_bytes"]
+
+
+def _lowered_step(one_chip, net, cfg):
+    """(`TrainStep` program of `net` lowered for the described chip from
+    shapes alone: one sequence of `bptt` tokens, bf16 with fp32 masters,
+    the configuration's optimizer; the trained parameters' count)."""
     import numpy as np
 
-    from mxnet_tpu import gluon, initializer
-    from mxnet_tpu.gluon.model_zoo import qwen3_next as zoo
+    from mxnet_tpu import gluon
     from mxnet_tpu.ops import linear_attention as la
     from mxnet_tpu.parallel import TrainStep, make_mesh
     from mxnet_tpu.parallel.mesh import data_sharding
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "configs",
-                           "qwen3_next_80b_a3b.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(root, "chipbench", "peaks.json")) as f:
-        hbm = json.load(f)["TPU v5 lite"]["hbm_bytes"]
-    held = cfg["num_experts"]
     # the parameters exist as shapes alone: nothing of their size is drawn
-    net = zoo.qwen3_next(dict(
-        cfg, num_experts=cfg["published"]["num_experts"],
-        held_experts=list(range(held))))
     shapes = {name: jax.ShapeDtypeStruct(p.shape, jnp.dtype(p.dtype))
               for name, p in net.collect_params().items()}
     count = sum(int(np.prod(p.shape))
                 for p in net.collect_params().values()
                 if p.grad_req != "null")
-    assert 420e6 < count < 630e6
     device = one_chip._device
     step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                      optimizer=cfg["optimizer"]["name"],
@@ -266,12 +262,95 @@ def test_qwen3_next_step_fits_the_chip(one_chip):
     finally:
         jax.default_backend = backend
     assert real == (pa.flash_attention, la.gated_delta_rule)
-    text = lowered.as_text()
-    # three delta-rule layers and one attention layer, forward and back
-    assert text.count("mx_gdn_fwd") >= 3 and text.count("mx_gdn_bwd") >= 3
-    assert "mx_flash_bwd" in text
+    return lowered, count
+
+
+def _assert_fits(lowered, count, hbm):
+    """Arguments and temporaries under the chip's memory, with room for
+    the imperative gradient buffers that the process also holds (4 bytes
+    a parameter); returns the program's bytes."""
     memory = lowered.compile().memory_analysis()
     program = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     assert memory.argument_size_in_bytes >= 12 * count
     assert program + 4 * count < hbm
+    return program
+
+
+def test_qwen3_next_step_fits_the_chip(one_chip):
+    """The whole `TrainStep` program of the `qwen3_next_80b_a3b`
+    configuration (one sequence of 4,096 tokens, bf16 with fp32 masters,
+    Adam), from shapes alone: arguments and temporaries stay under the
+    16 GB of `peaks.json`, with room for the imperative gradient buffers
+    that the process also holds (4 bytes a parameter)."""
+    from mxnet_tpu.gluon.model_zoo import qwen3_next as zoo
+
+    cfg, hbm = _config_and_hbm("qwen3_next_80b_a3b")
+    net = zoo.qwen3_next(dict(
+        cfg, num_experts=cfg["published"]["num_experts"],
+        held_experts=list(range(cfg["num_experts"]))))
+    lowered, count = _lowered_step(one_chip, net, cfg)
+    assert 420e6 < count < 630e6
+    text = lowered.as_text()
+    # three delta-rule layers and one attention layer, forward and back
+    assert text.count("mx_gdn_fwd") >= 3 and text.count("mx_gdn_bwd") >= 3
+    assert "mx_flash_bwd" in text
+    _assert_fits(lowered, count, hbm)
+
+
+def test_windowed_flash_kernels_compile_for_v5e(one_chip):
+    """32 query heads on 4 key/value heads, 128 wide, 8,192 tokens, a
+    window of 1,024, at the windowed default blocks: the forward and the
+    fused backward of a group under their own names, with grids of as
+    many inner steps as a window touches."""
+    b, h, kv, t, d, window = 1, 32, 4, 8192, 128, 1024
+    static = (d ** -0.5, True) + pa.DEFAULT_WINDOW_BLOCK + (False, window)
+    assert pa._bwd_path(t, d, t, d, h // kv) == "fused"
+    spec = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                           sharding=one_chip)
+    row = jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32,
+                               sharding=one_chip)
+    q3, k3 = spec(b * h, t, d), spec(b * kv, t, d)
+    calls = {
+        "mx_flash_swa_fwd": (
+            lambda q, k, v: pa._flash_forward(q, k, v, *static),
+            [spec(b, h, t, d), spec(b, kv, t, d), spec(b, kv, t, d)]),
+        "mx_flash_swa_bwd": (
+            lambda *a: pa._flash_bwd_fused(*a, *static),
+            [q3, k3, k3, q3, row, row])}
+    block_q, block_k = pa.DEFAULT_WINDOW_BLOCK
+    for name, (fn, args) in calls.items():
+        lowered = jax.jit(fn).lower(*args)
+        text = lowered.as_text()
+        assert 'kernel_name = "%s"' % name in text, name
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < b * h * t * t * 4 // 4, name
+    steps, _ = pa._window_geometry(t // block_q, t // block_k, block_q,
+                                   block_k, window, keys=True)
+    assert steps == window // block_k + 1 < t // block_k
+    dk, dv, dq = jax.eval_shape(calls["mx_flash_swa_bwd"][0],
+                                *calls["mx_flash_swa_bwd"][1])
+    assert dk.shape == dv.shape == (b * kv, t, d) and dq.shape == q3.shape
+
+
+def test_mellum2_step_fits_the_chip(one_chip):
+    """The whole `TrainStep` program of the `mellum2_12b_a2_5b`
+    configuration (one sequence of 8,192 tokens, bf16 with fp32 masters,
+    Adam, no recomputation), from shapes alone: arguments, temporaries
+    and 4 bytes a parameter of gradient buffers under 15 GB."""
+    from mxnet_tpu.gluon.model_zoo import mellum as zoo
+
+    cfg, hbm = _config_and_hbm("mellum2_12b_a2_5b")
+    net = zoo.mellum(dict(
+        cfg, num_experts=cfg["published"]["num_experts"],
+        held_experts=list(range(cfg["num_experts"]))))
+    lowered, count = _lowered_step(one_chip, net, cfg)
+    assert 335e6 < count < 345e6
+    text = lowered.as_text()
+    # three sliding layers and one full layer, forward and back
+    for name, calls in (("mx_flash_swa_fwd", 3), ("mx_flash_swa_bwd", 3),
+                        ("mx_flash_fwd", 1), ("mx_flash_bwd", 1)):
+        assert text.count('kernel_name = "%s"' % name) == calls, name
+    program = _assert_fits(lowered, count, hbm)
+    assert program + 4 * count < 15e9
