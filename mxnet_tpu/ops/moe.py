@@ -9,11 +9,17 @@ add is another chip's to compute and is not in the result.
 Layout. The rows routed to held experts are sorted by expert (then by
 token) into one buffer of ``C = buffer_rows(...)`` rows; what the rows do
 not fill is zero padding, counted to the last expert's group. The three
-products (gate, up, down) are grouped products over the whole buffer
-(`jax.lax.ragged_dot`: on the TPU the compiler's own grouped-matmul
-kernel, which walks the buffer tile by tile): every tile of the C rows is
-computed whether rows or padding fill it, so the device time of a step
-does not depend on how the router filled the buffer.
+products (gate, up, down) are grouped products over the whole buffer, and
+so are their six transposes in the backward: the repo's own Pallas kernels
+(`ops/pallas_grouped_matmul.py`: `mx_gmm`, `mx_gmm_t`, `mx_tgmm`), which
+walk the buffer tile by tile with a group's weights resident in VMEM.
+Every tile of the C rows is still computed whether rows or padding fill
+it, and a launch makes ``C / tile_m + n - 1`` tile products however the
+groups' edges fall (a tile that edges cross is computed once for each
+group in it; what the groups do not need is computed all the same): the
+device time of a step does not depend on how the router filled the
+buffer. Skipping the padding's tiles would save more and make the step
+follow the fill (PERF.md, PRs 27, 28 and 38).
 
 No token is dropped. A step whose held rows exceed C computes the rest
 too, exactly, in a second pass that is taken only then (`lax.cond`; a
@@ -30,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..telemetry import metrics as _tm
+from .pallas_grouped_matmul import grouped_matmul
 from .registry import register
 
 __all__ = ["moe_held_experts", "buffer_rows"]
@@ -92,9 +99,9 @@ def moe_held_experts(data, ids, weights, gate_weight, up_weight,
         ends = jnp.minimum(jnp.cumsum(count), rows)
         sizes = jnp.diff(ends, prepend=0)
         sizes = sizes.at[n - 1].add(rows - ends[n - 1])
-        act = _silu_gated(jax.lax.ragged_dot(buf, gate_weight, sizes),
-                          jax.lax.ragged_dot(buf, up_weight, sizes))
-        out = jax.lax.ragged_dot(act, down_weight, sizes)
+        act = _silu_gated(grouped_matmul(buf, gate_weight, sizes),
+                          grouped_matmul(buf, up_weight, sizes))
+        out = grouped_matmul(act, down_weight, sizes)
         out = out.astype(jnp.float32) * w_slot[:, None]
         result = jnp.zeros((tokens, hidden), jnp.float32).at[token_of].add(
             out)

@@ -15,7 +15,9 @@ worker that is given this file loads it on the first test, and a second
 file could land on another worker. The topology is described inside the
 fixture, never at import, and the compile runs in the test's own process.
 """
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -200,6 +202,43 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip):
         < a_state_a_token // 8
 
 
+_CELLS = {"mellum2": (20480, 8, 2304, 896), "kanana2": (4608, 16, 2048, 768),
+          "qwen3next": (1920, 16, 2048, 512)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_grouped_product_kernels_compile_for_v5e(one_chip, cell):
+    """`mx_gmm`, `mx_gmm_t` and `mx_tgmm` at a cell's buffer (rows,
+    held experts, hidden, expert width; bf16), the gate/up and the down
+    product's shapes, at the tiles the shapes give: a block that is no
+    multiple of the lanes or a tile set over VMEM is refused here. The
+    results are the only arrays made: no copy of the weights."""
+    from mxnet_tpu.ops import pallas_grouped_matmul as pg
+
+    rows, groups, hidden, width = _CELLS[cell]
+    spec = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                           sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    for k, n in ((hidden, width), (width, hidden)):
+        calls = {
+            "mx_gmm": (lambda a, b, s: pg.mx_gmm(a, b, s, interpret=False),
+                       [spec(rows, k), spec(groups, k, n), sizes]),
+            "mx_gmm_t": (lambda a, b, s: pg.mx_gmm(
+                a, b, s, transpose_rhs=True, interpret=False),
+                [spec(rows, n), spec(groups, k, n), sizes]),
+            "mx_tgmm": (lambda a, b, s: pg.mx_tgmm(a, b, s, interpret=False),
+                        [spec(rows, k), spec(rows, n), sizes])}
+        for name, (fn, args) in calls.items():
+            lowered = jax.jit(fn).lower(*args)
+            assert 'kernel_name = "%s"' % name in lowered.as_text(), name
+            compiled = lowered.compile()
+            entry = compiled.as_text().split("ENTRY", 1)[1]
+            assert not [line for line in entry.splitlines()
+                        if " copy(" in line and "bf16[" in line], name
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < 2 ** 20, name
+
+
 def _config_and_hbm(name):
     import json
 
@@ -265,11 +304,11 @@ def _lowered_step(one_chip, net, cfg):
     return lowered, count
 
 
-def _assert_fits(lowered, count, hbm):
+def _assert_fits(compiled, count, hbm):
     """Arguments and temporaries under the chip's memory, with room for
     the imperative gradient buffers that the process also holds (4 bytes
     a parameter); returns the program's bytes."""
-    memory = lowered.compile().memory_analysis()
+    memory = compiled.memory_analysis()
     program = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     assert memory.argument_size_in_bytes >= 12 * count
@@ -295,7 +334,8 @@ def test_qwen3_next_step_fits_the_chip(one_chip):
     # three delta-rule layers and one attention layer, forward and back
     assert text.count("mx_gdn_fwd") >= 3 and text.count("mx_gdn_bwd") >= 3
     assert "mx_flash_bwd" in text
-    _assert_fits(lowered, count, hbm)
+    assert "ragged" not in text and 'kernel_name = "mx_tgmm"' in text
+    _assert_fits(lowered.compile(), count, hbm)
 
 
 def test_windowed_flash_kernels_compile_for_v5e(one_chip):
@@ -352,5 +392,21 @@ def test_mellum2_step_fits_the_chip(one_chip):
     for name, calls in (("mx_flash_swa_fwd", 3), ("mx_flash_swa_bwd", 3),
                         ("mx_flash_fwd", 1), ("mx_flash_bwd", 1)):
         assert text.count('kernel_name = "%s"' % name) == calls, name
-    program = _assert_fits(lowered, count, hbm)
+    # the experts' products: six kernel bodies lowered (three kernels at
+    # the gate/up and the down shapes), 36 call sites compiled (three
+    # products forward, their six transposes backward, four layers), each
+    # under a name of its own, and nothing left to XLA's ragged-dot
+    assert "ragged" not in text
+    for name in ("mx_gmm", "mx_gmm_t", "mx_tgmm"):
+        assert text.count('kernel_name = "%s"' % name) == 2, name
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "ragged-dot" not in hlo
+    sites = re.findall(r"%(mx_t?gmm(?:_t)?(?:\.\d+)?) = \S+ custom-call\(",
+                       hlo)
+    assert len(sites) == len(set(sites)) == 36
+    assert sorted(collections.Counter(
+        site.split(".")[0] for site in sites).items()) \
+        == [("mx_gmm", 12), ("mx_gmm_t", 12), ("mx_tgmm", 12)]
+    program = _assert_fits(compiled, count, hbm)
     assert program + 4 * count < 15e9
