@@ -18,9 +18,11 @@ forward with unknown param shapes the hook fills them from the inputs
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 
+import jax
 import numpy as np
 
 from .. import ndarray as nd
@@ -95,8 +97,6 @@ class Block:
         return self._params
 
     def name_scope(self):
-        import contextlib
-
         return contextlib.nullcontext()
 
     def __setattr__(self, name, value):
@@ -217,7 +217,13 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        if self._name and _being_traced(args):
+            # The block's name goes into the metadata of every device op
+            # traced under it (telemetry/device_table.py sums by it).
+            with jax.named_scope(self._name):
+                out = self.forward(*args, **kwargs)
+        else:
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out
@@ -319,7 +325,9 @@ class HybridBlock(Block):
             ps, flat_ins = xs[:n], xs[n:]
             ins = jtu.tree_unflatten(block._cached_in_tree, list(flat_ins))
             ov = override(dict(zip(params, ps)))
-            with ov:
+            scope = jax.named_scope(block._name) if block._name \
+                else contextlib.nullcontext()
+            with ov, scope:
                 out = block.forward(*ins)
             # Outputs may be nested (e.g. RNN cells return
             # (output, [states])); flatten to the executable's flat tuple
@@ -452,9 +460,14 @@ class HybridBlock(Block):
 
 
 def _is_traced_nd(x):
-    import jax.core as jcore
+    return isinstance(x._data, jax.core.Tracer)
 
-    return isinstance(x._data, jcore.Tracer)
+
+def _being_traced(args):
+    """Whether a trace is being made: a step or a CachedOp holds the
+    parameters' overrides, or an argument is a tracer."""
+    return tracing_overrides() is not None or any(
+        isinstance(a, NDArray) and _is_traced_nd(a) for a in args)
 
 
 class SymbolBlock(HybridBlock):

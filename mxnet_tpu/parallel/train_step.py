@@ -16,6 +16,9 @@ aliasing).
 """
 from __future__ import annotations
 
+import logging
+import resource
+import threading
 import time
 import weakref
 
@@ -48,6 +51,54 @@ _step_seconds = _tm.REGISTRY.histogram(
     "TrainStep.__call__ wall time (host dispatch path)")
 _steps_total = _tm.REGISTRY.counter(
     "mx_train_steps_total", "Completed TrainStep calls")
+_switches_total = _tm.REGISTRY.counter(
+    "mx_train_step_involuntary_switches_total",
+    "Involuntary context switches of the stepping thread, summed over "
+    "its TrainStep calls and the time between them (a host that was "
+    "taken away)")
+# What the step executable itself says it needs (memory_analysis() of the
+# program the last step ran), folded when the registry is collected.
+_PROGRAM_GAUGES = {
+    "total": _tm.REGISTRY.gauge(
+        "mx_step_program_bytes", "Device bytes the step executable needs "
+        "to run: arguments + outputs - aliased + temporaries + code (the "
+        "largest live TrainStep)"),
+    "temp": _tm.REGISTRY.gauge(
+        "mx_step_program_temp_bytes", "Temporaries of that executable"),
+}
+_program_recompiled = _tm.REGISTRY.counter(
+    "mx_step_program_recompiled_total", "Demands of a step executable's "
+    "statistics that compiled a program instead of finding the step's "
+    "own (should stay 0)")
+_live_steps = weakref.WeakSet()
+_log = logging.getLogger(__name__)
+
+
+def _host_usage():
+    """(CPU seconds, involuntary context switches) of this thread."""
+    return (time.thread_time(),
+            resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw)
+
+
+def _fold_step_programs():
+    """Registry.on_collect hook: the gauges of the largest live step
+    program. The first collect after a step pays one lookup of the
+    cached executable; nothing inside a step does."""
+    best = None
+    for ts in list(_live_steps):
+        try:
+            stats = ts.program_stats()
+        except RuntimeError:          # arguments given away to a running step
+            continue
+        if stats and (best is None or stats["total_bytes"]
+                      > best["total_bytes"]):
+            best = stats
+    if best is not None:
+        _PROGRAM_GAUGES["total"].set(best["total_bytes"])
+        _PROGRAM_GAUGES["temp"].set(best["temp_bytes"])
+
+
+_tm.REGISTRY.on_collect(_fold_step_programs)
 
 
 def _as_pair(res):
@@ -138,6 +189,14 @@ class TrainStep:
         # whole-step XLA compile.
         self._hp_component = None
         self._hp_ready = False
+        # Shapes, dtypes and shardings of the last call's (x, y, lr, t, key)
+        # (never the arrays: a batch must not outlive its step) and, once
+        # asked for, the executable it ran: program_stats() / program_text().
+        self._last_args = None
+        self._program = None
+        # (thread, CPU seconds, involuntary switches) at the last step's end
+        self._host_mark = None
+        _live_steps.add(self)
 
     def _make_opt_rule(self):
         """(n_states, update_fn) for the configured optimizer.
@@ -484,7 +543,10 @@ class TrainStep:
                 loss_of, has_aux=True)(pvals, aux_vals, xs, ys, key)
             gather = lambda t: jax.tree_util.tree_map(
                 lambda a: ordered_mean(jax.lax.all_gather(a, "dp")), t)
-            return gather(loss), gather(new_aux), gather(g)
+            # the gradients' aggregation is the backward's in the
+            # profiler's table of phases
+            with jax.named_scope("backward"):
+                return gather(loss), gather(new_aux), gather(g)
 
         data_spec = P(tuple(a for a in ("dp",) if a in mesh.axis_names))
         rep = P()
@@ -518,28 +580,39 @@ class TrainStep:
                 (lambda a: a.astype(cdt) if jnp.issubdtype(a.dtype,
                                                            jnp.floating)
                  else a)
-            mapping = {p: NDArray(cast(pvals[p.name])) for p in train_params}
-            # Aux (BN running stats) stay fp32: in train mode they sit
-            # only on the EMA-update path, and BatchNorm hands that path
-            # the batch moments as it summed them, in fp32 (the
-            # reference's AccReal contract), while activations stay in
-            # the compute dtype.
-            mapping.update({p: NDArray(aux_vals[p.name])
-                            for p in aux_params})
+            # The phases' names go into every device op's metadata
+            # (`forward`, `loss`; autodiff names the backward
+            # `transpose(jvp(forward))`): telemetry/device_table.py sums
+            # a capture by them. The casts are the forward's.
+            with jax.named_scope("forward"):
+                mapping = {p: NDArray(cast(pvals[p.name]))
+                           for p in train_params}
+                # Aux (BN running stats) stay fp32: in train mode they
+                # sit only on the EMA-update path, and BatchNorm hands
+                # that path the batch moments as it summed them, in fp32
+                # (the reference's AccReal contract), while activations
+                # stay in the compute dtype.
+                mapping.update({p: NDArray(aux_vals[p.name])
+                                for p in aux_params})
+                x = NDArray(cast(x))
             ov = override(mapping)
             with autograd.pause(train_mode=True), \
                     _random.trace_key_scope(key), ov:
-                out = net(NDArray(cast(x)))
-                if cdt is not None:
-                    # Loss math in fp32 regardless of compute dtype.
-                    out = NDArray(out._data.astype(jnp.float32))
-                loss = loss_fn(out, NDArray(y))
+                with jax.named_scope("forward"):
+                    out = net(x)
+                with jax.named_scope("loss"):
+                    if cdt is not None:
+                        # Loss math in fp32 regardless of compute dtype.
+                        out = NDArray(out._data.astype(jnp.float32))
+                    loss = loss_fn(out, NDArray(y))
             new_aux = dict(aux_vals)
-            for p, v in ov.writes.items():
-                nv = v._data if isinstance(v, NDArray) else v
-                # Running stats keep their stored (fp32) dtype.
-                new_aux[p.name] = nv.astype(aux_vals[p.name].dtype)
-            return jnp.mean(loss._data), new_aux
+            with jax.named_scope("forward"):
+                for p, v in ov.writes.items():
+                    nv = v._data if isinstance(v, NDArray) else v
+                    # Running stats keep their stored (fp32) dtype.
+                    new_aux[p.name] = nv.astype(aux_vals[p.name].dtype)
+            with jax.named_scope("loss"):
+                return jnp.mean(loss._data), new_aux
 
         opt_update = self._opt_update
 
@@ -559,15 +632,16 @@ class TrainStep:
             opt_key = jax.random.fold_in(key, 0x7FFFFFFF) if needs_key \
                 else None
             new_p, new_s = {}, {}
-            for idx, (name, p) in enumerate(pvals.items()):
-                g = grads[name].astype(jnp.float32)
-                if needs_key:
-                    new_p[name], new_s[name] = opt_update(
-                        p, g, opt_state[name], lr, t,
-                        jax.random.fold_in(opt_key, idx))
-                else:
-                    new_p[name], new_s[name] = opt_update(
-                        p, g, opt_state[name], lr, t)
+            with jax.named_scope("optimizer_update"):
+                for idx, (name, p) in enumerate(pvals.items()):
+                    g = grads[name].astype(jnp.float32)
+                    if needs_key:
+                        new_p[name], new_s[name] = opt_update(
+                            p, g, opt_state[name], lr, t,
+                            jax.random.fold_in(opt_key, idx))
+                    else:
+                        new_p[name], new_s[name] = opt_update(
+                            p, g, opt_state[name], lr, t)
             return new_p, new_s, new_aux, loss
 
         step.__name__ = "mx_train_step"      # the executable's name
@@ -601,6 +675,9 @@ class TrainStep:
         feeds its own `num_parts`/`part_index` shard of the epoch.
         """
         t_start = time.perf_counter()
+        me = threading.get_ident()
+        if self._host_mark is None or self._host_mark[0] != me:
+            self._host_mark = (me,) + _host_usage()
         if self._hp_component is None:
             self._hp_component = _hp.unique_component("train_step")
         # Heartbeat lane for the hang watchdog: in-flight work between
@@ -634,9 +711,12 @@ class TrainStep:
             # inside it, so its device-side cost is only separable in
             # the XPlane trace.
             with _trace.span("train_step::dispatch", step=t):
+                last = (x, y, jnp.float32(self.lr), jnp.float32(t), key)
                 new_p, new_s, new_a, loss = self._jitted(
                     self._param_vals, self._opt_state, self._aux_vals,
-                    x, y, jnp.float32(self.lr), jnp.float32(t), key)
+                    *last)
+            self._last_args = tuple(
+                (a.shape, a.dtype, a.sharding) for a in last)
             if _attr.device_spans_enabled():
                 # Step attribution's device bracket: how long the
                 # device still chews after dispatch returned. Gated —
@@ -656,7 +736,15 @@ class TrainStep:
                 new_p, new_s, new_a
             self.num_update = t
             t_end = time.perf_counter()
-            _trace.complete("train_step::step", t_start, t_end, step=t)
+            # Wall time without CPU time and with switches is a host that
+            # was taken away; with CPU time, a pause of the program's own.
+            cpu, switches = _host_usage()
+            _, cpu0, switches0 = self._host_mark
+            self._host_mark = (me, cpu, switches)
+            _trace.complete("train_step::step", t_start, t_end, step=t,
+                            cpu_ms=(cpu - cpu0) * 1e3,
+                            switches=switches - switches0)
+            _switches_total.inc(switches - switches0)
             _step_seconds.observe(t_end - t_start)
             _steps_total.inc()
             _cc.step_done()
@@ -675,6 +763,73 @@ class TrainStep:
 
     def set_learning_rate(self, lr):
         self.lr = float(lr)
+
+    # -- the step executable's own account ----------------------------------
+
+    def _last_structs(self):
+        """The last call's (x, y, lr, t, key) as ShapeDtypeStructs."""
+        return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                for shape, dtype, sharding in self._last_args]
+
+    def _last_program(self):
+        """The executable the last step ran, found again in the jit's
+        caches by lowering the live state and the last batch's shapes,
+        dtypes and shardings: no trace of the Python, no lowering, no
+        compile. None before the first step."""
+        last = self._last_args
+        if last is None:
+            return None
+        sig = tuple(a[:2] for a in last[:2])
+        if self._program is None or self._program["sig"] != sig:
+            t0 = time.perf_counter()
+            compiled = self._jitted.lower(
+                self._param_vals, self._opt_state, self._aux_vals,
+                *self._last_structs()).compile()
+            if any(r.kind == "build" and r.start >= t0
+                   for r in _cc.build_log()):
+                _program_recompiled.inc()
+                if _program_recompiled.value == 1:
+                    _log.warning(
+                        "TrainStep.program_stats() compiled a program: the "
+                        "step's own executable was not found in the cache")
+            self._program = {"sig": sig, "compiled": compiled, "text": None}
+        return self._program
+
+    def program_stats(self):
+        """``memory_analysis()`` of the executable the last step ran:
+        ``argument_bytes``, ``output_bytes``, ``alias_bytes`` (outputs
+        that reuse donated arguments), ``temp_bytes``, ``code_bytes`` and
+        their sum ``total_bytes`` (arguments + outputs - aliased +
+        temporaries + code: what the device must hold to run a step,
+        which the allocator's peak counter misses the temporaries of).
+        Found on first demand and kept; None before the first step."""
+        program = self._last_program()
+        if program is None:
+            return None
+        m = program["compiled"].memory_analysis()
+        stats = {
+            "argument_bytes": int(m.argument_size_in_bytes),
+            "output_bytes": int(m.output_size_in_bytes),
+            "alias_bytes": int(m.alias_size_in_bytes),
+            "temp_bytes": int(m.temp_size_in_bytes),
+            "code_bytes": int(m.generated_code_size_in_bytes),
+        }
+        stats["total_bytes"] = (
+            stats["argument_bytes"] + stats["output_bytes"]
+            - stats["alias_bytes"] + stats["temp_bytes"]
+            + stats["code_bytes"])
+        return stats
+
+    def program_text(self):
+        """Optimized HLO text of the executable the last step ran (every
+        instruction with its ``op_name``; fused computations with their
+        inner instructions'). None before the first step."""
+        program = self._last_program()
+        if program is None:
+            return None
+        if program["text"] is None:
+            program["text"] = program["compiled"].as_text()
+        return program["text"]
 
     def _gather_host(self, tree):
         """Pytree of global arrays -> pytree of host numpy, valid on

@@ -8,10 +8,17 @@ summary tables from aggregate_stats.cc).
 
 TPU rebuild, two layers:
 
-1. Device/XLA level — delegated to `jax.profiler`: `set_state('run')`
-   starts a trace capture whose output (TensorBoard/XPlane format, the
-   modern chrome-trace equivalent; profiler.h:87 wrote chrome JSON)
-   lands in the configured directory with per-HLO device timing.
+1. Device/XLA level — captured by `jax.profiler`, reduced here:
+   `set_state('run')` starts a trace capture whose output
+   (TensorBoard/XPlane format, the modern chrome-trace equivalent;
+   profiler.h:87 wrote chrome JSON) lands in the configured directory
+   with per-HLO device timing, and after `set_state('stop')`
+   :func:`device_table` reads that capture back
+   (`telemetry/device_table.py`): device ms a step by executable, by
+   phase of the step (`forward`, `loss`, `backward`,
+   `optimizer_update`, and a combined row for a fusion that mixes two),
+   by scope (Gluon block names and op scopes), by kernel, and the
+   longest idle gaps, each split by what the host did through it.
 2. Framework level — a thin VIEW over `mxnet_tpu.telemetry.REGISTRY`:
    the dispatch path records per-op wall-time spans into the
    ``mx_dispatch_seconds`` histogram family (exact count/total/min/max
@@ -24,8 +31,15 @@ TPU rebuild, two layers:
    /metrics endpoint) exposes everything this module shows and more.
 
 On an async backend the op spans measure *dispatch* cost, not device
-cost — the device truth lives in the trace files; both are stated in
-the output header.
+cost. `dumps()` prints both, under their names: the dispatch table; a
+"Device" section, the table above, once a capture has been stopped (key
+``"device"`` of ``format='json'``; the capture itself stays in the trace
+directory for TensorBoard or Perfetto); and a "Host" section, with or
+without a capture: the longest intervals between successive
+``train_step::dispatch`` ends against their median, each with the
+collector's ms (``host::gc``), the stepping thread's CPU share and its
+involuntary context switches — what to print after a window that read
+low.
 
 Reset semantics (pinned by tests/test_profiler.py): ``dumps(reset=True)``
 clears the per-op dispatch statistics only. User-defined Counters are
@@ -43,6 +57,7 @@ from .telemetry import trace as _trace
 
 __all__ = ["set_config", "profiler_set_config", "set_state",
            "profiler_set_state", "pause", "resume", "dump", "dumps",
+           "device_table",
            "set_kvstore_handle", "server_dumps",
            "Domain", "Task", "Frame", "Counter", "Marker"]
 
@@ -53,6 +68,11 @@ _state = {
                "profile_symbolic": True, "profile_imperative": True,
                "profile_api": True, "aggregate_stats": True},
     "trace_active": False,
+    # the capture the last set_state('stop') closed: whether there is
+    # one, and its device lines once device_table() has read them
+    "capture_ready": False,
+    "capture": None,
+    "table": None,                 # dumps()'s reduction of it
 }
 # Kept for back-compat with callers that serialized on the profiler
 # lock; the registry families shard their own locks now.
@@ -132,6 +152,9 @@ def set_state(state="stop", profile_process="worker"):
             os.makedirs(_trace_dir(), exist_ok=True)
             jax.profiler.start_trace(_trace_dir())
             _state["trace_active"] = True
+            _state["capture_ready"] = False
+            _state["capture"] = _state["table"] = None
+            _trace.mark_capture_clock()
         except Exception:
             _state["trace_active"] = False  # framework-level only
     elif state == "stop" and _state["running"]:
@@ -141,6 +164,7 @@ def set_state(state="stop", profile_process="worker"):
 
             jax.profiler.stop_trace()
             _state["trace_active"] = False
+            _state["capture_ready"] = True
 
 
 profiler_set_state = set_state
@@ -199,6 +223,41 @@ def server_dumps():
     return _server_cmd("dumps")
 
 
+def device_table(depth=3, steps=None, skip=0):
+    """Where the device's time went in the capture that the last
+    ``set_state('stop')`` closed (``telemetry.device_table.reduce_capture``
+    has the details): ms a step ``by_executable``, ``by_phase``,
+    ``by_scope`` (paths cut to `depth`), ``by_kernel``; ``busy_ms``,
+    ``idle_ms`` and the longest ``idle_gaps``, each split by the host's
+    spans. Steps are the launches of the step executable after the first
+    `skip` (or `steps`, as the caller says). ``names`` is None where the
+    phases and scopes are the capture's own, else why they may not be (an
+    executable loaded from a compile-cache entry that an older build
+    wrote, more than one live TrainStep, none). None where no capture has
+    been made, while one is running, and where it holds no device line."""
+    return _device_table(_trace.chrome_trace()["traceEvents"], depth=depth,
+                         steps=steps, skip=skip)
+
+
+def _device_table(ring_events, **how):
+    from .telemetry import device_table as _dt
+
+    if not _state["capture_ready"]:
+        return None
+    if _state["capture"] is None:
+        path = _dt.find_capture(_trace_dir())
+        if path is None:
+            return None
+        _state["capture"] = _dt.load_capture(path)
+    if not _state["capture"]["ops"]:
+        return None                    # a capture with no device line (CPU)
+    from .parallel import train_step as _ts
+
+    return _dt.reduce_capture(
+        _state["capture"], ring_events,
+        [ts.program_text() for ts in list(_ts._live_steps)], **how)
+
+
 def _op_table(reset=False):
     """{op: (calls, total_s, min_s, max_s, p50_s, p99_s)} from the
     dispatch family (quantiles interpolated from the histogram buckets,
@@ -252,8 +311,16 @@ def dumps(reset=False, format="table"):
         if reset:
             _dispatch.drain()
         return text
+    from .telemetry import device_table as _dt
+
     ops = _op_table(reset=reset)
     counters = _counter_table()
+    ring_events = _trace.chrome_trace()["traceEvents"]
+    # reduced once a capture: a dashboard may call dumps() every second
+    if _state["capture_ready"] and _state["table"] is None:
+        _state["table"] = _device_table(ring_events)
+    device = _state["table"] if _state["capture_ready"] else None
+    host = _dt.host_table(ring_events)
     if format == "json":
         import json
 
@@ -264,10 +331,12 @@ def dumps(reset=False, format="table"):
                            "p50_ms": st[4] * 1e3, "p99_ms": st[5] * 1e3}
                     for name, st in ops.items()},
             "counters": counters,
+            "device": device,
+            "host": host,
         })
     lines = [
-        "Profile Statistics (framework dispatch spans; device timing "
-        "is in the trace directory %r)" % _trace_dir(),
+        "Profile Statistics (framework dispatch spans; device timing: "
+        "the Device section below, from the capture in %r)" % _trace_dir(),
         "%-40s %10s %14s %14s %14s %14s %14s"
         % ("Name", "Calls", "Total(ms)", "Min(ms)", "Max(ms)",
            "P50(ms)", "P99(ms)"),
@@ -280,6 +349,10 @@ def dumps(reset=False, format="table"):
     for name in sorted(counters):
         lines.append("%-40s %10s %14s" % (name, "counter",
                                           counters[name]))
+    if device is not None:
+        lines.append(_dt.render(device))
+    if host is not None:
+        lines.append(_dt.render_host(host))
     return "\n".join(lines)
 
 

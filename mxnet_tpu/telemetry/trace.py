@@ -46,11 +46,24 @@ Design:
   :class:`mxnet_tpu.telemetry.export.StreamingTraceWriter`'s
   incremental segment files.
 
+* **What the host did, always on.** One ``gc.callbacks`` entry turns
+  every generation-2 collection, and any collection of a millisecond or
+  more, into a ``host::gc`` span (``generation``, ``collected``) and
+  ``mx_gc_pause_seconds_total{generation}``: a pause of Python's own is
+  then in the ring of whatever run met it, traced or not.
+* **One clock with a capture.** :func:`mark_capture_clock`, called by
+  ``mx.profiler.set_state('run')``, writes one annotation into the
+  running capture whose argument is ``perf_counter``'s reading at that
+  instant: the offset between the two clocks, through which
+  ``telemetry.device_table`` lays every ring event (the retroactive
+  ones that cannot be mirrored, too) beside the device lines.
+
 ``set_enabled(False)`` turns ``span()`` bodies into no-ops (one boolean
 check) — the tracing half of the telemetry overhead contract.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -64,7 +77,8 @@ from . import xtrace as _xtrace
 __all__ = ["span", "instant", "complete", "chrome_trace", "dump",
            "drain", "clear", "set_enabled", "enabled", "set_capacity",
            "capacity", "event_count", "set_span_ids", "span_ids_enabled",
-           "current_span_id", "take_dropped"]
+           "current_span_id", "take_dropped", "mark_capture_clock",
+           "CLOCK_SYNC", "GC_SPAN"]
 
 _DEFAULT_CAPACITY = 16384
 # Rings of dead threads retained for the next flush (most recent first
@@ -286,6 +300,20 @@ class _Span:
         return False
 
 
+CLOCK_SYNC = "mx_profiler::clock_sync"
+
+
+def mark_capture_clock():
+    """Write ``perf_counter``'s reading into the running ``jax.profiler``
+    capture as the argument ``perf_counter_ns`` of one annotation named
+    :data:`CLOCK_SYNC`; its start on the capture's clock is that reading
+    on the rings'. A no-op while no capture runs."""
+    if _capture_running():
+        with _annotation(CLOCK_SYNC,
+                         perf_counter_ns=time.perf_counter_ns()):
+            pass
+
+
 def span(name, **args):
     """``with trace.span("step", step=i): ...`` — records a chrome
     complete event covering the block (thread-local ring)."""
@@ -316,6 +344,42 @@ def complete(name, start_s, end_s, **args):
     if _state["enabled"]:
         _append(("X", name, start_s * 1e6,
                  max(0.0, end_s - start_s) * 1e6, _stamp(args) or None))
+
+
+GC_SPAN = "host::gc"
+_GC_SPAN_MIN_S = 1e-3
+_gc_pause = _metrics.REGISTRY.counter(
+    "mx_gc_pause_seconds_total",
+    "Seconds inside Python's garbage collector, over the collections "
+    "that became a host::gc span (every generation-2 one, any of 1 ms or "
+    "more)", labels=("generation",))
+_gc_children = {g: _gc_pause.labels(generation=g) for g in range(3)}
+_gc_clock = time.perf_counter      # tests put their own here
+_gc_start = [None]
+
+
+def _on_gc(phase, info):
+    """``gc.callbacks`` entry: two clock reads a collection. It runs
+    wherever an allocation set the collector off, possibly under one of
+    this module's locks: so it takes none (``inc_try``; no span from a
+    thread that has no ring yet)."""
+    if phase == "start":
+        _gc_start[0] = _gc_clock()
+        return
+    t0, _gc_start[0] = _gc_start[0], None
+    if t0 is None:
+        return
+    t1 = _gc_clock()
+    gen = info.get("generation", 0)
+    if gen < 2 and t1 - t0 < _GC_SPAN_MIN_S:
+        return
+    _gc_children[gen].inc_try(t1 - t0)
+    if _state["enabled"] and getattr(_tls, "ring", None) is not None:
+        _append(("X", GC_SPAN, t0 * 1e6, (t1 - t0) * 1e6,
+                 {"generation": gen, "collected": info.get("collected", 0)}))
+
+
+gc.callbacks.append(_on_gc)
 
 
 def event_count():

@@ -73,7 +73,9 @@ def test_profiler_dumps_json_format():
     profiler.set_state("stop")
 
     payload = json.loads(profiler.dumps(format="json"))
-    assert set(payload) == {"trace_dir", "ops", "counters"}
+    assert set(payload) == {"trace_dir", "ops", "counters", "device",
+                            "host"}
+    assert payload["device"] is None      # the CPU's capture: no device line
     tanh_keys = [k for k in payload["ops"] if "tanh" in k]
     assert tanh_keys, sorted(payload["ops"])
     st = payload["ops"][tanh_keys[0]]
