@@ -3,7 +3,9 @@ kernels (forward, the fused backward, and the dK/dV and dQ pair of the
 long-sequence path) at real widths (equal, latent attention's
 192/128, and 16 query heads on 2 key/value heads at 256, at the blocks
 the kernels default to, and 32 on 4 at 128 over 8,192 tokens under a
-window of 1,024), the delta rule's kernels at (1, 32, 4096, 128), and
+window of 1,024), the delta rule's kernels at (1, 32, 4096, 128), the experts' combine
+at the three cells' buffers and one sparse layer's value and gradient at
+cell 5's shapes (no scatter over hidden-wide rows), and
 the whole training steps of the `qwen3_next_80b_a3b` and
 `mellum2_12b_a2_5b` configurations, compiled for a described TPU v5e
 (2x2). What
@@ -237,6 +239,80 @@ def test_grouped_product_kernels_compile_for_v5e(one_chip, cell):
                         if " copy(" in line and "bf16[" in line], name
             assert compiled.memory_analysis().temp_size_in_bytes \
                 < 2 ** 20, name
+
+
+# tokens, hidden, width, held, experts, top_k, capacity factor
+_LAYERS = {"mellum2": (8192, 2304, 896, 8, 64, 8, 2.5),
+           "kanana2": (4096, 2048, 768, 16, 128, 6, 1.5),
+           "qwen3next": (4096, 2048, 512, 16, 512, 10, 2.0)}
+
+
+@pytest.mark.parametrize("cell", sorted(_LAYERS))
+def test_moe_combine_kernel_compiles_for_v5e(one_chip, cell):
+    """`mx_moe_combine` at a cell's tokens, hidden width, held experts
+    and buffer (bf16 rows, fp32 result), at the tiles the shapes give."""
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops import pallas_moe_combine as pmc
+
+    tokens, hidden, _, held, experts, top_k, cf = _LAYERS[cell]
+    rows = moe.buffer_rows(tokens, top_k, held, experts, cf)
+    spec = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    lowered = jax.jit(lambda x, p, w: pmc.mx_moe_combine(
+        x, p, w, interpret=False)).lower(
+            spec((rows, hidden), jnp.bfloat16),
+            spec((tokens, held), jnp.int32),
+            spec((tokens, held), jnp.float32))
+    assert 'kernel_name = "mx_moe_combine"' in lowered.as_text()
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < tokens * hidden * 4
+
+
+def test_sparse_layer_moves_rows_without_a_scatter(one_chip):
+    """One sparse layer's value and gradient at cell 5's shapes (8,192
+    tokens, 2,304 wide, 8 of 64 experts held, 8 a token, a buffer of
+    20,480 rows): no `scatter` over hidden-wide rows in the compiled
+    program (the combine and the dispatch's pullback are gathers), two
+    `mx_moe_combine` calls (the result and the dispatch's pullback), and
+    temporaries under the 1.19 GB that the scatter form took by ISSUE
+    41's count."""
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.ops import pallas_grouped_matmul as pg
+
+    tokens, hidden, width, held, experts, top_k, cf = _LAYERS["mellum2"]
+    spec = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    bf16 = jnp.bfloat16
+
+    def value_and_gradient(x, ids, w, gate, up, down, cot):
+        def value(x, w, gate, up, down):
+            out, _, _ = moe.moe_held_experts(
+                x, ids, w, gate, up, down, held=tuple(range(held)),
+                num_experts=experts, capacity_factor=cf)
+            return jnp.sum(out.astype(jnp.float32) * cot)
+        return jax.value_and_grad(value, argnums=(0, 1, 2, 3, 4))(
+            x, w, gate, up, down)
+
+    # off the TPU the kernels would take interpret mode
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        lowered = jax.jit(value_and_gradient).lower(
+            spec((tokens, hidden), bf16), spec((tokens, top_k), jnp.int32),
+            spec((tokens, top_k), jnp.float32),
+            spec((held, hidden, width), bf16),
+            spec((held, hidden, width), bf16),
+            spec((held, width, hidden), bf16),
+            spec((tokens, hidden), jnp.float32))
+    finally:
+        jax.default_backend = backend
+    assert pg._interpret(None)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    wide = [line for line in hlo.splitlines()
+            if re.search(r"= \S+\[[0-9,]*%d\]\S* scatter\(" % hidden, line)]
+    assert not wide, wide
+    assert len(re.findall(r"%mx_moe_combine(?:\.\d+)? = ", hlo)) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.19e9
 
 
 def _config_and_hbm(name):
