@@ -25,13 +25,16 @@ U = T beta V, W = T beta exp(G) K, the masked Q K^T. What has, the state
 carried from chunk to chunk, is two kernels with the state in VMEM across
 the chunk axis: `mx_gdn_fwd` (D = U - W S, O, S') and `mx_gdn_bwd`, the
 same scan reversed, which is handed the state at each chunk's start (one
-state a chunk is the only residual beside the arguments, never one a
-token) and forms D again. Backward the operands are formed again
-(`mx_gdn_prepare`, which there also hands on T) and their cotangents
-pulled back by hand from T (`mx_gdn_prepare_bwd`), never through the
-elimination. Decays, beta, the solve and the state are fp32; the products
-take their operands in the type of q (bf16 in training) and accumulate in
-fp32.
+state a chunk, never one a token) and forms D again. Under
+differentiation the forward's preparation also writes T, and the backward
+is handed the six operands and T as residuals beside the arguments: they
+are O(seq d), as the flash kernel's q, k, v and out are (0.24 GB a layer
+at 32 heads of 128 over 4,096 tokens), so the preparation runs once a
+call. Their cotangents are pulled back by hand from T
+(`mx_gdn_prepare_bwd`), never through the elimination. An
+undifferentiated call writes no T. Decays, beta, the solve and the state
+are fp32; the products take their operands in the type of q (bf16 in
+training) and accumulate in fp32.
 
 Registered as `_contrib_gated_delta_rule` and `_contrib_causal_conv1d`.
 """
@@ -628,18 +631,21 @@ def _scan_backward(qg, kd, w, u, p, a, states, do, chunk, interpret):
 # that call the rule at one shape are traced and lowered once, not once a
 # layer (the kernels' bodies are long).
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7), inline=True)
-def _forward(q, k, v, g, beta, chunk, interpret, state):
-    operands = _chunk_operands(q, k, v, g, beta, chunk, interpret, state)
-    return _scan_forward(*operands, chunk, interpret)
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8), inline=True)
+def _forward(q, k, v, g, beta, chunk, interpret, state, keep_inverse):
+    """(o, what the backward reads: the six operands of the scan, T where
+    `keep_inverse`, and the state at each chunk's start)."""
+    prepared = _chunk_operands(q, k, v, g, beta, chunk, interpret, state,
+                               keep_inverse)
+    o, states = _scan_forward(*prepared[:6], chunk, interpret)
+    return o, prepared + (states,)
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9), inline=True)
-def _backward(q, k, v, g, beta, states, do, chunk, interpret, state):
-    # the operands again (a state a chunk is kept, not 151 MB of them),
-    # the scan reversed, then the pullback of the operands
-    *operands, inv = _chunk_operands(q, k, v, g, beta, chunk, interpret,
-                                     state, keep_inverse=True)
+def _backward(q, k, v, g, beta, kept, do, chunk, interpret, state):
+    # the scan reversed on the forward's operands, then their pullback
+    # from the forward's T: the preparation does not run again
+    *operands, inv, states = kept
     cotangents = _scan_backward(*operands, states, do.astype(q.dtype), chunk,
                                 interpret)
     return _chunk_operands_pullback(q, k, v, g, beta, inv, cotangents, chunk,
@@ -648,12 +654,17 @@ def _backward(q, k, v, g, beta, states, do, chunk, interpret, state):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _delta_rule(q, k, v, g, beta, chunk, interpret, state):
-    return _forward(q, k, v, g, beta, chunk, interpret, state)[0]
+    return _forward(q, k, v, g, beta, chunk, interpret, state, False)[0]
 
 
 def _delta_fwd(q, k, v, g, beta, chunk, interpret, state):
-    o, states = _forward(q, k, v, g, beta, chunk, interpret, state)
-    return o, (q, k, v, g, beta, states)
+    # Kept: the operands and T, O(seq d) as the flash kernel's q, k, v and
+    # out are (at 32 heads of 128 over 4,096 tokens, chunk 64: qg, kd, w
+    # and u 33.5 MB each in bf16, p 16.8 and 33.5 on the TPU, whose lanes
+    # pad its 64 columns to 128, the decays 1, T 67 in fp32: 0.24 GB a
+    # layer), and a state a chunk, never one a token
+    o, kept = _forward(q, k, v, g, beta, chunk, interpret, state, True)
+    return o, (q, k, v, g, beta, kept)
 
 
 def _delta_bwd(chunk, interpret, state, res, do):
