@@ -115,39 +115,145 @@ class _Compiles:
 _compiles = _Compiles()
 
 
-def check_losses(losses, classes):
-    """chip_smoke.py's `_check_losses`, returning what is wrong."""
-    problems = []
+def _compare(checks, name, value, above=None, below=None):
+    """Record a compared number beside its limits in `checks` (the
+    result line's last key); True where it lies strictly between them
+    (a NaN lies nowhere)."""
+    entry = {"value": value}
+    if above is not None:
+        entry["above"] = above
+    if below is not None:
+        entry["below"] = below
+    checks[name] = entry
+    return (above is None or value > above) and \
+        (below is None or value < below)
+
+
+def check_losses(reads, pool_size, chk, checks):
+    """What is wrong with the losses. `reads`: (call, loss) of every
+    step whose loss was read, the first step's first: all finite, the
+    first near ln(`chk.classes`). The step trains on pool batch `call %
+    pool_size`, so two reads of one batch see the same rows under the
+    values of two moments: of the batches read twice, the one read
+    furthest apart has to have its loss fallen by over
+    `chk.loss_fall_min` between its first and its last read. The same
+    rows in the same program, so the fall has no spread from batch to
+    batch (the reads of different batches differ by as much as the loss
+    falls over a window, and "last read under first" was a coin)."""
+    losses = [v for _, v in reads]
+    classes = chk["classes"]
     if not all(math.isfinite(v) for v in losses):
-        return ["non-finite loss: %r" % (losses,)]
+        return ["non-finite loss: %r" % (reads,)]
+    problems = []
     # An untrained classifier sits at ln(classes) plus the spread of its
     # logits (default init: about 1.3x).
-    ratio = losses[0] / math.log(classes)
-    if not 0.7 < ratio < 1.5:
+    if not _compare(checks, "first_loss_over_ln_classes",
+                    losses[0] / math.log(classes), 0.7, 1.5):
         problems.append("first loss %.4f is not near ln(%d)"
                         % (losses[0], classes))
-    if not losses[-1] < losses[0]:
-        problems.append("loss did not fall: %.4f -> %.4f"
-                        % (losses[0], losses[-1]))
+    by_batch = {}
+    for call, v in reads:
+        by_batch.setdefault(call % pool_size, []).append((call, v))
+    pairs = [(seen[-1][0] - seen[0][0], seen[-1][0], seen[0], seen[-1])
+             for seen in by_batch.values() if len(seen) > 1]
+    if not pairs:
+        problems.append("no pool batch was read twice (calls %r, %d "
+                        "batches): the loss's fall is not compared"
+                        % ([c for c, _ in reads], pool_size))
+        return problems
+    _, _, (c0, v0), (c1, v1) = max(pairs)
+    if not _compare(checks, "same_batch_loss_fall", v0 - v1,
+                    chk["loss_fall_min"]):
+        problems.append("the loss on pool batch %d did not fall by over %g "
+                        "from call %d to call %d: %.6f -> %.6f"
+                        % (c0 % pool_size, chk["loss_fall_min"], c0, c1,
+                           v0, v1))
     return problems
 
 
-def check_reference(cfg, wl, seed, runner, model):
-    """Logits and loss of the system's evaluation forward, with the
-    trained values, against the model's plain reference on a few seeded
-    samples. Returns (facts, problems)."""
+SAMPLE = 1024
+
+
+def sample_masters(runner):
+    """name -> host copy (fp32) of an evenly spaced sample of each
+    trainable master's elements, up to SAMPLE of them, taken by one
+    jitted program, built at each call and not kept (the step donates
+    the arrays themselves, so they are copied out)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    def spaced(v):
+        flat = v.reshape(-1)
+        return flat[::max(1, flat.size // SAMPLE)][:SAMPLE].astype(
+            jnp.float32)
+
+    sampler = jax.jit(lambda masters: {k: spaced(v)
+                                       for k, v in masters.items()})
+    got = jax.device_get(sampler(runner.masters()))
+    return {k: np.asarray(v, np.float32) for k, v in got.items()}
+
+
+def check_motion(cfg, before, after, updates, checks):
+    """What is wrong with how the masters moved over `updates` steps,
+    by the sample `sample_masters` took before and after them: the share
+    of sampled elements that changed is over `check.moved_share_min` (a
+    skipped update, or masters too coarse to take the stated step, moves
+    few); under Adam, whose step is about the learning rate whatever the
+    gradient, mean |change| / (the stated learning rate x updates) lies
+    inside `check.mean_step_over_lr` (a rate other than the stated one
+    moves it by its factor)."""
+    import numpy as np
+
     chk = cfg["check"]
+    moved = np.abs(np.concatenate([after[k] - before[k]
+                                   for k in sorted(before)]))
+    problems = []
+    share = float(np.mean(moved > 0))
+    if not _compare(checks, "masters_moved_share", share,
+                    chk["moved_share_min"]):
+        problems.append("%.4f of the sampled master elements moved in %d "
+                        "updates" % (share, updates))
+    if "mean_step_over_lr" in chk:
+        lo, hi = chk["mean_step_over_lr"]
+        ratio = float(np.mean(moved)) / (
+            cfg["optimizer"]["params"]["learning_rate"] * updates)
+        if not _compare(checks, "mean_step_over_lr", ratio, lo, hi):
+            problems.append("the masters moved %.4g stated learning rates "
+                            "an update, not %g to %g" % (ratio, lo, hi))
+    return problems
+
+
+def _bf16(a):
+    """`a` rounded to bf16 and held in fp32."""
+    import jax.numpy as jnp
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def check_reference(cfg, wl, seed, runner, model, checks=None):
+    """Logits and loss of the system's evaluation forward, with the
+    trained values, against the model's plain reference on the check's
+    seeded sample; in training mode where `check.batch_statistics` says
+    so. Where `check.rms_over_bf16_operands` has the cell's dtype, the
+    logits' rms error is compared as a multiple of the one the reference
+    itself makes with every product's operands rounded to bf16 (the
+    model's `reference_forward` takes `operand`); else their largest
+    error over the largest logit, against `check.tolerance`. Returns
+    (facts, problems)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    checks = {} if checks is None else checks
     dtype = wl.get("dtype") or "float32"
-    tol = chk["tolerance"][dtype]
+    tol = cfg["check"]["tolerance"][dtype]
+    over = cfg["check"].get("rms_over_bf16_operands", {}).get(dtype)
     key = jax.random.fold_in(jax.random.PRNGKey(seed % (1 << 32)), 0x5EED)
-    x, y = model.make_batch(cfg, key, chk["samples"])
-    got, got_loss = runner.eval_forward(x, y)
+    x, y = model.make_batch(cfg, key, cfg["check"]["samples"])
+    mode = {"train": True} if cfg["check"].get("batch_statistics") else {}
+    got, got_loss = runner.eval_forward(x, y, **mode)
     params = runner.params()
-    ref = jax.jit(lambda p, a: model.reference_forward(cfg, p, a))
+    ref = jax.jit(lambda p, a: model.reference_forward(cfg, p, a, **mode))
     want = np.asarray(ref(params, x), np.float32)
     want_loss = float(model.reference_loss(jnp.asarray(want), y))
     err = float(np.abs(got - want).max() / np.abs(want).max())
@@ -162,10 +268,22 @@ def check_reference(cfg, wl, seed, runner, model):
     problems = []
     if not np.isfinite(got).all():
         problems.append("non-finite logits from the evaluation forward")
-    if not err < tol:
+    if over is not None:
+        low = np.asarray(jax.jit(lambda p, a: model.reference_forward(
+            cfg, p, a, operand=_bf16, **mode))(params, x), np.float32)
+        ratio = float(np.sqrt(np.mean((got - want) ** 2)
+                              / np.mean((low - want) ** 2)))
+        facts["logits_rms_over_bf16_operands"] = ratio
+        if not _compare(checks, "logits_rms_over_bf16_operands", ratio,
+                        below=over):
+            problems.append("logits' rms error is %.3g times the "
+                            "reference's with bf16 operands, limit %g"
+                            % (ratio, over))
+    elif not _compare(checks, "logits_rel_err", err, below=tol):
         problems.append("logits differ from the reference: rel err %.3g, "
                         "tolerance %.3g (%s)" % (err, tol, dtype))
-    if not abs(got_loss - want_loss) <= loss_tol:
+    if not _compare(checks, "loss_gap_to_reference",
+                    abs(got_loss - want_loss), below=loss_tol):
         problems.append("evaluation loss %.6f, reference %.6f, may differ "
                         "by %.3g" % (got_loss, want_loss, loss_tol))
     return facts, problems
@@ -200,12 +318,20 @@ def _program_spans(lo_s, hi_s):
     return out
 
 
-def _plain_window(runner, seconds, read_every):
+def _read(runner, loss):
+    """(the call that made `loss`, its value on the host)."""
+    return runner.calls - 1, runner.read_loss(loss)
+
+
+def _plain_window(runner, seconds, read_every, seen):
     """Steps run free; the loss is read on the host every `read_every`th
     step, as a training script's logging does; the window closes on the
-    first such read at or after `seconds`."""
+    first such read at or after `seconds` by which some pool batch has
+    had its loss read twice (`seen`: the batches that set-up read), so
+    that `check_losses` has a fall to compare."""
     attempted = failed = 0
     losses = []
+    seen, twice = set(seen), False
     raised = None
     start = now = clock()
     while True:
@@ -213,16 +339,19 @@ def _plain_window(runner, seconds, read_every):
         try:
             loss = runner.step()
             if attempted % read_every == 0:
-                losses.append(runner.read_loss(loss))
+                losses.append(_read(runner, loss))
                 now = clock()
-                if not math.isfinite(losses[-1]):
+                if not math.isfinite(losses[-1][1]):
                     failed += 1
-                if now - start >= seconds:
+                batch = losses[-1][0] % len(runner.pool)
+                twice = twice or batch in seen
+                seen.add(batch)
+                if now - start >= seconds and twice:
                     break
         except Exception as exc:   # the step is the system under test
             raised, failed, now = exc, failed + 1, clock()
             break
-    return {"attempted": attempted, "failed": failed, "losses": losses,
+    return {"attempted": attempted, "failed": failed, "reads": losses,
             "window_s": now - start, "raised": raised,
             "done": attempted - (1 if raised else 0)}
 
@@ -245,7 +374,7 @@ def _traced_window(runner, steps, read_every, keep=None):
             # Starting the profiler stalls the first step after it: two
             # steps run, and end, before the window opens.
             runner.step()
-            runner.read_loss(runner.step())
+            before = _read(runner, runner.step())
             lo = clock()
             with TraceAnnotation(WINDOW):
                 for i in range(1, steps + 1):
@@ -253,7 +382,7 @@ def _traced_window(runner, steps, read_every, keep=None):
                         loss = runner.step()
                     if i % read_every == 0 or i == steps:
                         with TraceAnnotation(READ):
-                            losses.append(runner.read_loss(loss))
+                            losses.append(_read(runner, loss))
             hi = clock()
         finally:
             jax.profiler.stop_trace()
@@ -277,8 +406,9 @@ def _traced_window(runner, steps, read_every, keep=None):
     by_name = {}
     for name, s, e in spans:
         by_name.setdefault(name, []).append((e - s) * 1e3)
-    failed = sum(1 for v in losses if not math.isfinite(v))
-    return {"attempted": steps, "failed": failed, "losses": losses,
+    failed = sum(1 for _, v in losses if not math.isfinite(v))
+    return {"attempted": steps, "failed": failed,
+            "reads": [before] + losses,
             "window_s": hi - lo, "raised": None, "done": steps,
             "trace": reduced, "program_spans_ms": by_name}
 
@@ -320,14 +450,19 @@ def run_cell(root, bench, name, seed, seconds, trace, devices, t0,
     runner = runner_mod.setup(cfg, wl, seed, devices, model)
     phases["build_s"] = clock() - t
     t = clock()
-    first_loss = runner.read_loss(runner.step())
+    reads = [_read(runner, runner.step())]
     phases["first_step_s"] = clock() - t
     t = clock()
     runner.step()
-    runner.read_loss(runner.step())       # the third warm-up step, ended
+    reads.append(_read(runner, runner.step()))  # the third, ended
     phases["warm_steps_s"] = clock() - t
     setup_s = clock() - t0
     say(json.dumps({"setup_s": setup_s, "phases": phases}))
+
+    # Outside set-up and the window: a sample of the masters, read again
+    # after the window.
+    masters_before = sample_masters(runner)
+    calls_before = runner.calls
 
     _compiles.reset()
     read_every = wl.get("read_every", 8)
@@ -335,23 +470,30 @@ def run_cell(root, bench, name, seed, seconds, trace, devices, t0,
         win = _traced_window(runner, wl.get("trace_steps", 30), read_every,
                              keep_trace)
     else:
-        win = _plain_window(runner, seconds, read_every)
+        win = _plain_window(runner, seconds, read_every,
+                            {c % len(runner.pool) for c, _ in reads})
     compiles = _compiles.n
     peak_bytes, peak_key = memory_peak_bytes(devices)
+    updates = runner.calls - calls_before
+    masters_after = sample_masters(runner)
+    reads += win["reads"]
 
+    checks = {}
     problems = []
     if win["raised"] is not None:
         problems.append("a step raised: %r" % (win["raised"],))
-    if compiles:
+    if not _compare(checks, "compiles_in_window", compiles, below=1):
         problems.append("%d programs compiled inside the window" % compiles)
-    problems += check_losses([first_loss] + win["losses"],
-                             cfg["check"]["classes"])
-    facts, ref_problems = check_reference(cfg, wl, seed, runner, model)
+    problems += check_motion(cfg, masters_before, masters_after, updates,
+                             checks)
+    facts, ref_problems = check_reference(cfg, wl, seed, runner, model,
+                                          checks)
+    problems += check_losses(reads, len(runner.pool), cfg["check"], checks)
     problems += ref_problems
     say(json.dumps({
         "steps": win["done"], "window_s": win["window_s"],
-        "compiles_in_window": compiles, "first_loss": first_loss,
-        "last_loss": win["losses"][-1] if win["losses"] else None,
+        "compiles_in_window": compiles, "updates": updates,
+        "pool_batches": len(runner.pool), "reads": reads,
         "reference": facts, "memory_counter": peak_key,
         "problems": problems}))
 
@@ -383,6 +525,7 @@ def run_cell(root, bench, name, seed, seconds, trace, devices, t0,
         device["window_s"] = win["trace"]["window_ns"] / 1e9
         result["breakdown"] = {"device_ops": win["trace"]["device_ops"],
                                "idle_gaps": win["trace"]["idle_gaps"]}
+    result["checks"] = checks           # the last key: numbers and limits
     return result
 
 
@@ -425,4 +568,6 @@ def main(argv, t0):
         print("chipbench: %s" % exc, file=sys.stderr)
         return 2
     print(json.dumps(result), flush=True)
+    for name, entry in result["checks"].items():
+        print("check %s %s" % (name, json.dumps(entry)), file=sys.stderr)
     return 0

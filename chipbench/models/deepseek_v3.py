@@ -222,10 +222,11 @@ def kernel_work(cfg, batch, block_q, block_k):
     layer metric's `args` name them; counted here, not asked of the
     program): what `readers/kernel_roofline_pct` divides by the chip's
     peaks. Score-sized products a computed pair costs: forward q k^T
-    and p v; dK/dV k q^T, v dO^T, p^T dO, dS^T q; dQ q k^T, dO v^T,
-    dS k. Bytes: each operand and result once in bf16, row statistics
-    in fp32; far under the operations over the chip's ridge (240 FLOP a
-    byte), so every flash kernel here is compute-bound."""
+    and p v; the one-pass backward (`mx_flash_bwd`) q k^T, dO v^T,
+    p^T dO, dS^T q and dS k. Bytes: each operand and result once in
+    bf16 (backward: q, k, dQ, dK at d_qk; v, dO, dV at d_v), row
+    statistics in fp32; far under the operations over the chip's ridge
+    (240 FLOP a byte), so every flash kernel here is compute-bound."""
     seq, heads = cfg["bptt"], cfg["num_attention_heads"]
     qk = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
     v = cfg["v_head_dim"]
@@ -239,8 +240,7 @@ def kernel_work(cfg, batch, block_q, block_k):
 
     return {
         "mx_flash_fwd": (flops(1, 1), nbytes(2 * qk + 2 * v)),
-        "mx_flash_bwd_dkv": (flops(2, 2), nbytes(3 * qk + 3 * v)),
-        "mx_flash_bwd_dq": (flops(2, 1), nbytes(3 * qk + 2 * v)),
+        "mx_flash_bwd": (flops(3, 2), nbytes(4 * qk + 3 * v)),
     }
 
 

@@ -161,8 +161,8 @@ def kernel_work(cfg, batch, block_q, block_k):
     The flash kernels at `num_attention_heads` query heads on
     `num_key_value_heads` key/value heads, blocks (block_q, block_k):
     score-sized products a computed pair as in `models/deepseek_v3.py`
-    (forward 2, the fused backward 5, dK/dV 4, dQ 3); bytes with K, V, dK
-    and dV once a key/value head, not once a query head."""
+    (forward 2, the one-pass backward 5); bytes with K, V, dK and dV once
+    a key/value head, not once a query head."""
     seq = cfg["bptt"]
     hv = cfg["linear_num_value_heads"]
     dk, dv = cfg["linear_key_head_dim"], cfg["linear_value_head_dim"]
@@ -187,8 +187,6 @@ def kernel_work(cfg, batch, block_q, block_k):
         "mx_gdn_bwd": gdn_bwd,
         "mx_flash_fwd": (2 * pairs * 2 * d, nbytes(2, 2)),
         "mx_flash_bwd": (2 * pairs * 5 * d, nbytes(3, 4)),
-        "mx_flash_bwd_dkv": (2 * pairs * 4 * d, nbytes(2, 4)),
-        "mx_flash_bwd_dq": (2 * pairs * 3 * d, nbytes(3, 2)),
     }
 
 

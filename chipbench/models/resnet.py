@@ -90,7 +90,7 @@ def flops_per_item(cfg):
     return 3 * 2 * forward_macs(cfg)
 
 
-def _conv(x, w, stride, pad):
+def _plain_conv(x, w, stride, pad):
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), [(pad, pad), (pad, pad)],
         dimension_numbers=("NCHW", "OIHW", "NCHW"))
@@ -120,11 +120,18 @@ class _Params:
             + (beta - mean * scale)[None, :, None, None]
 
 
-def reference_forward(cfg, params, x, train=False):
+def reference_forward(cfg, params, x, train=False, operand=None):
     """Forward in plain fp32 jax.numpy at the highest matmul precision:
     logits (N, classes). `params` is the ordered name -> array mapping of
     the net (running statistics included). `train` takes BatchNorm's
-    statistics from the batch, as a training step does."""
+    statistics from the batch, as a training step does. `operand`, where
+    given, is applied to both operands of every product (a control
+    rounds them to a lower precision there)."""
+    q = operand or (lambda a: a)
+
+    def _conv(x, w, stride, pad):
+        return _plain_conv(q(x), q(w), stride, pad)
+
     with jax.default_matmul_precision("highest"):
         p = _Params(params)
         x = jnp.asarray(x, jnp.float32)
@@ -146,7 +153,7 @@ def reference_forward(cfg, params, x, train=False):
                 x = jax.nn.relu(x + r)
                 cin = cout
         x = jnp.mean(x, axis=(2, 3))
-        return x @ p.take("weight").T + p.take("bias")
+        return q(x) @ q(p.take("weight")).T + p.take("bias")
 
 
 def reference_loss(logits, y):

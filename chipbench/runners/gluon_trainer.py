@@ -55,12 +55,22 @@ class Runner:
             (name, p.data()._data)
             for name, p in self.net.collect_params().items())
 
-    def eval_forward(self, x, y):
-        """(fp32 logits, mean loss) of the net's evaluation forward."""
+    def masters(self):
+        """name -> the trainable masters as the Trainer updates them."""
+        return {name: p.data()._data
+                for name, p in self.net.collect_params().items()
+                if p.grad_req != "null"}
+
+    def eval_forward(self, x, y, train=False):
+        """(fp32 logits, mean loss) of the net's evaluation forward;
+        `train`: in training mode (BatchNorm by the batch's statistics,
+        the running statistics it would write dropped)."""
         from mxnet_tpu import autograd
+        from mxnet_tpu.gluon.parameter import override
         from mxnet_tpu.ndarray import NDArray
 
-        with autograd.pause(train_mode=False):
+        own = {p: p.data() for p in self.net.collect_params().values()}
+        with autograd.pause(train_mode=train), override(own):
             out = self.net(NDArray(x))
             loss = self.loss_fn(out, NDArray(y))
         return np.asarray(out._data, np.float32), \

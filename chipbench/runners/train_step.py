@@ -35,7 +35,7 @@ class Runner:
                               wl["pool_batches"], self.sharding)
         self.items_per_step = wl["batch"] * cfg.get("bptt", 1)
         self.calls = 0
-        self._eval = None
+        self._eval = {}
 
     def step(self):
         x, y = self.pool[self.calls % len(self.pool)]
@@ -52,18 +52,24 @@ class Runner:
         return collections.OrderedDict(
             (name, vals[name]) for name in self.net.collect_params())
 
-    def eval_forward(self, x, y):
+    def masters(self):
+        """name -> the trainable masters as the optimizer holds them."""
+        return dict(self.step_fn._param_vals)
+
+    def eval_forward(self, x, y, train=False):
         """The system's evaluation forward with the trained values, in
         the cell's compute type (every value cast inside the program, as
         `net.cast` would: BatchNorm's evaluation branch promotes to the
         type of its running statistics), and its loss in fp32:
-        (fp32 logits, mean loss)."""
+        (fp32 logits, mean loss). `train`: in training mode, as the step
+        runs it (BatchNorm normalises by the batch's own statistics; the
+        running statistics it would write are dropped)."""
         from mxnet_tpu import autograd
         from mxnet_tpu.gluon.parameter import override
         from mxnet_tpu.ndarray import NDArray
 
         st = self.step_fn
-        if self._eval is None:
+        if train not in self._eval:
             cdt = self.dtype
 
             def fwd(pvals, aux_vals, data, labels):
@@ -74,14 +80,15 @@ class Runner:
                            for p in st._train_params}
                 mapping.update({p: NDArray(cast(aux_vals[p.name]))
                                 for p in st._aux_params})
-                with autograd.pause(train_mode=False), override(mapping):
+                with autograd.pause(train_mode=train), override(mapping):
                     out = self.net(NDArray(cast(data)))
                     out = NDArray(out._data.astype(jnp.float32))
                     loss = self.loss_fn(out, NDArray(labels))
                 return out._data, jnp.mean(loss._data)
 
-            self._eval = jax.jit(fwd)
-        logits, loss = self._eval(st._param_vals, st._aux_vals, x, y)
+            self._eval[train] = jax.jit(fwd)
+        logits, loss = self._eval[train](st._param_vals, st._aux_vals,
+                                         x, y)
         return np.asarray(logits), float(loss)
 
 

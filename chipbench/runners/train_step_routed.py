@@ -102,9 +102,9 @@ class Runner(_base.Runner):
 
     def eval_forward(self, x, y):
         st = self.step_fn
-        if self._eval is None:
-            self._eval = self._evaluation()
-        logits, loss, self._told = self._eval(
+        if False not in self._eval:     # evaluation mode only
+            self._eval[False] = self._evaluation()
+        logits, loss, self._told = self._eval[False](
             st._param_vals, st._aux_vals, x, y)
         return np.asarray(logits), float(loss)
 

@@ -105,7 +105,20 @@ def test_cell_untraced_line_holds_the_contracts_keys(root, cell):
     result, lines = _run(root, cell, trace=0)
     assert lines[-1]["problems"] == [], lines[-1]
     assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+                           "device", "checks"}
+    # every compared number beside its limits, as the line's last key
+    assert list(result)[-1] == "checks"
+    assert {"compiles_in_window", "masters_moved_share",
+            "loss_gap_to_reference", "first_loss_over_ln_classes",
+            "same_batch_loss_fall"} <= set(result["checks"])
+    # the logits by one measure: bf16 cells whose configuration states
+    # it, against the reference's own rounding; else the largest error
+    assert len({"logits_rel_err", "logits_rms_over_bf16_operands"}
+               & set(result["checks"])) == 1
+    # the loss fell on a pool batch read twice, in every cell
+    assert result["checks"]["same_batch_loss_fall"]["value"] > 0
+    for entry in result["checks"].values():
+        assert {"value"} < set(entry) <= {"value", "above", "below"}
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 2 and result["attempted"] % 2 == 0
     assert set(result["metrics"]) == {"train_rate", "setup_s"}
@@ -247,10 +260,64 @@ def test_a_wrong_result_is_reported_not_hidden(root):
     assert result["correct"] is False
     assert any("differ from the reference" in p
                for p in lines[-1]["problems"])
-    assert harness.check_losses([2.3, 2.0], 1000)       # not near ln(1000)
-    assert harness.check_losses([2.3, 2.4], 10)         # did not fall
-    assert harness.check_losses([2.3, float("nan")], 10)
-    assert harness.check_losses([2.3, 2.0], 10) == []
+
+
+_NAN = float("nan")
+
+
+@pytest.mark.parametrize("reads,classes,problem", [
+    ([(0, 2.3), (2, 2.0), (3, 1.9)], 1000, "not near ln"),
+    ([(0, 2.3), (2, 2.0), (3, 2.4)], 10, "did not fall"),
+    ([(0, 2.3), (3, _NAN)], 10, "non-finite"),
+    ([(0, 2.3), (3, 2.0)], 10, None),
+    # the window's last read above its first is no fault: its batches
+    # differ by as much as the loss falls; the batch read twice decides
+    ([(0, 2.3), (2, 2.0), (3, 2.29), (4, 2.4)], 10, None),
+    # of the batches read twice, the one read furthest apart decides
+    ([(0, 2.3), (2, 2.2), (3, 2.35), (8, 2.1)], 10, None),
+    ([(0, 2.3), (2, 2.2), (3, 2.25), (8, 2.21)], 10, "did not fall"),
+    # a fall within the limit is no fall: what moves the loss without an
+    # update (dropout's masks, a router's selection bias) moves it so far
+    ([(0, 2.3), (3, 2.2995)], 10, "did not fall"),
+    ([(0, 2.3), (1, 2.0), (2, 1.9)], 10, "no pool batch was read twice"),
+])
+def test_loss_checks(reads, classes, problem):
+    """`check_losses` over (call, loss) reads of a pool of 3 batches."""
+    checks = {}
+    chk = {"classes": classes, "loss_fall_min": 0.004}
+    problems = harness.check_losses(reads, 3, chk, checks)
+    if problem is None:
+        assert problems == []
+        assert checks["same_batch_loss_fall"]["above"] == 0.004
+        assert checks["same_batch_loss_fall"]["value"] > 0.004
+    else:
+        assert len(problems) == 1 and problem in problems[0]
+
+
+class _CountingRunner:
+    """A runner of three pool batches whose loss is its call's number,
+    three calls in, as set-up leaves one."""
+    pool = [None] * 3
+
+    def __init__(self):
+        self.calls = 3
+
+    def step(self):
+        self.calls += 1
+        return float(self.calls - 1)
+
+    def read_loss(self, loss):
+        return loss
+
+
+@pytest.mark.parametrize("seen,closes_at", [
+    ({0, 2}, 4),        # set-up read batches 0 and 2: call 6 reads 0 again
+    (set(), 8),         # calls 4, 6, 8, 10 read batches 1, 0, 2, 1
+])
+def test_plain_window_closes_once_a_batch_is_read_twice(seen, closes_at):
+    win = harness._plain_window(_CountingRunner(), 0.0, 2, seen)
+    assert win["attempted"] == closes_at
+    assert [c for c, _ in win["reads"]] == list(range(4, 4 + closes_at, 2))
 
 
 def test_no_tpu_no_result():

@@ -102,21 +102,21 @@ def test_roofline_reader_reads_call_sites_whole_or_not_at_all():
                            "flash_bwd_roofline_pct.train.json")) as f:
         declared = json.load(f)["args"]
     assert {k: declared[k] for k in args} == args
+    assert declared["kernels"] == ["mx_flash_bwd"]
     # 10 of the 16 blocks of 1024 x 1024 are computed, counted here
     assert model._flash_pairs(4096, 1024, 1024) == 10 * 1024 * 1024
     assert model._flash_pairs(4096, 512, 1024) == 20 * 512 * 1024
     assert model._flash_pairs(32, 1024, 1024) == 32 * 32
     at_peak = {k: 30 * f / 197e12 for k, (f, _) in work.items()}
-    ops = [["mx_flash_bwd_dkv.7", 2 * at_peak["mx_flash_bwd_dkv"]],
+    ops = [["mx_flash_bwd.7", 2 * at_peak["mx_flash_bwd"]],
            ["jvp_mx_flash_fwd_.1", 4 * at_peak["mx_flash_fwd"]],
-           ["transpose_jvp_mx_flash_bwd_dq__.3",
-            4 * at_peak["mx_flash_bwd_dq"]],
-           ["fusion.12", 1.0], ["mx_flash_bwd_dq_other.1", 1e-9]]
+           ["transpose_jvp_mx_flash_bwd__.3", 4 * at_peak["mx_flash_bwd"]],
+           ["fusion.12", 1.0], ["mx_flash_bwd_dq.1", 1e-9]]
     run = _synthetic_run(ops)
     assert reader.read(run, kernels=["mx_flash_fwd"], **args) \
         == pytest.approx(25.0)
-    assert reader.read(run, kernels=["mx_flash_bwd_dkv", "mx_flash_bwd_dq"],
-                       **args) == pytest.approx((50.0 + 25.0) / 2)
+    assert reader.read(run, kernels=declared["kernels"], **args) \
+        == pytest.approx((50.0 + 25.0) / 2)
     assert reader.read(_synthetic_run([["fusion.1", 1.0]]),
                        kernels=["mx_flash_fwd"], **args) is None
     assert reader.read({"trace": None}, kernels=["mx_flash_fwd"],

@@ -62,8 +62,17 @@ def test_declared_with_its_files_and_no_other_cell():
             == ("kernels", "train_rate", "device_trace", "%")
     assert bench["workloads"][-1]["name"] == _CELL
     assert bench["configs"][-1]["name"] == _CONFIG
+    # and the host spans and expert gauges that every TrainStep cell with
+    # sparse layers reports name it beside cells 3 and 4
+    shared = {"host_step_ms.train", "dispatch_ms.train", "data_put_ms.train",
+              "moe_load_max_over_mean.train", "moe_buffer_fill_pct.train",
+              "moe_overflow_steps.train"}
     for m in bench["per_layer"]:
-        assert _CELL not in m.get("workloads", ()) or m["name"] in _METRICS
+        assert _CELL not in m.get("workloads", ()) \
+            or m["name"] in set(_METRICS) | shared
+    for name in shared:
+        assert declared[name]["workloads"][-2:] == [
+            "qwen3next-80b-a3b-train-s4096", _CELL]
 
 
 def test_every_published_number_is_in_the_file():
