@@ -3,8 +3,9 @@ the step's loss on a pool batch read twice has to fall between the two
 reads (`harness.check_losses`),
 and the masters have to move as the stated optimizer moves them
 (`harness.check_motion`). A sound step passes both; bf16 masters, a
-skipped update and a tenfold Adam rate are refused, and so is the logits
-check's control, the reference in fp8 in the program's place. Each fault
+skipped update and a tenfold Adam rate are refused, and so are the logits
+checks' controls: the reference in fp8 in the program's place, and the
+fp32 Gluon cell's evaluation forward computing in bf16. Each fault
 is planted under the runner the harness builds (`planted`), so the run is
 the command's own from set-up to the result line. The fused flash backward's
 count in `models/deepseek_v3.py:kernel_work` is held to its hand count.
@@ -66,9 +67,35 @@ def _fp8_reference(runner):
     runner.eval_forward = eval_forward
 
 
+def _bf16_compute(runner):
+    """The control of an fp32 Gluon cell's logits check: its evaluation
+    forward computing in bf16, the type below the stated one, as
+    `net.cast` would set it: every floating parameter, running statistic
+    and input cast to bf16, the logits back to fp32."""
+    from mxnet_tpu import autograd
+    from mxnet_tpu.gluon.parameter import override
+    from mxnet_tpu.ndarray import NDArray
+
+    def cast(a):
+        return a.astype(jnp.bfloat16) \
+            if jnp.issubdtype(a.dtype, jnp.floating) else a
+
+    def eval_forward(x, y, train=False):
+        low = {p: NDArray(cast(p.data()._data))
+               for p in runner.net.collect_params().values()}
+        with autograd.pause(train_mode=train), override(low):
+            out = runner.net(NDArray(cast(jnp.asarray(x))))
+            out = NDArray(out._data.astype(jnp.float32))
+            loss = runner.loss_fn(out, NDArray(y))
+        return (np.asarray(out._data, np.float32),
+                float(loss.mean().asnumpy()))
+
+    runner.eval_forward = eval_forward
+
+
 FAULTS = {"bf16_masters": _bf16_masters, "no_update": _scale_rate(0.0),
           "tenfold_rate": _scale_rate(10.0),
-          "fp8_reference": _fp8_reference}
+          "fp8_reference": _fp8_reference, "bf16_compute": _bf16_compute}
 
 
 def planted(fault, load=None):
@@ -95,6 +122,7 @@ def planted(fault, load=None):
 _ADAM = "kanana2-30b-a3b-train-s4096"
 _SGD = "ptb-lstm-train-b1024"
 _BN = "resnet50-train-b256"
+_GLUON = "resnet50-gluon-b32"
 
 
 def _stated_rate(root, config):
@@ -120,7 +148,7 @@ def _refused_by(result):
 # the first read that repeats a pool batch: four updates however fast the
 # machine runs. The tiny LSTM needs more updates than that for nine in ten
 # of its sampled masters to move.
-_SECONDS = {_ADAM: 0.0, _SGD: 0.2, _BN: 0.0}
+_SECONDS = {_ADAM: 0.0, _SGD: 0.2, _BN: 0.0, _GLUON: 0.0}
 _MOTION = {"masters_moved_share", "mean_step_over_lr"}
 _ALL = None
 
@@ -137,6 +165,8 @@ _CASES = [
                                       "same_batch_loss_fall"}),
     (_BN, None, False, _ALL, set()),
     (_BN, "fp8_reference", False, _ALL, {"logits_rms_over_bf16_operands"}),
+    (_GLUON, None, False, _ALL, set()),
+    (_GLUON, "bf16_compute", False, {"logits_rel_err"}, {"logits_rel_err"}),
 ]
 
 
